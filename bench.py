@@ -45,7 +45,16 @@ child persists an inspection snapshot (res["inspection"]: the
 obs_inspect rules over every live store + event-ring tails) into the
 result JSON, and a partial snapshot is re-dumped every 30s so even a
 SIGKILL'd flight (rc=137/rc=124) leaves a diagnosis. The SF100
-north-star flight (tpch_big) runs FIRST. Environment knobs:
+north-star flight (tpch_big) runs FIRST.
+
+Devices: each flight child is the one process that touches JAX (this
+parent never imports it). A flight FAILS when JAX finds no accelerator;
+XLA's CPU backend serves only when BENCH_PLATFORM=cpu names it. Every
+board line ends with the platform, device_kind and device count it was
+taken on, follower/replica children are pinned to JAX_PLATFORMS=cpu and
+labelled so, and the board exits non-zero if any flight failed.
+
+Environment knobs:
 BENCH_SF (10), BENCH_JOIN_SF (10),
 BENCH_SSB_SF (100), BENCH_CB_ROWS (1e8), BENCH_SF_BIG (100),
 BENCH_MESH_ROWS (4e6), BENCH_MESH_DEVICES (8),
@@ -706,32 +715,61 @@ def _scale_to_ram(requested_rows: int, bytes_per_row: float,
     return requested_rows
 
 
-def _session_env():
+def _session_env(cpu_devices: int = 0) -> dict:
     """Flight-local engine setup: quiet the slow log (it drowned the
-    r04 board's output tail), pick the platform."""
+    r04 board's output tail), place the compile cache, and claim the
+    flight's device. A flight needs an accelerator: XLA's CPU backend
+    serves only when BENCH_PLATFORM=cpu names it (then with
+    `cpu_devices` virtual devices for the mesh flight), and every board
+    line carries the device (run_flight_child). This process is the one
+    that touches the chip — the parent board never imports jax."""
     import logging
+
+    import jax
+    from tidb_tpu import device
 
     logging.getLogger("tidb_tpu.slowlog").setLevel(logging.ERROR)
     platform = os.environ.get("BENCH_PLATFORM")
     if platform:
-        import jax
         jax.config.update("jax_platforms", platform)
+        if platform == "cpu" and cpu_devices:
+            jax.config.update("jax_num_cpu_devices", cpu_devices)
+    device.configure_compile_cache()
+    info = device.describe()
+    if info["platform"] == "cpu" and platform != "cpu":
+        raise RuntimeError(
+            f"no accelerator: JAX is on {device.line(info)}; a CPU run "
+            f"has to be named (BENCH_PLATFORM=cpu) and is never reported "
+            f"as a device")
+    return info
+
+
+# peak HBM bytes/s per chip by jax device_kind. Source: Google Cloud
+# documentation, "TPU v5e" system architecture (819 GB/s per chip).
+HBM_PEAK_BYTES_PER_S = {"TPU v5 lite": 819e9}
 
 
 def _hbm_line(name: str, p50: float, n: int, col_bytes: float) -> str:
     """Estimated device bytes touched per pass vs nominal HBM bandwidth.
     col_bytes = per-row data bytes at staged (narrowed) widths; each
     staged column also carries a 1-byte validity lane + one shared
-    visibility lane."""
-    import jax
+    visibility lane. An accelerator missing from HBM_PEAK_BYTES_PER_S
+    is an error, not a default; a named CPU run has no HBM to compare."""
+    from tidb_tpu import device
 
-    bw = {"tpu": 819e9}.get(jax.default_backend())  # v5e: ~819 GB/s
+    info = device.describe()
     touched = n * col_bytes
-    line = (f"{name}: ~{touched / p50 / 1e9:.0f} GB/s device scan "
+    line = (f"{name}: ~{touched / p50 / 1e9:.0f} GB/s scan "
             f"({touched / 1e9:.1f}GB staged bytes / {p50 * 1e3:.1f}ms)")
-    if bw:
-        line += f" = {touched / p50 / bw * 100:.0f}% of nominal HBM bw"
-    return line
+    if info["platform"] == "cpu":
+        return line
+    bw = HBM_PEAK_BYTES_PER_S.get(info["device_kind"])
+    if bw is None:
+        raise RuntimeError(
+            f"no HBM peak recorded for device_kind "
+            f"{info['device_kind']!r}; add it to HBM_PEAK_BYTES_PER_S "
+            f"with its source")
+    return line + f" = {touched / p50 / bw * 100:.0f}% of nominal HBM bw"
 
 
 # ---------------------------------------------------------------------------
@@ -973,42 +1011,19 @@ def flight_multichip(res: dict) -> None:
     """Mesh data plane: Q1/Q6-class scan+agg over epochs sharded across
     the device mesh vs the single-device path — per-query rows/s for
     both, plus per-device placement (shard spec + bytes per device from
-    `arr.sharding` / `addressable_shards`). Forces an 8-virtual-device
-    CPU mesh when no real multi-chip backend was requested
-    (BENCH_PLATFORM unset), mirroring the MULTICHIP board's dryrun."""
+    `arr.sharding` / `addressable_shards`). Runs on the host's real
+    chips and fails on a single-device backend; only BENCH_PLATFORM=cpu
+    gives it BENCH_MESH_DEVICES virtual CPU devices (a control-flow
+    run, labelled as such on every line)."""
     import jax
 
-    want = int(os.environ.get("BENCH_MESH_DEVICES", 8))
-    if not os.environ.get("BENCH_PLATFORM"):
-        # prefer REAL multi-device hardware: probe the default backend
-        # in a throwaway child (this process must not initialize a
-        # backend before deciding — init is one-shot), and only fall
-        # back to `want` virtual CPU devices when the default backend
-        # is cpu or single-device
-        ndev, backend = 1, "cpu"
-        try:
-            probe = subprocess.run(
-                [sys.executable, "-c",
-                 "import jax; print(jax.default_backend(), "
-                 "len(jax.devices()))"],
-                capture_output=True, text=True, timeout=180)
-            parts = probe.stdout.split()
-            if len(parts) >= 2:
-                backend, ndev = parts[-2], int(parts[-1])
-        except (subprocess.TimeoutExpired, OSError, ValueError):
-            pass
-        if backend != "cpu" and ndev > 1:
-            log(f"multichip: using default backend {backend} "
-                f"({ndev} devices)")
-        else:
-            try:  # must precede backend init; ignored afterwards
-                jax.config.update("jax_platforms", "cpu")
-                jax.config.update("jax_num_cpu_devices", want)
-            except AttributeError:
-                os.environ["XLA_FLAGS"] = (
-                    os.environ.get("XLA_FLAGS", "")
-                    + f" --xla_force_host_platform_device_count={want}")
-    _session_env()
+    dev = _session_env(
+        cpu_devices=int(os.environ.get("BENCH_MESH_DEVICES", 8)))
+    if dev["count"] < 2:
+        raise RuntimeError(
+            f"multichip flight needs a multi-device backend, have "
+            f"{dev['count']} device (BENCH_PLATFORM=cpu runs it on "
+            f"BENCH_MESH_DEVICES virtual CPU devices)")
     from tidb_tpu.bench.tpch import TPCH_Q1, TPCH_Q6, load_lineitem
     from tidb_tpu.copr import mesh as M
     from tidb_tpu.copr.client import CopClient
@@ -1183,8 +1198,11 @@ def flight_replica_read(res: dict) -> None:
             "s = Storage(sys.argv[1], remote=sys.argv[2])\n"
             "print('follower ready', flush=True)\n"
             "time.sleep(1e9)\n")
-        env = dict(os.environ, TIDB_TPU_REPLICA_APPLY_MS="100")
-        env.setdefault("JAX_PLATFORMS", "cpu")
+        # one process per chip: this flight process holds it, so the
+        # follower replicas are pinned to XLA's CPU backend — their
+        # scans are host work, and the board line below says so
+        env = dict(os.environ, TIDB_TPU_REPLICA_APPLY_MS="100",
+                   JAX_PLATFORMS="cpu")
         for i in range(n_followers):
             procs.append(subprocess.Popen(
                 [sys.executable, "-c", code,
@@ -1268,7 +1286,9 @@ def flight_replica_read(res: dict) -> None:
         lines.append(
             f"replica_read scaling: {routed['qps'] / max(base['qps'], 1e-9):.2f}x "
             f"QPS with {n_followers} serving followers "
-            f"({workers} workers, {n} rows)")
+            f"({workers} workers, {n} rows; followers are child "
+            f"processes pinned to JAX_PLATFORMS=cpu — routed reads are "
+            f"host work, only the leader is on the flight's device)")
 
         # ranged phase: the same routed read with the range plane
         # armed as a 4-range leader fleet and the range-aware covering
@@ -1887,6 +1907,12 @@ def run_flight_child(name: str, out_path: str) -> None:
     finally:
         stop.set()
         dumper.join(timeout=2.0)
+    # every board line names the device it was taken on
+    from tidb_tpu import device
+    dev = device.described()
+    res["device"] = dev
+    tag = f" [{device.line(dev)}]" if dev else " [no jax backend]"
+    res["lines"] = [str(ln) + tag for ln in res["lines"]]
     with out_lock:
         # atomic like the periodic dumps: a kill landing mid-final-write
         # must not truncate away the last good partial snapshot
@@ -2054,6 +2080,7 @@ def _headline(values: dict, baseline_rps: float, lines_done: int) -> str:
         "unknown",
         "baseline": "compiled C++ row-loop (native/baseline.cpp), "
                     "single-stream",
+        "device": values.get("device"),
         "flights_done": lines_done,
     })
 
@@ -2137,6 +2164,9 @@ def main() -> None:
         all_lines += res.get("lines", [])
         if res.get("ok"):
             values.update(res.get("values", {}))
+            if res.get("values", {}).get("q6_big") or \
+                    res.get("values", {}).get("q6_small"):
+                values["device"] = res.get("device")  # the headline's
             done += 1
         else:
             all_lines.append(
@@ -2198,8 +2228,7 @@ def main() -> None:
         print(_headline(values, kv_rps, done), flush=True)
     else:
         print(json.dumps({
-            "metric": "tpch_q6_rows_per_sec", "value": 0,
-            "unit": "rows/s", "vs_baseline": 0,
+            "metric": "tpch_q6_rows_per_sec",
             "error": "no flight produced a headline"}), flush=True)
 
     # ---- round record (BENCH_ROUND=N): structured, comparator-ready ----
@@ -2240,6 +2269,12 @@ def main() -> None:
                     "lines": mc_res.get("lines", []),
                     "error": mc_res.get("error"),
                 })
+    failed = sorted(n for n, r in flight_results.items()
+                    if not r.get("ok"))
+    if failed or not headline_ok:
+        log(f"FAILED flights: {failed or 'none'}; headline "
+            f"{'ok' if headline_ok else 'missing'}")
+        sys.exit(1)
 
 
 if __name__ == "__main__":

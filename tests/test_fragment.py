@@ -253,3 +253,45 @@ def test_highcard_topn_join_device_path(star, monkeypatch):
 def test_highcard_group_key_order(highcard):
     q = "SELECT g, SUM(v) FROM hc GROUP BY g ORDER BY g LIMIT 9"
     assert highcard.query(q) == _oracle(highcard, q)
+
+
+def test_device_error_fails_the_statement(star, monkeypatch):
+    """An error from the device compiler or runtime (Mosaic/XLA refusing
+    a program) is the statement's typed error — not a slow, correct
+    host(fragment:compile) answer that hides that the chip did nothing.
+    The typed planner gates (_Fallback reasons: the overlay and key-span
+    tests above) still reach the host interpreter, and so does the one
+    counted degrade, a program that did not fit HBM."""
+    import jax
+
+    from tidb_tpu import obs
+    from tidb_tpu.copr.client import CopClient
+    from tidb_tpu.copr.eval import DeviceError
+    from tidb_tpu.errno import CodedError
+
+    def refuse(msg):
+        def raiser(*a, **kw):
+            raise jax.errors.JaxRuntimeError(msg)
+        return raiser
+
+    want = star.query(JOIN_AGG)  # the device answer, oracle-checked above
+    mosaic = "INTERNAL: Mosaic failed to compile TPU kernel: bad layout"
+    monkeypatch.setattr(F, "_device_fragment", refuse(mosaic))
+    with monkeypatch.context() as m:
+        m.setattr(F, "_host_fragment", refuse("host fallback taken"))
+        with pytest.raises(DeviceError, match="Mosaic failed") as ei:
+            star.query(JOIN_AGG)
+    assert isinstance(ei.value, CodedError)
+
+    # HBM exhaustion degrades, tagged and counted
+    before = obs.FRAG_FALLBACKS.get(reason="device-oom")
+    monkeypatch.setattr(F, "_device_fragment", refuse(
+        "RESOURCE_EXHAUSTED: out of memory in memory space hbm"))
+    assert star.query(JOIN_AGG) == want
+    assert "host(fragment:device-oom)" in star.last_engines
+    assert obs.FRAG_FALLBACKS.get(reason="device-oom") == before + 1
+
+    # the single-table device path has no degrade at all
+    monkeypatch.setattr(CopClient, "_run_batch", refuse(mosaic))
+    with pytest.raises(DeviceError, match="Mosaic failed"):
+        star.query("SELECT SUM(amount) FROM fact WHERE qty < 5")

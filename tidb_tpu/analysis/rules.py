@@ -630,12 +630,8 @@ def _r_config_drift(tree: SourceTree):
     toml_text = tree.aux.get("config.toml.example")
     if toml_text is None:
         return []
-    try:
-        import tomllib
-        raw = tomllib.loads(toml_text)
-    except ImportError:
-        from ..config import _parse_toml_subset
-        raw = _parse_toml_subset(toml_text)
+    import tomllib
+    raw = tomllib.loads(toml_text)
     from ..config import Config
     cfg = Config()
     out = []

@@ -14,6 +14,7 @@ loudly at startup instead of silently running with defaults.
 from __future__ import annotations
 
 import dataclasses
+import tomllib
 from dataclasses import dataclass, field
 from typing import Any, Optional
 
@@ -448,23 +449,11 @@ class Config:
         """Strict TOML decode (reference: config.go strict check — an
         undecoded key is an error)."""
         try:
-            import tomllib
-        except ImportError:  # Python < 3.11: the minimal subset parser
-            tomllib = None
-        if tomllib is not None:
-            try:
-                with open(path, "rb") as f:
-                    raw = tomllib.load(f)
-            except tomllib.TOMLDecodeError as e:
-                raise ConfigError(
-                    f"malformed TOML in {path}: {e}") from None
-        else:
-            try:
-                with open(path, encoding="utf-8") as f:
-                    raw = _parse_toml_subset(f.read())
-            except _TomlError as e:
-                raise ConfigError(
-                    f"malformed TOML in {path}: {e}") from None
+            with open(path, "rb") as f:
+                raw = tomllib.load(f)
+        except tomllib.TOMLDecodeError as e:
+            raise ConfigError(
+                f"malformed TOML in {path}: {e}") from None
         cfg = Config()
         cfg.apply(raw)
         return cfg
@@ -1050,60 +1039,6 @@ class _JsonLogFormatter:
         if slow is not None:
             out["slow"] = slow
         return json.dumps(out, default=str)
-
-
-class _TomlError(Exception):
-    pass
-
-
-def _parse_toml_subset(text: str) -> dict:
-    """Fallback decoder for interpreters without tomllib: the subset the
-    config format actually uses — [section] tables, key = value with
-    quoted strings, integers, floats and booleans, # comments. Malformed
-    input raises (strictness preserved: the caller maps to ConfigError)."""
-    root: dict = {}
-    cur = root
-    for ln, line in enumerate(text.splitlines(), 1):
-        line = line.strip()
-        if not line or line.startswith("#"):
-            continue
-        if line.startswith("["):
-            if not line.endswith("]"):
-                raise _TomlError(f"line {ln}: unterminated table header")
-            cur = root
-            for part in line[1:-1].strip().split("."):
-                if not part:
-                    raise _TomlError(f"line {ln}: empty table name")
-                cur = cur.setdefault(part, {})
-            continue
-        key, eq, value = line.partition("=")
-        if not eq:
-            raise _TomlError(f"line {ln}: expected key = value")
-        cur[key.strip()] = _toml_value(value.strip(), ln)
-    return root
-
-
-def _toml_value(v: str, ln: int):
-    if v and v[0] in "\"'":
-        q = v[0]
-        end = v.find(q, 1)
-        if end < 0:
-            raise _TomlError(f"line {ln}: unterminated string")
-        rest = v[end + 1:].strip()
-        if rest and not rest.startswith("#"):
-            raise _TomlError(f"line {ln}: trailing characters {rest!r}")
-        return v[1:end]
-    v = v.split("#", 1)[0].strip()
-    if v in ("true", "false"):
-        return v == "true"
-    try:
-        return int(v, 0)
-    except ValueError:
-        pass
-    try:
-        return float(v)
-    except ValueError:
-        raise _TomlError(f"line {ln}: unsupported value {v!r}") from None
 
 
 def _apply_section(obj, raw: dict, prefix: str) -> None:

@@ -23,6 +23,7 @@ from typing import Any, Callable
 import jax.numpy as jnp
 import numpy as np
 
+from ..errno import CodedError
 from ..plan.expr import Call, Col, Const, PlanExpr
 from ..types.field_type import FieldType, TypeKind
 
@@ -32,6 +33,19 @@ VV = tuple[jnp.ndarray, jnp.ndarray]
 
 class CompileError(Exception):
     """Raised when an expression can't lower to device ops (host fallback)."""
+
+
+class DeviceError(CodedError):
+    """The device compiler or runtime refused a program the planner's
+    gates admitted (a Mosaic or XLA compile error, a runtime fault). It
+    is the statement's error: answering from the host interpreter
+    instead would be a slow correct result that hides that the chip did
+    nothing. (HBM exhaustion on a fragment is the one counted degrade:
+    copr/fragment.py device_refusal.)"""
+
+    @classmethod
+    def of(cls, exc: BaseException) -> "DeviceError":
+        return cls(f"device program failed: {exc}")
 
 
 def _np_dtype_for(ft: FieldType):

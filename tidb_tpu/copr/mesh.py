@@ -33,11 +33,12 @@ owns both decisions:
   re-shard per dispatch. DML that folds a new epoch invalidates the
   old epoch's device buffers eagerly (Storage.add_epoch_listener).
 
-* **Graceful fallback** — `mesh.enabled = false`, a single visible
+* **Single-device path** — `mesh.enabled = false`, a single visible
   device, or a below-threshold table all take the EXACT single-device
-  path: `client_for` hands out a plain CopClient when the plane is
-  inactive, and MeshCopClient in `single` mode dispatches every hook
-  to the base implementations.
+  path: `client_for` hands out the storage's shared plain CopClient
+  when the plane is inactive, and MeshCopClient in `single` mode
+  dispatches every hook to the base implementations. A backend that
+  fails to initialise is an error, never "single-device".
 
 Results are bit-identical to the single-device path by construction:
 the sharded kernels produce the same exact limb partials and merge
@@ -400,6 +401,9 @@ class MeshPlane:
         import weakref
         self._clients: "weakref.WeakKeyDictionary" = \
             weakref.WeakKeyDictionary()
+        # storage -> shared plain CopClient while the plane is inactive
+        self._single_clients: "weakref.WeakKeyDictionary" = \
+            weakref.WeakKeyDictionary()
         # devices currently above the HBM watermark (edge-triggered
         # mesh_hbm_watermark events)
         self._above_watermark: set[str] = set()
@@ -431,13 +435,13 @@ class MeshPlane:
     @property
     def active(self) -> bool:
         """Enabled AND more than one device. Checking device count
-        builds the mesh; a disabled plane never touches the backend."""
+        builds the mesh; a disabled plane never touches the backend.
+        A backend that fails to initialise raises here — it is the
+        statement's error, not a reason to call the process
+        single-device."""
         if not self.cfg.enabled:
             return False
-        try:
-            return self.n_devices > 1
-        except Exception:  # noqa: BLE001 — no backend: single-device
-            return False
+        return self.n_devices > 1
 
     # ---- placement policy -------------------------------------------------
     def placement_for(self, snap) -> str:
@@ -464,18 +468,25 @@ class MeshPlane:
         # receives mesh_skew / mesh_compile_storm / mesh_hbm_watermark
         if c.recorder.obs is None:
             c.recorder.obs = getattr(storage, "obs", None)
-        # the keyspace heat recorder: scans account per-range traffic
-        if c.heat is None:
-            c.heat = getattr(storage, "heat", None)
         # module-level storage->client registry: the diag/infoschema
         # read side (client_of) resolves through it, so recorder rings
         # stay queryable whichever plane instance built the client
         # (tests construct private planes; latest client wins)
         _STORAGE_CLIENTS[storage] = c
-        # outside the plane lock: the listener hook takes storage-side
-        # structures only
-        if hasattr(storage, "add_epoch_listener"):
-            storage.add_epoch_listener(c.on_epoch_replaced)
+        _attach_storage(c, storage)
+        return c
+
+    def single_client_for(self, storage) -> CopClient:
+        """The storage's shared plain client while the plane is
+        inactive (one visible device, or disabled). A client per
+        session — i.e. per wire connection — would stage its own copy
+        of every epoch it scans and compile its own kernels, so device
+        memory and compile time would grow with the connection count."""
+        with self._lock:
+            c = self._single_clients.get(storage)
+            if c is None:
+                c = self._single_clients[storage] = CopClient()
+        _attach_storage(c, storage)
         return c
 
     def clients(self) -> list:
@@ -569,6 +580,17 @@ class MeshPlane:
             except Exception:  # noqa: BLE001 — telemetry only
                 continue
         return peak
+
+
+def _attach_storage(c: CopClient, storage) -> None:
+    """Wire a shared client to its storage, outside the plane lock (the
+    listener hook takes storage-side structures only): the keyspace
+    heat recorder, so scans account per-range traffic, and eager
+    device-buffer eviction on every epoch replacement."""
+    if c.heat is None:
+        c.heat = getattr(storage, "heat", None)
+    if hasattr(storage, "add_epoch_listener"):
+        storage.add_epoch_listener(c.on_epoch_replaced)
 
 
 def _walk_arrays(o):
@@ -684,14 +706,6 @@ class MeshCopClient(DistCopClient):
             with self.placement_scope(snap):
                 return super().execute(dag, snap)
         return super().execute(dag, snap)
-
-    # ---- storage integration ----------------------------------------------
-    def on_epoch_replaced(self, store) -> None:
-        """Eager invalidation on epoch fold (bulk load / compaction /
-        DDL rewrite): free the superseded epoch's device buffers NOW
-        instead of on the next dispatch — sharded epochs pin HBM on
-        every device."""
-        self._evict_stale(store.table.id, store.epoch.epoch_id)
 
     # ---- engine tags -------------------------------------------------------
     def _device_engine(self) -> str:
@@ -1110,14 +1124,15 @@ def configure(enabled: Optional[bool] = None,
 
 
 def client_for(storage) -> CopClient:
-    """Default coprocessor client for a session over `storage`: the
-    storage's shared mesh client when the plane is active, else a fresh
-    single-device CopClient (exactly the pre-mesh behavior)."""
+    """Default coprocessor client for a session over `storage`, shared
+    by every session of that storage: the mesh client when the plane is
+    active, else one plain single-device CopClient. The first caller
+    initialises the JAX backend; the device line is logged there."""
+    from .. import device
+    device.describe()
     plane = get_plane()
     if not plane.active:
-        c = CopClient()
-        c.heat = getattr(storage, "heat", None)
-        return c
+        return plane.single_client_for(storage)
     return plane.client_for(storage)
 
 

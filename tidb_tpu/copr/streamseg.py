@@ -49,6 +49,7 @@ BLK = 1024     # rows per inner block (one-hot sublane extent)
 B = 16         # inner blocks per grid step
 MAX_ROWS_PER_KEY = 4096   # f32 exactness: rows_per_key * (2^12-1) < 2^24
 MAX_ARRAYS = 8  # K cap
+KERNEL_NAME = "titpu_rank_sums"   # the Mosaic custom call's name in HLO
 
 
 def _r128(x: int) -> int:
@@ -158,13 +159,52 @@ def _kernel(vals_ref, f_ref, out_hbm, acc, sem, st, *, K, OHW, F, WS,
         cp.wait()
 
 
+def rank_sums_pallas(vals, f_dev, meta):
+    """The kernel proper: vals f32[K, n], f_dev int32[n0] change flags
+    -> f32[K, nd_pad] per-rank sums; entries at ranks >= nd are
+    unwritten HBM. Lowers through Mosaic on a TPU; under
+    pltpu.force_tpu_interpret_mode() the same body runs on any
+    backend."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    K = vals.shape[0]
+    steps = -(-vals.shape[1] // (B * BLK))
+    npad2 = steps * B * BLK
+    K8 = -(-K // 8) * 8   # DMA slices must be sublane(8)-aligned
+    pad_rows = ((0, K8 - K), (0, max(0, npad2 - vals.shape[1])))
+    if pad_rows != ((0, 0), (0, 0)):
+        vals = jnp.pad(vals, pad_rows)
+    kern = functools.partial(
+        _kernel, K=K8, OHW=meta["ohw"], F=meta["flush"],
+        WS=meta["wstep"], steps=steps)
+    out = pl.pallas_call(
+        kern,
+        name=KERNEL_NAME,
+        grid=(steps,),
+        in_specs=[
+            pl.BlockSpec((K8, B * BLK), lambda i: (0, i)),
+            pl.BlockSpec((1, B * BLK), lambda i: (0, i)),
+        ],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        scratch_shapes=[
+            pltpu.VMEM((K8, meta["wstep"]), jnp.float32),
+            pltpu.SemaphoreType.DMA,
+            pltpu.SMEM((2,), jnp.int32),
+        ],
+        out_shape=jax.ShapeDtypeStruct((K8, meta["out_pad"]),
+                                       jnp.float32),
+    )(vals, jnp.pad(f_dev, (0, npad2 - f_dev.shape[0])
+                    ).reshape(1, -1))
+    return out[:K, :meta["nd_pad"]]
+
+
 def rank_sums(vals, f_dev, meta):
     """vals: f32[K, n_pad] query-masked integer-valued arrays.
     -> f32[K, nd_pad] per-rank sums (exact integers; entries at ranks
     >= nd are zeroed).
 
     TPU: the Pallas kernel above; otherwise jax.ops.segment_sum."""
-    K = vals.shape[0]
     nd, nd_pad = meta["nd"], meta["nd_pad"]
     if meta["identity"]:
         flat = vals[:, :nd_pad]
@@ -179,36 +219,7 @@ def rank_sums(vals, f_dev, meta):
             lambda v: jax.ops.segment_sum(v, rank, num_segments=nd_pad)
         )(vals)
     else:
-        from jax.experimental import pallas as pl
-        from jax.experimental.pallas import tpu as pltpu
-
-        steps = -(-vals.shape[1] // (B * BLK))
-        npad2 = steps * B * BLK
-        K8 = -(-K // 8) * 8   # DMA slices must be sublane(8)-aligned
-        pad_rows = ((0, K8 - K), (0, max(0, npad2 - vals.shape[1])))
-        if pad_rows != ((0, 0), (0, 0)):
-            vals = jnp.pad(vals, pad_rows)
-        kern = functools.partial(
-            _kernel, K=K8, OHW=meta["ohw"], F=meta["flush"],
-            WS=meta["wstep"], steps=steps)
-        out = pl.pallas_call(
-            kern,
-            grid=(steps,),
-            in_specs=[
-                pl.BlockSpec((K8, B * BLK), lambda i: (0, i)),
-                pl.BlockSpec((1, B * BLK), lambda i: (0, i)),
-            ],
-            out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
-            scratch_shapes=[
-                pltpu.VMEM((K8, meta["wstep"]), jnp.float32),
-                pltpu.SemaphoreType.DMA,
-                pltpu.SMEM((2,), jnp.int32),
-            ],
-            out_shape=jax.ShapeDtypeStruct((K8, meta["out_pad"]),
-                                           jnp.float32),
-        )(vals, jnp.pad(f_dev, (0, npad2 - f_dev.shape[0])
-                        ).reshape(1, -1))
-        flat = out[:K, :nd_pad]
+        flat = rank_sums_pallas(vals, f_dev, meta)
     # ranks beyond nd carry garbage (unwritten HBM) on the kernel path
     live = jnp.arange(nd_pad, dtype=jnp.int32) < nd
     return jnp.where(live[None, :], flat, 0.0)

@@ -1,9 +1,8 @@
 """Exact integer segment sums on TPU without 64-bit device arithmetic.
 
 TPUs have no native int64/float64; JAX's x64 mode emulates them (pairs of
-u32 + X64Combine), which doubles transfer sizes and parameter counts and
-costs extra tunnel round trips on remote devices. This module provides the
-x64-free primitive the aggregation kernels are built on:
+u32 + X64Combine), which doubles transfer sizes and parameter counts.
+This module provides the x64-free primitive the aggregation kernels are built on:
 
     per-row int32 values -> int32[limbs, 2, segments] partials
     (every partial is exactly representable; the host recombines to int64)

@@ -1,7 +1,8 @@
 """ctypes bindings for the C++ ordered-KV engine (native/kvstore.cpp).
 
-Builds the shared library on first use (g++ is part of the toolchain; no
-pybind11 in this environment, hence the plain C ABI). `NativeOrderedKV`
+Runs `make` on first use in every process (a no-op when the library is
+newer than its source, so a stale binary from another checkout is never
+loaded unseen; no pybind11 in this environment, hence the plain C ABI). `NativeOrderedKV`
 is interface-identical to mvcc.PyOrderedKV, so `MVCCStore(NativeOrderedKV())`
 swaps the substrate without touching percolator logic.
 """
@@ -9,6 +10,9 @@ swaps the substrate without touching percolator logic.
 from __future__ import annotations
 
 import ctypes
+import fcntl
+import os
+import shutil
 import subprocess
 import threading
 from pathlib import Path
@@ -32,11 +36,16 @@ _lib_lock = threading.Lock()
 
 
 class NativeUnavailable(RuntimeError):
+    """No toolchain on this host (or no preloaded ASan runtime): the
+    Python twin is the engine. A build that RUNS and fails is not this —
+    it raises NativeBuildError and is nobody's fallback."""
+
+
+class NativeBuildError(RuntimeError):
     pass
 
 
 def _sanitize_requested() -> bool:
-    import os
     # same falsy spellings as lockcheck's env parsing
     return os.environ.get(SANITIZE_ENV, "") not in ("", "0", "false",
                                                     "off")
@@ -49,12 +58,22 @@ def _load() -> ctypes.CDLL:
             return _lib
         so, target = (_SO_ASAN, "sanitize") if _sanitize_requested() \
             else (_SO, "all")
-        if not so.exists():
+        cxx = os.environ.get("CXX", "g++")
+        if shutil.which("make") is None or shutil.which(cxx) is None:
+            raise NativeUnavailable(
+                f"cannot build {so.name}: no make/{cxx} on this host")
+        # the Makefile doubles as the cross-process build lock: sibling
+        # servers starting together must not write the .so concurrently
+        with open(_NATIVE_DIR / "Makefile", "rb") as lockf:
+            fcntl.flock(lockf, fcntl.LOCK_EX)
             try:
                 subprocess.run(["make", "-C", str(_NATIVE_DIR), target],
-                               check=True, capture_output=True, timeout=120)
-            except (subprocess.CalledProcessError, OSError) as e:
-                raise NativeUnavailable(f"cannot build {so}: {e}") from e
+                               check=True, capture_output=True,
+                               timeout=120)
+            except subprocess.CalledProcessError as e:
+                raise NativeBuildError(
+                    f"building {so.name} from native/kvstore.cpp failed:"
+                    f"\n{e.stderr.decode(errors='replace')}") from e
         try:
             lib = ctypes.CDLL(str(so))
         except OSError as e:

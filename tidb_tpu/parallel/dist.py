@@ -25,15 +25,11 @@ from typing import Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .. import obs
 from ..copr.client import CopClient
-
-try:  # jax >= 0.5 exports shard_map at top level
-    shard_map = jax.shard_map
-except AttributeError:  # 0.4.x: the experimental home
-    from jax.experimental.shard_map import shard_map
 
 AXIS = "shard"
 

@@ -7,10 +7,10 @@ store/tikv/mpp.go:372 DispatchMPPTasks, executor/mpp_gather.go:103). The
 TPU equivalent keeps whole snowflake join trees inside ONE fused device
 program: dimension ("build") tables become device-resident lookup tables,
 the fact ("probe") table streams through gather-joins, and the post-join
-selection/aggregation reuses the single-table kernel machinery. On a
-remote TPU every synchronous round trip costs ~100ms, so fusing the whole
-join pipeline into one dispatch+fetch is the difference between one RTT
-and five.
+selection/aggregation reuses the single-table kernel machinery. Fusing
+the whole join pipeline into one dispatch+fetch keeps the joined
+intermediates out of HBM and leaves one host sync per query instead of
+one per join.
 
 Eligibility (recognized bottom-up over the physical plan):
 

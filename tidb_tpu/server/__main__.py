@@ -14,6 +14,7 @@ import signal
 import sys
 import threading
 
+from .. import device
 from ..config import Config, ConfigError
 from ..store.storage import Storage
 from .server import Server
@@ -183,6 +184,12 @@ def main(argv: list[str] | None = None) -> int:
         return 1
 
     cfg.apply_log_level()
+    # one process owns the chip: claim it now (and say which device it
+    # is) rather than at the first analytic statement, where a held or
+    # missing chip would surface as JAX's silent fall back to the CPU
+    cache_dir = device.configure_compile_cache()
+    print(f"tidb-tpu-server device: {device.line(device.describe())} "
+          f"compile-cache={cache_dir}", flush=True)
     # [analysis] lock-check arms the dynamic lock-order checker BEFORE
     # any storage/lock creation — only locks created after enable()
     # are instrumented (env TIDB_TPU_LOCK_CHECK is the no-config path)
@@ -241,8 +248,8 @@ def main(argv: list[str] | None = None) -> int:
     # interval re-reads tidb_gc_run_interval every cycle (reference:
     # gcworker started with the store, gc_worker.go:95)
     storage.maintenance.start()
-    print(f"tidb-tpu-server listening on {cfg.host}:{srv.port}",
-          flush=True)
+    print(f"tidb-tpu-server listening on {cfg.host}:{srv.port} "
+          f"(kv engine {storage.kv_engine})", flush=True)
     if storage.rpc_server is not None:
         print(f"coordination rpc on {storage.rpc_server.address}",
               flush=True)

@@ -114,6 +114,12 @@ class StatusServer:
                         plane = getattr(st, "ranges", None)
                         if plane is not None:
                             status["ranges"] = plane.status()
+                    # which accelerator the process is on — present
+                    # once a backend exists (a scrape never creates one)
+                    from .. import device as _device
+                    dev = _device.described()
+                    if dev is not None:
+                        status["device"] = dev
                     # mesh data plane: device count + per-device
                     # sharded-epoch bytes (never grabs a backend as a
                     # scrape side effect — copr/mesh.status is lazy)

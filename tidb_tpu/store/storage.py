@@ -62,16 +62,15 @@ class TxnTooLargeError(CodedError):
 
 def _make_engine(path: Optional[str] = None, sync_log: str = "off",
                  sync_interval_ms: int = 100):
-    """C++ ordered-KV engine when buildable, pure-python twin otherwise.
-    With `path`, either engine opens WAL+snapshot files there (shared
-    format, native/kvstore.cpp) and honors the sync-log policy."""
-    try:
-        from ..kv.native import NativeOrderedKV, native_available
-        if native_available():
-            return NativeOrderedKV(path, sync_log=sync_log,
-                                   sync_interval_ms=sync_interval_ms)
-    except Exception:
-        pass
+    """C++ ordered-KV engine where the host has a toolchain, the
+    pure-python twin where it has none (a build that fails is an error,
+    not a fallback; `Storage.kv_engine` names the one in use). With
+    `path`, either engine opens WAL+snapshot files there (shared format,
+    native/kvstore.cpp) and honors the sync-log policy."""
+    from ..kv.native import NativeOrderedKV, native_available
+    if native_available():
+        return NativeOrderedKV(path, sync_log=sync_log,
+                               sync_interval_ms=sync_interval_ms)
     if path is not None:
         from ..kv.mvcc import PyOrderedKV
         return PyOrderedKV(path, sync_log=sync_log,
@@ -536,6 +535,13 @@ class Storage:
         for fn in self._epoch_listeners:
             if fn not in store.evict_hooks:
                 store.evict_hooks.append(fn)
+
+    @property
+    def kv_engine(self) -> str:
+        """Class name of the ordered-KV substrate in use:
+        NativeOrderedKV (C++, native/kvstore.cpp), PyOrderedKV (the
+        Python twin; also the shared-directory mode) or RemoteKV."""
+        return type(self.kv.kv).__name__
 
     def add_epoch_listener(self, fn) -> None:
         """Attach `fn(store)` to fire after every base-epoch
