@@ -403,6 +403,15 @@ def _diag_threads() -> list[threading.Thread]:
 
 
 def test_shutdown_leaves_no_diag_threads(tmp_path):
+    # held to the threads started after this line: the names are looked
+    # up in the whole process, and under xdist a sibling test's Storage
+    # that was served and never closed (Server.start starts the store's
+    # sampler; only Storage.close joins it) would fail this one
+    before = set(_diag_threads())
+
+    def mine() -> list[threading.Thread]:
+        return [t for t in _diag_threads() if t not in before]
+
     leader = Storage(str(tmp_path / "leader"), shared=True,
                      rpc_listen="127.0.0.1:0", rpc_options=OPTS)
     follower = Storage(str(tmp_path / "follower"),
@@ -411,12 +420,12 @@ def test_shutdown_leaves_no_diag_threads(tmp_path):
     s = Session(leader)
     assert len(s.execute("select instance from "
                          "information_schema.cluster_info").rows) == 2
-    assert _diag_threads()  # sampler + follower listener are live
+    assert mine()  # sampler + follower listener are live
     follower.close()
     leader.close()
     # generous deadline: on a loaded CI box the joins themselves are
     # slow; what matters is that they HAPPEN (no thread survives)
     deadline = time.monotonic() + 15.0
-    while _diag_threads() and time.monotonic() < deadline:
+    while mine() and time.monotonic() < deadline:
         time.sleep(0.05)
-    assert _diag_threads() == []  # close() joined them, nothing leaked
+    assert mine() == []  # close() joined them, nothing leaked

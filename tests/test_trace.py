@@ -568,3 +568,468 @@ def test_dominant_wait_inspection_rule():
     finally:
         wp.configure(enabled=False)
         wp.clear()
+
+
+# ==================== the served path's stage timeline ====================
+#
+# obs.stage over the whole served path (socket -> fetch): every command
+# moves each stage that applies to it once; the stages are exclusive; the
+# few clocked brackets (command, exec, device_get) are on two clocks (wall,
+# thread CPU); and under a jax profiler session the stages are events of
+# the host plane.
+
+import contextlib
+import glob
+import json
+import os
+import subprocess
+import sys
+import time
+
+from mysql_client import MiniClient, MySQLError
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+# the stages this PR put outside the coprocessor, in path order
+SERVED_STAGES = ("wire_queue", "wire_read", "parse", "admission", "exec",
+                 "epilogue", "encode", "wire_write", "wire_repark")
+# what EXPLAIN ANALYZE's `stages` cell could hold before them
+COPR_CELL_STAGES = {"prepare", "staging", "transfer", "compile", "kernel",
+                    "device_get", "merge", "shard", "reshard",
+                    "host_fallback", "ranged"}
+SERVED_SQL = {
+    "select": Q6,
+    "point": "select l_quantity from lineitem where l_orderkey = 7",
+    "update": "update lineitem set l_quantity = l_quantity + 1 "
+              "where l_orderkey = 7",
+}
+
+
+def _stage_snapshot() -> dict:
+    """{stage: (wall sum, count)}, {clocked bracket: (wall, off-CPU)} and
+    the command histogram's (sum, count), read in-process."""
+    stages = {dict(key)["stage"]: (total, n)
+              for key, _, total, n in obs.DISPATCH_STAGE_SECONDS.series()
+              if key}
+    off = {dict(k)["stage"]: v
+           for k, v in obs.DISPATCH_STAGE_OFFCPU.samples()}
+    clocked = {dict(k)["stage"]: (v, off[dict(k)["stage"]])
+               for k, v in obs.DISPATCH_STAGE_CLOCKED.samples()}
+    _, cmd_sum, cmd_n = obs.CONN_COMMAND_SECONDS.snapshot()
+    return {"stages": stages, "clocked": clocked,
+            "command": (cmd_sum, cmd_n)}
+
+
+def _moved(before: dict, after: dict, name: str) -> tuple:
+    """What one entry of a snapshot's `stages` or `clocked` moved by."""
+    return tuple(a - b for a, b in
+                 zip(after[name], before.get(name, (0.0, 0.0))))
+
+
+def _reparks() -> int:
+    return obs.DISPATCH_STAGE_SECONDS.snapshot(stage="wire_repark")[2]
+
+
+def _await_repark(n: int) -> None:
+    """Until the reactor has booked one more `wire_repark` than `n`: it
+    watches the socket again, so the next command starts from a wake."""
+    deadline = time.monotonic() + 10
+    while _reparks() <= n and time.monotonic() < deadline:
+        time.sleep(0.002)
+    assert _reparks() > n
+
+
+def _settled(client, sql) -> None:
+    """One command, and the reactor watching its socket again: the
+    re-park is booked by the reactor's thread after the answer left."""
+    n = _reparks()
+    client.query(sql)
+    _await_repark(n)
+
+
+@contextlib.contextmanager
+def _serving(token_limit: int = 0):
+    """A Server over the Q6 corpus and one wire client."""
+    from tidb_tpu.server.server import Server
+
+    tk = _q6_kit()
+    tk.session.storage.admission.configure(tokens=token_limit)
+    srv = Server(tk.session.storage, port=0, status_port=0)
+    srv.start()
+    n = _reparks()
+    c = MiniClient("127.0.0.1", srv.port, db="test")
+    try:
+        _await_repark(n)  # the handshake parks the connection too
+        yield c, tk.session
+    finally:
+        c.close()
+        srv.close()
+        tk.session.storage.close()  # joins the sampler Server.start began
+
+
+@pytest.fixture(scope="module")
+def served_deltas():
+    """Per statement of SERVED_SQL the stage snapshot before and after
+    ONE warm command over the wire. The server is gone again before the
+    first test's own fixtures run (conftest counts listening sockets
+    per test)."""
+    deltas = {}
+    with _serving(token_limit=8) as (c, _):  # a gate, so `admission` is one
+        for name, sql in SERVED_SQL.items():
+            _settled(c, sql)  # warm: compile, plan cache, worker thread
+            # a thread reads its CPU clock for one command in this long
+            time.sleep(2 * obs._CLOCK_EVERY_S)
+            before = _stage_snapshot()
+            _settled(c, sql)
+            deltas[name] = (before, _stage_snapshot())
+    return deltas
+
+
+@pytest.mark.parametrize("stage", SERVED_STAGES)
+@pytest.mark.parametrize("stmt", sorted(SERVED_SQL))
+def test_served_command_moves_each_stage_once(served_deltas, stmt, stage):
+    """A SELECT, a point SELECT and an UPDATE over the wire each pass
+    every stage outside the coprocessor exactly once (each is the first
+    command after a wake). `admission` is the WAIT for a token: all
+    three pass the gate, which the fixture limits to 8 tokens, none
+    waits there, and none books it."""
+    before, after = served_deltas[stmt]
+    n0 = before["stages"].get(stage, (0.0, 0))[1]
+    n1 = after["stages"].get(stage, (0.0, 0))[1]
+    assert n1 - n0 == (0 if stage == "admission" else 1)
+
+
+@pytest.mark.parametrize("stmt", sorted(SERVED_SQL))
+def test_served_stages_are_exclusive_and_inside_the_command(served_deltas,
+                                                            stmt):
+    """Per command: no stage moved by more than the coprocessor's own
+    per-tile repeats allow; the exclusive stage seconds inside the
+    command sum to no more than the command's; the clocked brackets
+    (`command` = the command outside `exec`, `exec` = the executor with
+    every stage in it but `device_get`) are never more off the CPU than
+    their wall, and together with the hand-off they are the command."""
+    before, after = served_deltas[stmt]
+    assert after["command"][1] - before["command"][1] == 1
+    whole = after["command"][0] - before["command"][0]
+    inside = 0.0
+    for stage in after["stages"]:
+        total, n = _moved(before["stages"], after["stages"], stage)
+        if stage not in COPR_CELL_STAGES:
+            assert n <= 1, stage
+        if stage != "wire_repark":  # booked after the command's end
+            inside += total
+    assert 0 < inside <= whole
+    brackets = 0.0
+    for name in after["clocked"]:
+        wall, off = _moved(before["clocked"], after["clocked"], name)
+        assert -1e-9 <= off <= wall + 1e-9, name
+        if name != "wire_repark":
+            brackets += wall
+    for name in ("command", "exec", "wire_queue"):
+        assert _moved(before["clocked"], after["clocked"], name)[0] > 0
+    # the brackets tile the command: reactor's stamp to the write's end
+    assert brackets == pytest.approx(whole, rel=1e-6)
+    in_exec = sum(_moved(before["stages"], after["stages"], s)[0]
+                  for s in after["stages"]
+                  if s in COPR_CELL_STAGES - {"device_get"}
+                  or s in ("exec", "fast_plan", "plan_build", "prepare"))
+    assert in_exec <= _moved(before["clocked"], after["clocked"],
+                             "exec")[0] + 1e-9
+
+
+def _burn(seconds: float) -> None:
+    end = time.thread_time() + seconds
+    while time.thread_time() < end:
+        pass
+
+
+def test_offcpu_reads_a_wait_apart_from_work():
+    """Two clocks per clocked bracket: one whose thread waited books the
+    wait as off-CPU, one that computed books (almost) none, a nested
+    bracket's wait is its own and not its parent's, and an unclocked
+    stage's wait is its enclosing bracket's."""
+    before = _stage_snapshot()
+    with obs.stage("t_outer", clocked=True):
+        time.sleep(0.03)
+        with obs.stage("t_wait", clocked=True):
+            time.sleep(0.02)
+        with obs.stage("t_work", clocked=True):
+            _burn(0.02)
+        with obs.stage("t_plain"):
+            time.sleep(0.01)
+    after = _stage_snapshot()
+    wall, off = _moved(before["clocked"], after["clocked"], "t_wait")
+    assert wall >= 0.02 and 0.015 <= off <= wall
+    wall, off = _moved(before["clocked"], after["clocked"], "t_work")
+    assert wall >= 0.02 and off <= 0.005  # the CPU it burned
+    # its own sleep and t_plain's; not t_wait's
+    wall, off = _moved(before["clocked"], after["clocked"], "t_outer")
+    assert 0.04 <= wall < 0.06 and 0.035 <= off <= wall
+    assert "t_plain" not in after["clocked"]
+    # the stage histogram is exclusive of EVERY nested stage, as before
+    assert 0.03 <= _moved(before["stages"], after["stages"],
+                          "t_outer")[0] < 0.04
+
+
+@pytest.mark.parametrize("wait_ms", [0.3, 0.6, 1.0])
+def test_offcpu_keeps_short_neighbouring_waits_apart(wait_ms):
+    """The scale of a point read: a bracket of a few hundred microseconds
+    that only waits, between two that only compute, keeps its wait: none
+    of it lands on a neighbour and nothing between brackets lands in
+    one (each reading is from the bracket's own two edges)."""
+    wait = wait_ms / 1e3
+    before = _stage_snapshot()["clocked"]
+    rounds = 50
+    for _ in range(rounds):
+        with obs.stage("t_pre", clocked=True):
+            _burn(0.0005)
+        time.sleep(wait)  # between brackets: belongs to none
+        with obs.stage("t_mid", clocked=True):
+            time.sleep(wait)
+        with obs.stage("t_post", clocked=True):
+            _burn(0.0005)
+    after = _stage_snapshot()["clocked"]
+    wall, off = _moved(before, after, "t_mid")
+    assert wall >= rounds * wait
+    assert 0.8 * wall <= off <= wall  # sleep()'s own entry and exit run
+    for name in ("t_pre", "t_post"):
+        wall, off = _moved(before, after, name)
+        # what is not off-CPU is the CPU time it burned, whatever a busy
+        # host preempted; a wait carried in would eat into it
+        assert wall - off >= 0.95 * rounds * 0.0005, (name, wall, off)
+        assert off <= wall
+
+
+def test_a_thread_clocks_one_command_in_a_while(monkeypatch):
+    """The CPU clock is a system call: of a stream of quick commands on
+    one thread only one per _CLOCK_EVERY_S pays its four reads and books
+    its brackets; every command's stages reach the histogram."""
+    reads = []
+    real = time.thread_time
+    monkeypatch.setattr(obs.time, "thread_time",
+                        lambda: reads.append(1) or real())
+    n0 = obs.DISPATCH_STAGE_SECONDS.snapshot(stage="t_cmd_parse")[2]
+
+    def serve(n: int) -> None:
+        cmd = obs.command_begin(0.0)
+        for _ in range(n):
+            with obs.stage("t_cmd_parse"):
+                pass
+            with obs.stage("t_cmd_exec", clocked=True):
+                pass
+            cmd.end()
+        cmd.close()
+
+    seen = []
+
+    def run() -> None:  # a thread of its own: a clock never read yet
+        t0 = time.perf_counter()
+        serve(50)
+        seen.append((time.perf_counter() - t0, len(reads)))
+        time.sleep(1.2 * obs._CLOCK_EVERY_S)
+        serve(1)
+        seen.append(len(reads))
+
+    t = threading.Thread(target=run)
+    t.start()
+    t.join()
+    (took, first), after = seen
+    # begin, exec's two edges, end: four reads, for the first command
+    # (and one more command per _CLOCK_EVERY_S a slow host took)
+    assert first % 4 == 0
+    assert 4 <= first <= 4 * (1 + int(took / obs._CLOCK_EVERY_S))
+    assert after == first + 4
+    assert obs.DISPATCH_STAGE_SECONDS.snapshot(
+        stage="t_cmd_parse")[2] - n0 == 51
+
+
+def test_admission_is_the_wait_in_the_gate():
+    """`admission` is booked inside AdmissionGate, by the statement that
+    waits for a token, once, for as long as it waited, nested in `exec`;
+    a statement that finds a token free books nothing."""
+    from tidb_tpu.util.governor import AdmissionGate
+
+    gate = AdmissionGate(tokens=1)
+    n0 = obs.DISPATCH_STAGE_SECONDS.snapshot(stage="admission")
+    with gate.admit():
+        pass
+    assert obs.DISPATCH_STAGE_SECONDS.snapshot(stage="admission")[2] \
+        == n0[2]
+    assert gate.acquire() is True  # the one token is taken
+    t = threading.Timer(0.05, gate.release)
+    t.start()
+    try:
+        with obs.stage("t_exec"):
+            with gate.admit():
+                pass
+    finally:
+        t.join()
+    _, total, n = obs.DISPATCH_STAGE_SECONDS.snapshot(stage="admission")
+    assert n - n0[2] == 1 and total - n0[1] >= 0.04
+
+
+def test_served_stage_families_are_on_metrics(served_deltas):
+    text = obs.PROCESS_METRICS.render()
+    for stage in SERVED_STAGES:
+        if stage != "admission":  # nobody waited in the gate
+            assert ('tidb_dispatch_stage_duration_seconds_count'
+                    f'{{stage="{stage}"}}') in text
+    for bracket in ("command", "exec", "device_get", "wire_queue",
+                    "wire_repark"):
+        for family in ("clocked", "offcpu"):
+            assert (f'tidb_dispatch_stage_{family}_seconds_total'
+                    f'{{stage="{bracket}"}}') in text
+    assert "tidb_conn_command_seconds_sum" in text
+    assert obs.lint_metrics([obs.PROCESS_METRICS]) == []
+
+
+def test_served_path_allocates_no_spans_without_trace(monkeypatch):
+    """The no-Span-when-off pin, over the wire: the new sites (wire,
+    parse, admission, exec, epilogue, encode) build none either."""
+    made: list[str] = []
+    orig = obs.Span.__init__
+
+    def counting(self, name, start):
+        made.append(name)
+        orig(self, name, start)
+
+    with _serving() as (c, _):
+        for sql in SERVED_SQL.values():
+            _settled(c, sql)  # warm
+        monkeypatch.setattr(obs.Span, "__init__", counting)
+        gated = obs.DISPATCH_STAGE_SECONDS.snapshot(stage="admission")[2]
+        for sql in SERVED_SQL.values():
+            _settled(c, sql)
+    assert made == []
+    # an unlimited gate (the default) is no gate: no `admission` stage
+    assert obs.DISPATCH_STAGE_SECONDS.snapshot(
+        stage="admission")[2] == gated
+
+
+def test_import_obs_does_not_import_jax():
+    """A KV-only process stays jax-free: the stage mechanism takes the
+    profiler's annotation class only where jax is already loaded."""
+    p = subprocess.run(
+        [sys.executable, "-c",
+         "import sys; import tidb_tpu.obs as o\n"
+         "with o.stage('parse'): pass\n"
+         "o.note_stage('wire_queue', 0.001)\n"
+         "assert not [m for m in sys.modules if m == 'jax' or "
+         "m.startswith('jax.')], 'jax imported'"],
+        cwd=ROOT, capture_output=True, text=True, timeout=120)
+    assert p.returncode == 0, p.stderr[-2000:]
+
+
+def test_profiler_session_shows_stages_in_the_host_plane(tmp_path):
+    """Under jax.profiler.start_trace a served Q6 leaves titpu/kernel and
+    titpu/device_get in the host plane, on the profiler's clock, with the
+    program's name on them; the stage events are exclusive, so none
+    encloses both (an enclosing event would own every idle gap under it);
+    and the jitted program calls itself titpu_agg, not `kernel`."""
+    import jax
+    from jax.profiler import ProfileData
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    with _serving() as (c, session):
+        _settled(c, Q6)  # warm
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        try:
+            _settled(c, Q6)
+        finally:
+            jax.profiler.stop_trace()
+        # every compiled program of the session's client is named
+        progs = {getattr(k, "__name__", "")
+                 for k in session.cop._kernels.values()}
+    assert progs and all(p.startswith("titpu_") for p in progs), progs
+    path = sorted(glob.glob(str(
+        tmp_path / "plugins" / "profile" / "*" / "*.xplane.pb")))[-1]
+    events = []  # (start, end, name, stats) of the host plane
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name == "/host:CPU":
+            for line in plane.lines:
+                events += [(e.start_ns, e.start_ns + e.duration_ns, e.name,
+                            dict(e.stats)) for e in line.events]
+    names = {e[2] for e in events}
+    for stage in ("wire_read", "parse", "exec", "kernel", "device_get",
+                  "encode", "wire_write"):
+        assert f"titpu/{stage}" in names, sorted(names)[:40]
+    assert "titpu/wire_queue" not in names  # on no one thread's timeline
+    assert "PjitFunction(titpu_agg)" in names
+    kernel = next(e for e in events if e[2] == "titpu/kernel")
+    fetch = next(e for e in events if e[2] == "titpu/device_get"
+                 and e[0] >= kernel[1])
+    assert kernel[3]["prog"] == fetch[3]["prog"] == "titpu_agg"
+    assert kernel[3]["conn"] == fetch[3]["conn"] and "seq" in kernel[3]
+    both = [e[2] for e in events
+            if e[0] <= kernel[0] and e[1] >= fetch[1]]
+    assert both == []
+
+
+def _bench_statements() -> list[str]:
+    return sorted(f[:-5] for f in os.listdir(
+        os.path.join(ROOT, "benchmarks", "statements")))
+
+
+@pytest.fixture(scope="module")
+def bench_cells(tmp_path_factory):
+    """{class: (its statement file, the longest `stages` cell of its
+    EXPLAIN ANALYZE or the error it got)} for the benchmark's ten
+    statements, from the benchmark's own loader at rehearsal scale: both
+    configurations' tables in one in-memory store, served over the wire.
+    The system is closed again before the first test's own fixtures run
+    (conftest counts listening sockets per test)."""
+    sys.path.insert(0, ROOT)
+    from benchmarks.datagen import rf1
+    from benchmarks.harness import system as S
+
+    cfg = {}
+    for name in ("htap_sysbench_tpch10_1chip", "tpch_sf10_1chip"):
+        with open(os.path.join(ROOT, "benchmarks", "configs",
+                               name + ".json")) as f:
+            cfg.update(json.load(f))
+    cfg["storage"] = {"durable": False, "sync_log": "off"}
+    system = S.System(cfg, 7, cfg["rehearsal_scale"],
+                      str(tmp_path_factory.mktemp("bench")), lambda m: None)
+    cells = {}
+    try:
+        for cls in _bench_statements():
+            with open(os.path.join(ROOT, "benchmarks", "statements",
+                                   cls + ".json")) as f:
+                st = json.load(f)
+            if st.get("builder") == "rf1_lineitem":
+                sql = rf1.orders(system.data["lineitem"],
+                                 system.data["lineitem_vocab"], 7, 1)[0][0]
+            else:
+                sql = st["sql"].replace("{key}", "5")
+            c = MiniClient("127.0.0.1", system.port, db=st["db"])
+            try:
+                if st["op"] == "query":
+                    c.query(sql)  # warm: no compile stage in the cell
+                rows = c.query("explain analyze " + sql)
+                cells[cls] = (st, max((r[4] or "" for r in rows), key=len))
+            except MySQLError as e:
+                cells[cls] = (st, e)
+            finally:
+                c.close()
+    finally:
+        system.close()
+    return cells
+
+
+@pytest.mark.parametrize("cls", _bench_statements())
+def test_explain_analyze_stages_cell_keeps_its_names(bench_cells, cls):
+    """benchmarks/run.py reads the longest `stages` cell of an EXPLAIN
+    ANALYZE: for each of the benchmark's ten statements it holds the
+    coprocessor's stage names and none of the served path's new ones
+    (`exec` closes after the root node, `parse` before the first)."""
+    st, cell = bench_cells[cls]
+    if st["op"] != "query":
+        # the harness never explains a write; the program refuses
+        assert isinstance(cell, MySQLError) and "SELECT only" in str(cell)
+    elif st["kind"] == "point":
+        assert cell.startswith("plan_cache:")
+    else:
+        names = set(_parse_stages(cell))
+        assert {"staging", "kernel", "device_get"} <= names <= \
+            COPR_CELL_STAGES, cell

@@ -218,7 +218,8 @@ def device_column_stats(cop, snap, offsets: list[int]):
                 from .client import widen32
                 (d, v), = widen32([(d, v)])
                 return _column_partials(d, v & vis)
-            return jax.jit(kernel)
+            from .client import named_jit
+            return named_jit(kernel, "titpu_analyze")
 
         # one kernel per (dtype, bucket) — shared across all columns of
         # that width, so the first ANALYZE compiles a handful of tiny

@@ -1071,7 +1071,7 @@ class CopClient:
         return arr
 
     def _frag_jit(self, kernel, mode, prepared):
-        return jax.jit(kernel)
+        return named_jit(kernel, f"titpu_frag_{mode}")
 
     def _kernel(self, key, build):
         with self._lock:
@@ -1104,14 +1104,16 @@ class CopClient:
         # dispatches are async and queue on the device; ONE device_get
         # fetches every tile's partials with a single host sync
         from ..util import interrupt
-        with obs.stage("kernel", span_name="device.dispatch") as sp:
+        with obs.stage("kernel", span_name="device.dispatch",
+                       prog="titpu_agg") as sp:
             if sp:
                 sp.note = f"{len(tiles)} tile(s)"
             devs = []
             for cols, vis, _ in tiles:
                 interrupt.check()  # KILL QUERY checkpoint between tiles
                 devs.append(kern(cols, vis))
-        with obs.stage("device_get", span_name="device.fetch"):
+        with obs.stage("device_get", span_name="device.fetch", clocked=True,
+                       prog="titpu_agg"):
             outs = jax.device_get(devs)
         with obs.stage("merge"):
             out = _merge_tile_outs(outs, prepared["__agg_sched__"])
@@ -1127,7 +1129,7 @@ class CopClient:
 
     def _build_agg_kernel(self, dag, prepared, cards, segments):
         body = self._agg_kernel_body(dag, prepared, cards, segments)
-        return jax.jit(body)
+        return named_jit(body, "titpu_agg")
 
     def _agg_kernel_body(self, dag, prepared, cards, segments):
         """Pure (cols, row_mask) -> {partials} function. All leaves are
@@ -1163,9 +1165,11 @@ class CopClient:
         key = ("rowmask", _dag_key(dag, prepared), bucket)
         kern = self._kernel(key, lambda: self._build_rowmask_kernel(
             dag, prepared))
-        with obs.stage("kernel", span_name="device.dispatch"):
+        with obs.stage("kernel", span_name="device.dispatch",
+                       prog="titpu_rowmask"):
             devs = [kern(cols, vis) for cols, vis, _ in tiles]
-        with obs.stage("device_get", span_name="device.fetch"):
+        with obs.stage("device_get", span_name="device.fetch", clocked=True,
+                       prog="titpu_rowmask"):
             packs = jax.device_get(devs)
         parts = [
             np.unpackbits(packed, count=None).astype(bool)[:cnt]
@@ -1178,7 +1182,7 @@ class CopClient:
         return self._host_rows(dag, snap, host_cols, idx)
 
     def _build_rowmask_kernel(self, dag, prepared):
-        return jax.jit(self._rowmask_body(dag, prepared))
+        return named_jit(self._rowmask_body(dag, prepared), "titpu_rowmask")
 
     def _rowmask_body(self, dag, prepared):
         sel = dag.selection
@@ -1233,9 +1237,11 @@ class CopClient:
                tuple(d for _, d in dag.topn.items))
         kern = self._kernel(key, lambda: self._build_topn_kernel(
             dag, prepared, expr, desc, n))
-        with obs.stage("kernel", span_name="device.dispatch"):
+        with obs.stage("kernel", span_name="device.dispatch",
+                       prog="titpu_topn"):
             devs = [kern(cols, vis) for cols, vis, _ in tiles]
-        with obs.stage("device_get", span_name="device.fetch"):
+        with obs.stage("device_get", span_name="device.fetch", clocked=True,
+                       prog="titpu_topn"):
             outs = jax.device_get(devs)
         chunks = []
         for out in outs:
@@ -1275,7 +1281,8 @@ class CopClient:
         return Chunk(columns)
 
     def _build_topn_kernel(self, dag, prepared, expr, desc, n):
-        return jax.jit(self._topn_body(dag, prepared, expr, desc, n))
+        return named_jit(self._topn_body(dag, prepared, expr, desc, n),
+                         "titpu_topn")
 
     def _topn_body(self, dag, prepared, expr, desc, n):
         sel = dag.selection
@@ -1377,6 +1384,15 @@ class CopClient:
             columns.append(Column(ft, np.empty(0, ft.np_dtype), None,
                                   dictionary))
         return Chunk(columns)
+
+
+def named_jit(fn, name: str):
+    """jax.jit(fn) under the program's own name, taken from the key it
+    is cached under (and adding nothing to that key): the host event
+    reads PjitFunction(<name>) and the XLA module jit_<name>, where
+    every program used to be `kernel` or `body`."""
+    fn.__name__ = fn.__qualname__ = name
+    return jax.jit(fn)
 
 
 class _FirstCallCompile:

@@ -47,6 +47,7 @@ from .client import (
     CopResult,
     agg_partials,
     decode_agg_partials,
+    named_jit,
     widen32,
 )
 from .eval import CompileError, DeviceError, eval_expr, selection_mask
@@ -642,11 +643,13 @@ def _run_frag_batch(cop, frag, snaps, prepared, spans, builds, overlay,
     kern = cop._kernel(key, lambda: cop._frag_jit(
         _build_frag_kernel(frag, prepared, spans, mode, raw=True, cop=cop),
         mode, prepared))
+    prog = f"titpu_frag_{mode}"
     with obs.operator(_mode_op(frag, mode)):
-        with obs.stage("kernel", span_name="device.dispatch"):
+        with obs.stage("kernel", span_name="device.dispatch", prog=prog):
             dev = kern(pcols, pvis, kern_builds) if aux is None \
                 else kern(pcols, pvis, kern_builds, aux)
-        with obs.stage("device_get", span_name="device.fetch"):
+        with obs.stage("device_get", span_name="device.fetch",
+                       clocked=True, prog=prog):
             out = jax.device_get(dev)
 
     if mode == "hc":
@@ -707,10 +710,12 @@ def _run_frag_tiled(cop, frag, snaps, prepared, spans, builds, mode):
         from ..util import interrupt
         interrupt.check()
         with obs.operator(kop), \
-                obs.stage("kernel", span_name="device.dispatch"):
+                obs.stage("kernel", span_name="device.dispatch",
+                          prog=f"titpu_frag_{mode}"):
             devs.append(kern(cols, vis, kb))
     with obs.operator(kop), \
-            obs.stage("device_get", span_name="device.fetch"):
+            obs.stage("device_get", span_name="device.fetch", clocked=True,
+                      prog=f"titpu_frag_{mode}"):
         outs = jax.device_get(devs)
 
     if mode == "agg":
@@ -1268,7 +1273,7 @@ def _build_frag_kernel(frag, prepared, spans, mode, raw=False, cop=None):
             return res
         return jnp.packbits(mask)
 
-    return kernel if raw else jax.jit(kernel)
+    return kernel if raw else named_jit(kernel, f"titpu_frag_{mode}")
 
 
 def _maybe_fused_cut(frag, prepared, res):

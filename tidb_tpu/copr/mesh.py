@@ -64,7 +64,7 @@ from ..parallel.dist import AXIS, DistCopClient, _collective_merge, \
     make_mesh, shard_map
 from ..util import failpoint
 from .client import CopClient, _FirstCallCompile, _dag_key, _obj_nbytes, \
-    widen32
+    named_jit, widen32
 from .eval import selection_mask
 
 
@@ -789,7 +789,7 @@ class MeshCopClient(DistCopClient):
                            in_specs=(P(AXIS), P(AXIS)),
                            out_specs=(P(), P(AXIS)))
         return self._with_shard_stats(
-            jax.jit(mapped), "agg",
+            named_jit(mapped, "titpu_mesh_agg"), "agg",
             _plan_digest("agg", _dag_key(dag, prepared)))
 
     def _build_topn_kernel(self, dag, prepared, expr, desc, n):
@@ -812,7 +812,7 @@ class MeshCopClient(DistCopClient):
                            in_specs=(P(AXIS), P(AXIS)),
                            out_specs=(P(None, AXIS), P(AXIS)))
         return self._with_shard_stats(
-            jax.jit(mapped), "topn",
+            named_jit(mapped, "titpu_mesh_topn"), "topn",
             _plan_digest("topn", _dag_key(dag, prepared)))
 
     def _build_rowmask_kernel(self, dag, prepared):
@@ -833,7 +833,7 @@ class MeshCopClient(DistCopClient):
                            in_specs=(P(AXIS), P(AXIS)),
                            out_specs=(P(AXIS), P(AXIS)))
         return self._with_shard_stats(
-            jax.jit(mapped), "rows",
+            named_jit(mapped, "titpu_mesh_rows"), "rows",
             _plan_digest("rows", _dag_key(dag, prepared)))
 
     def _frag_jit(self, kernel, mode, prepared):
@@ -853,10 +853,10 @@ class MeshCopClient(DistCopClient):
                                    _rows_partial_total(out["rows"]))
                 return _collective_merge(out, sched), stats
 
-            fn = jax.jit(shard_map(
+            fn = named_jit(shard_map(
                 merged, mesh=self.mesh,
                 in_specs=(P(AXIS), P(AXIS), build_specs),
-                out_specs=(P(), P(AXIS))))
+                out_specs=(P(), P(AXIS))), "titpu_mesh_frag_agg")
         elif mode == "hc":
             # DistCopClient's hc specs, with the per-shard stats riding
             # along; post-exchange survivors are not observable outside
@@ -870,10 +870,10 @@ class MeshCopClient(DistCopClient):
                                    jnp.int32(-1))
                 return res, stats
 
-            fn = jax.jit(shard_map(
+            fn = named_jit(shard_map(
                 hc_body, mesh=self.mesh,
                 in_specs=(P(AXIS), P(AXIS), build_specs),
-                out_specs=(specs, P(AXIS))))
+                out_specs=(specs, P(AXIS))), "titpu_mesh_frag_hc")
         elif mode == "topn":
             # fused join+topn: per-shard top-n candidate rows concatenate
             # along the k axis; survivors are not observable outside the
@@ -884,10 +884,10 @@ class MeshCopClient(DistCopClient):
                                    jnp.int32(-1))
                 return res, stats
 
-            fn = jax.jit(shard_map(
+            fn = named_jit(shard_map(
                 tp_body, mesh=self.mesh,
                 in_specs=(P(AXIS), P(AXIS), build_specs),
-                out_specs=(P(None, AXIS), P(AXIS))))
+                out_specs=(P(None, AXIS), P(AXIS))), "titpu_mesh_frag_topn")
         else:
             # rows mode: the packed bitmask is already P(AXIS)-sharded;
             # each device's slice popcounts to its survivors at collect

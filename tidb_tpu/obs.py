@@ -13,8 +13,10 @@ via SHOW SLOW QUERIES and the HTTP status port.
 from __future__ import annotations
 
 import logging
+import sys
 import threading
 import time
+from bisect import bisect_left
 from collections import deque
 from typing import Optional
 
@@ -31,9 +33,19 @@ class Counter:
         self._lock = threading.Lock()
 
     def inc(self, amount: float = 1.0, **labels) -> None:
-        key = tuple(sorted(labels.items()))
+        self.inc_key(tuple(sorted(labels.items())), amount)
+
+    def inc_key(self, key: tuple, amount: float) -> None:
+        """inc() for a caller on a hot path that keeps its sorted
+        label tuple (the stage mechanism: one per stage name)."""
+        self.inc_keys(((key, amount),))
+
+    def inc_keys(self, items) -> None:
+        """inc_key() for each (key, amount) under one lock."""
+        values = self._values
         with self._lock:
-            self._values[key] = self._values.get(key, 0.0) + amount
+            for key, amount in items:
+                values[key] = values.get(key, 0.0) + amount
 
     def get(self, **labels) -> float:
         key = tuple(sorted(labels.items()))
@@ -107,19 +119,26 @@ class Histogram:
         self._lock = threading.Lock()
 
     def observe(self, v: float, **labels) -> None:
-        key = tuple(sorted(labels.items()))
+        self.observe_key(tuple(sorted(labels.items())), v)
+
+    def observe_key(self, key: tuple, v: float) -> None:
+        """observe() for a caller on a hot path that keeps its sorted
+        label tuple (the stage mechanism: one per stage name)."""
+        self.observe_keys(((key, v),))
+
+    def observe_keys(self, items) -> None:
+        """observe_key() for each (key, v) under one lock (the stage
+        mechanism hands over a command's stages at once)."""
+        series, buckets = self._series, self.buckets
         with self._lock:
-            s = self._series.get(key)
-            if s is None:
-                s = self._series[key] = [
-                    [0] * (len(self.buckets) + 1), 0.0, 0]
-            s[1] += v
-            s[2] += 1
-            for i, b in enumerate(self.buckets):
-                if v <= b:
-                    s[0][i] += 1
-                    return
-            s[0][-1] += 1
+            for key, v in items:
+                s = series.get(key)
+                if s is None:
+                    s = series[key] = [[0] * (len(buckets) + 1), 0.0, 0]
+                s[1] += v
+                s[2] += 1
+                # first bucket with v <= bound; past the last: +Inf's
+                s[0][bisect_left(buckets, v)] += 1
 
     def snapshot(self, **labels):
         """(counts, sum, total) for one label set (default: unlabeled)."""
@@ -890,7 +909,8 @@ class Observability:
                     mem_peak: int = 0, spill_count: int = 0,
                     op_wall: Optional[dict[str, float]] = None,
                     mesh_skew: float = 0.0,
-                    waits: Optional[dict[str, float]] = None) -> None:
+                    waits: Optional[dict[str, float]] = None,
+                    offcpu: Optional[dict[str, float]] = None) -> None:
         self.slow_counter.inc()
         ent = {
             "ts": time.strftime("%Y-%m-%d %H:%M:%S"),
@@ -902,6 +922,11 @@ class Observability:
             "plan_digest": plan_digest,
             "stages": {k: round(v * 1e3, 3)
                        for k, v in (stages or {}).items()},
+            # of the clocked brackets' ms (exec, device_get), those the
+            # thread spent off the CPU: a slow statement that waited
+            # reads apart from one that computed
+            "stages_offcpu": {k: round(v * 1e3, 3)
+                              for k, v in (offcpu or {}).items()},
             # per-operator exclusive wall (ms): which plan operator of
             # this digest spent the time — the slow-log half of the
             # Top SQL attribution plane
@@ -986,8 +1011,27 @@ FRAG_FALLBACKS = PROCESS_METRICS.counter(
     "device-fragment gate rejections, by reason")
 DISPATCH_STAGE_SECONDS = PROCESS_METRICS.histogram(
     "tidb_dispatch_stage_duration_seconds",
-    "per-stage dispatch wall time (staging, compile, transfer, kernel, "
-    "device_get, host_fallback), labeled by stage")
+    "exclusive wall time of one obs.stage, labeled by stage: every layer "
+    "of the served path from the socket to the fetch (the README's "
+    "stage vocabulary lists them)")
+DISPATCH_STAGE_CLOCKED = PROCESS_METRICS.counter(
+    "tidb_dispatch_stage_clocked_seconds_total",
+    "wall time of the brackets whose thread CPU clock is read at both "
+    "edges (command = a wire command outside exec, exec = the executor "
+    "and every stage nested in it but device_get, device_get; "
+    "wire_queue / wire_repark = thread hand-offs), each exclusive of "
+    "the brackets nested in it: the divisor of an off-CPU share")
+DISPATCH_STAGE_OFFCPU = PROCESS_METRICS.counter(
+    "tidb_dispatch_stage_offcpu_seconds_total",
+    "the part of tidb_dispatch_stage_clocked_seconds_total its thread "
+    "spent off the CPU (wall - thread CPU time, from the same two "
+    "edges): waiting for the interpreter lock, a lock, the device, the "
+    "socket or a thread hand-off, by bracket")
+CONN_COMMAND_SECONDS = PROCESS_METRICS.histogram(
+    "tidb_conn_command_seconds",
+    "one wire command from the reactor's select returning its socket "
+    "(or the previous command's end, when pipelined) to the end of its "
+    "response's socket write: the whole the stages inside it add up to")
 COL_CACHE = PROCESS_METRICS.counter(
     "tidb_copr_column_cache_total",
     "device column-staging cache lookups, by result (hit / miss)")
@@ -1537,12 +1581,20 @@ class StageRecorder:
     '(session)'), and `op_bytes` (host->device transfer bytes per
     operator, fed by the copr client's staging accounting)."""
 
-    __slots__ = ("totals", "counts", "op_wall", "ops", "op_bytes",
-                 "op_mesh", "engines")
+    __slots__ = ("totals", "counts", "offcpu", "op_wall", "ops",
+                 "op_bytes", "op_mesh", "engines", "conn", "seq")
 
-    def __init__(self) -> None:
+    def __init__(self, conn: int = 0, seq: int = 0) -> None:
         self.totals: dict[str, float] = {}
         self.counts: dict[str, int] = {}
+        # of the clocked brackets (exec, device_get): the part of the
+        # bracket's time its thread was off the CPU (wall - thread
+        # CPU): waiting, not computing
+        self.offcpu: dict[str, float] = {}
+        # the statement's identity (connection id, the session's
+        # statement number): metadata of its stages' profiler events
+        self.conn = conn
+        self.seq = seq
         self.op_wall: dict[str, float] = {}
         self.ops: dict[str, dict[str, float]] = {}
         self.op_bytes: dict[str, int] = {}
@@ -1554,9 +1606,11 @@ class StageRecorder:
         # — the path-decision record bench.py persists per timed query
         self.engines: list[str] = []
 
-    def add(self, name: str, seconds: float) -> None:
+    def add(self, name: str, seconds: float, offcpu: float = 0.0) -> None:
         self.totals[name] = self.totals.get(name, 0.0) + seconds
         self.counts[name] = self.counts.get(name, 0) + 1
+        if offcpu:
+            self.offcpu[name] = self.offcpu.get(name, 0.0) + offcpu
 
     def add_op_wall(self, op: str, seconds: float) -> None:
         self.op_wall[op] = self.op_wall.get(op, 0.0) + seconds
@@ -1613,54 +1667,331 @@ def active_stage_recorder() -> Optional[StageRecorder]:
     return getattr(_stage_tls, "rec", None)
 
 
+_ANNOTATION = None  # jax.profiler.TraceAnnotation, once jax is loaded
+
+
+def _annotation_cls():
+    """jax.profiler.TraceAnnotation where this process has ALREADY
+    imported jax (the coprocessor did), else None: a KV-only process
+    and `import tidb_tpu.obs` stay jax-free."""
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        mod = sys.modules.get("jax.profiler")
+        if mod is None:
+            return None
+        _ANNOTATION = mod.TraceAnnotation
+    return _ANNOTATION
+
+
+# A thread's CPU clock is a system call (6.0 us on the benchmark's host,
+# where the wall clock costs 0.09 us), so it is NOT read at every stage
+# edge. It is read at the edges of the few CLOCKED brackets only: the
+# wire command on its worker's thread (command_begin / Command.end),
+# the stages opened with clocked=True (`exec`, `device_get`). A clocked
+# bracket's off-CPU time is its own wall minus its own CPU, both
+# exclusive of the clocked brackets nested in it and both from the same
+# two edges, so nothing between brackets is ever carried into one; the
+# unclocked stages inside a bracket (plan_build, kernel, wire_read, ...)
+# share its reading and have none of their own.
+#
+# And a thread clocks at most one wire command in _CLOCK_EVERY_S: the
+# others skip all four reads and book no bracket, neither wall nor
+# off-CPU, so a share (off-CPU / clocked wall) is a ratio over the same
+# sampled commands. Analytic statements are all clocked (they last
+# longer than that); of a stream of millisecond point commands about
+# one in five is, which loses nothing where the kernel ticks the clock
+# at 10 ms, as the benchmark's host does: there a bracket shorter than
+# a tick reads 0 or a whole tick whatever is done, and only sums over
+# many commands mean anything (see _settle).
+_CLOCK_EVERY_S = 0.025
+_STAGE_KEYS: dict[str, tuple] = {}
+_PEND_MAX = 256  # closed stages a thread may hold back from /metrics
+
+
+def _stage_key(name: str) -> tuple:
+    """The stage's sorted label tuple, built once per stage name."""
+    key = _STAGE_KEYS.get(name)
+    if key is None:
+        key = _STAGE_KEYS[name] = (("stage", name),)
+    return key
+
+
+def _stage_tls_init(tls) -> list:
+    tls.stack = []  # open _StageCtx frames, outermost first
+    tls.pend = []   # (key, exclusive wall) of closed stages, unflushed
+    tls.clk = []    # (key, wall) of closed clocked brackets, unflushed
+    tls.off = []    # (key, off-CPU seconds) of the same brackets
+    tls.debt = {}   # key -> CPU time read beyond a bracket's wall (<= 0)
+    tls.cmd = None  # the open Command on this thread
+    tls.clocked_at = 0.0  # when this thread last began a clocked command
+    return tls.stack
+
+
+def _flush_stages(tls) -> None:
+    """A command's (outside one: an outermost stage's) closed stages go
+    to the process families in one pass: one lock per family, not two
+    per stage."""
+    if tls.pend:
+        DISPATCH_STAGE_SECONDS.observe_keys(tls.pend)
+        del tls.pend[:]
+    if tls.clk:
+        DISPATCH_STAGE_CLOCKED.inc_keys(tls.clk)
+        DISPATCH_STAGE_OFFCPU.inc_keys(tls.off)
+        del tls.clk[:], tls.off[:]
+
+
+def _settle(debt: dict, key: tuple, off: float) -> float:
+    """A bracket's off-CPU seconds for the counter, which cannot go
+    down. Where the kernel advances a thread's CPU clock in ticks
+    longer than a bracket, most brackets read no CPU time at all and a
+    few read a whole tick, more than their wall time: what a reading
+    goes below zero by is held against this thread's next brackets of
+    the same name, so the bracket's totals stay wall - CPU (dropping it
+    would count every bracket between ticks as waiting)."""
+    if debt:
+        off += debt.get(key, 0.0)
+    if off < 0.0:
+        debt[key] = off
+        return 0.0
+    if debt:
+        debt[key] = 0.0
+    return off
+
+
 class _StageCtx:
-    """Times one dispatch stage: always feeds the per-stage Prometheus
-    histogram and the active StageRecorder — both with EXCLUSIVE time
-    (a per-thread nesting stack subtracts inner stages, so summing the
-    per-stage histograms never double-counts a nested compile into its
-    enclosing kernel stage) — and opens a TRACE span when a collector
-    is active. Allocates no Span when tracing is off (the hot-path
-    guarantee test_trace pins)."""
+    """Times one stage of the served path: always feeds the per-stage
+    Prometheus histogram and the active StageRecorder with EXCLUSIVE
+    wall time (a per-thread nesting stack subtracts inner stages, so
+    summing the per-stage histograms never double-counts a nested
+    compile into its enclosing kernel stage). The histogram is fed in
+    batches: when the thread's wire command ends, or outside a command
+    when its outermost stage closes.
 
-    __slots__ = ("stage", "spanctx", "t0", "rec")
+    A stage opened with clocked=True also reads this thread's CPU clock
+    at both its edges and books offcpu = wall - cpu, exclusive of the
+    clocked brackets nested in it (and only of those): a bracket that
+    computed and one whose thread waited (the interpreter lock, a lock,
+    the device, a socket) read apart.
 
-    def __init__(self, stage: str, span_name: Optional[str]) -> None:
+    Under a jax profiler session the stage is also an event
+    `titpu/<stage>` of the host plane, on the device ops' clock, with
+    the statement (conn, seq) and `meta` as its metadata. The events
+    are exclusive like the times: a nested stage suspends its parent's
+    event and the parent's resumes when it closes, so a thread's
+    timeline is a flat run of stage names and no enclosing event
+    covers (and, for a reader that names an idle gap by the event
+    overlapping it most, swallows) the stages inside it. With no
+    session this costs one is_enabled() call per edge.
+
+    Opens a TRACE span when a collector is active and allocates no Span
+    when tracing is off (the hot-path guarantee test_trace pins)."""
+
+    __slots__ = ("stage", "span_name", "clocked", "op_split", "meta",
+                 "spanctx", "rec", "ann", "t0", "child",
+                 "cpu0", "cwall", "ccpu", "cparent")
+
+    def __init__(self, stage: str, span_name: Optional[str] = None, *,
+                 clocked: bool = False, op_split: bool = True,
+                 **meta) -> None:
         self.stage = stage
-        self.spanctx = _SpanCtx(span_name or stage)
-        self.rec = getattr(_stage_tls, "rec", None)
-        self.t0 = 0.0
+        self.span_name = span_name
+        self.clocked = clocked
+        self.op_split = op_split
+        self.meta = meta
+        self.ann = None
+        self.child = 0.0  # nested stages' wall time
+
+    def _annotate(self, cls) -> None:
+        meta = dict(self.meta)
+        if self.rec is not None:
+            meta.setdefault("conn", self.rec.conn)
+            meta.setdefault("seq", self.rec.seq)
+        self.ann = cls("titpu/" + self.stage, **meta)
+        self.ann.__enter__()
+
+    def _unannotate(self) -> None:
+        if self.ann is not None:
+            self.ann.__exit__(None, None, None)
+            self.ann = None
 
     def __enter__(self) -> Optional[Span]:
-        stack = getattr(_stage_tls, "stack", None)
-        if stack is None:
-            stack = _stage_tls.stack = []
-        stack.append(0.0)  # accumulates nested-stage wall time
+        tls = _stage_tls
+        try:
+            stack = tls.stack
+        except AttributeError:
+            stack = _stage_tls_init(tls)
+        self.rec = getattr(tls, "rec", None)
+        cls = _ANNOTATION or _annotation_cls()
+        if cls is not None and cls.is_enabled():
+            if stack:
+                stack[-1]._unannotate()
+            self._annotate(cls)
+        if self.clocked:
+            up = tls.cmd
+            if up is not None and not up.clocked:
+                self.clocked = False  # a command that skips the clock
+            else:
+                # the nearest enclosing clocked bracket (a stage or the
+                # thread's Command) takes this one's wall and CPU time
+                # off its own
+                for frame in reversed(stack):
+                    if frame.clocked:
+                        up = frame
+                        break
+                self.cparent = up
+                self.cwall = self.ccpu = 0.0
+                self.cpu0 = time.thread_time()
+        stack.append(self)
+        if getattr(_span_tls, "coll", None) is None:
+            self.spanctx = sp = None
+        else:
+            self.spanctx = _SpanCtx(self.span_name or self.stage)
+            sp = self.spanctx.__enter__()
         self.t0 = time.perf_counter()
-        return self.spanctx.__enter__()
+        return sp
 
     def __exit__(self, *exc) -> None:
         dt = time.perf_counter() - self.t0
-        self.spanctx.__exit__(*exc)
-        stack = _stage_tls.stack
-        child = stack.pop()
-        if stack:
-            stack[-1] += dt
+        tls = _stage_tls
+        stage = self.stage
+        key = _STAGE_KEYS.get(stage) or _stage_key(stage)
+        off = 0.0
+        if self.clocked:
+            cpu = time.thread_time() - self.cpu0
+            up = self.cparent
+            if up is not None:
+                up.cwall += dt
+                up.ccpu += cpu
+            wall = dt - self.cwall
+            off = _settle(tls.debt, key, wall - (cpu - self.ccpu))
+            tls.clk.append((key, wall))
+            tls.off.append((key, off))
+        if self.spanctx is not None:
+            self.spanctx.__exit__(*exc)
+        stack = tls.stack
+        stack.pop()
+        child = self.child
         excl = dt - child if dt > child else 0.0
-        DISPATCH_STAGE_SECONDS.observe(excl, stage=self.stage)
-        if self.rec is not None:
-            self.rec.add(self.stage, excl)
+        pend = tls.pend
+        pend.append((key, excl))
+        if self.ann is not None:
+            self._unannotate()
+        if stack:
+            parent = stack[-1]
+            parent.child += dt
+            cls = _ANNOTATION
+            if cls is not None and cls.is_enabled():
+                parent._annotate(cls)
+            if len(pend) >= _PEND_MAX:  # a long statement's stages
+                _flush_stages(tls)
+        elif tls.cmd is None or len(pend) >= _PEND_MAX:
+            _flush_stages(tls)
+        rec = self.rec
+        if rec is not None:
+            rec.add(stage, excl, off)
             # per-operator split of the same exclusive time: stages
             # closed outside any operator frame (plan_build at the
-            # session layer) land under '(session)'
-            self.rec.add_op_stage(
-                getattr(_op_tls, "label", None) or "(session)",
-                self.stage, excl)
+            # session layer) land under '(session)'. A stage that
+            # ENCLOSES the operator frames is opened with
+            # op_split=False: its self time is what those frames
+            # already hold as op_wall, and op_wall plus the
+            # '(session)' stages must stay additive (Top SQL coverage)
+            if self.op_split:
+                rec.add_op_stage(
+                    getattr(_op_tls, "label", None) or "(session)",
+                    stage, excl)
 
 
-def stage(name: str, span_name: Optional[str] = None) -> _StageCtx:
-    """`with obs.stage("compile"):` — one named dispatch stage.
-    Histogram + recorder always; a span only under an active TRACE."""
-    return _StageCtx(name, span_name)
+# `with obs.stage("compile"):` — one named stage. Histogram and recorder
+# always; with clocked=True the thread's CPU clock and the off-CPU
+# counter too (two system calls: for the few brackets a statement passes
+# once, not for a per-tile stage); a span only under an active TRACE;
+# profiler events only under a jax profiler session (further keywords
+# become their metadata, e.g. prog="titpu_agg").
+stage = _StageCtx
+
+
+def note_stage(name: str, seconds: float) -> None:
+    """Book a stage timed from a stamp because it crosses threads (the
+    worker -> reactor hand-back): no thread computed for it, so all of
+    it is off-CPU. Feeds the same families as obs.stage; no recorder
+    (no statement is open) and no profiler event (it is on no one
+    thread's timeline)."""
+    if seconds < 0:
+        seconds = 0.0
+    key = _stage_key(name)
+    DISPATCH_STAGE_SECONDS.observe_key(key, seconds)
+    DISPATCH_STAGE_CLOCKED.inc_key(key, seconds)
+    DISPATCH_STAGE_OFFCPU.inc_key(key, seconds)
+
+
+class Command:
+    """One wire command on its worker's thread: the outermost clocked
+    bracket (`command`: what the thread did outside `exec`, i.e. the
+    packet read, parse, epilogue, encoding, the socket write and the
+    glue between them), the whole the stages add up to
+    (tidb_conn_command_seconds), and the point where the command's
+    stages reach the process families in one pass."""
+
+    __slots__ = ("t_cmd", "t0", "clocked", "cpu0", "cwall", "ccpu")
+
+    def _start(self, tls, now: float) -> None:
+        self.t0 = now
+        self.clocked = now - tls.clocked_at >= _CLOCK_EVERY_S
+        if self.clocked:
+            tls.clocked_at = now
+            self.cwall = self.ccpu = 0.0
+            self.cpu0 = time.thread_time()
+
+    def end(self) -> None:
+        """The response is on the socket; a pipelined command, if any,
+        starts here."""
+        tls = _stage_tls
+        now = time.perf_counter()
+        if self.clocked:
+            cpu = time.thread_time() - self.cpu0
+            wall = now - self.t0 - self.cwall
+            tls.clk.append((_COMMAND_KEY, wall))
+            tls.off.append((_COMMAND_KEY, _settle(
+                tls.debt, _COMMAND_KEY, wall - (cpu - self.ccpu))))
+        CONN_COMMAND_SECONDS.observe_key((), now - self.t_cmd)
+        _flush_stages(tls)
+        self.t_cmd = now
+        self._start(tls, now)
+
+    def close(self) -> None:
+        """The worker lets go of the connection (parked, closed or
+        failed): what an unfinished command left goes out too."""
+        tls = _stage_tls
+        tls.cmd = None
+        _flush_stages(tls)
+
+
+_COMMAND_KEY = _stage_key("command")
+
+
+def command_begin(woke_at: float) -> Command:
+    """Open the wire command this thread is about to serve. `woke_at`
+    is the reactor's perf_counter stamp of select() returning the
+    socket (0 = not woken by the reactor): the command's clock starts
+    there and the hand-off since then is the stage `wire_queue`, all of
+    it off-CPU (no thread ran for it)."""
+    tls = _stage_tls
+    if not hasattr(tls, "stack"):
+        _stage_tls_init(tls)
+    cmd = tls.cmd = Command()
+    now = time.perf_counter()
+    if woke_at:
+        queued = now - woke_at if now > woke_at else 0.0
+        key = _stage_key("wire_queue")
+        tls.pend.append((key, queued))
+        tls.clk.append((key, queued))
+        tls.off.append((key, queued))
+        cmd.t_cmd = woke_at
+    else:
+        cmd.t_cmd = now
+    cmd._start(tls, now)
+    return cmd
 
 
 # ---- typed wait-state ledger (critical-path attribution) --------------------
@@ -1801,8 +2132,9 @@ def fmt_stages(stages: Optional[dict[str, float]]) -> str:
     """stage dict -> 'staging:0.12ms compile:5.3ms ...' (stable order)."""
     if not stages:
         return ""
-    order = ("parse", "plan_build", "prepare", "staging", "transfer",
-             "compile", "kernel", "device_get", "host_fallback", "ranged")
+    order = ("parse", "fast_plan", "plan_build", "admission", "exec",
+             "prepare", "staging", "transfer", "compile", "kernel",
+             "device_get", "merge", "host_fallback", "ranged")
     keys = [k for k in order if k in stages] + \
         sorted(k for k in stages if k not in order)
     return " ".join(f"{k}:{stages[k] * 1e3:.3g}ms" for k in keys)

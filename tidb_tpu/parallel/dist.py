@@ -29,7 +29,7 @@ from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .. import obs
-from ..copr.client import CopClient
+from ..copr.client import CopClient, named_jit
 
 AXIS = "shard"
 
@@ -70,7 +70,7 @@ class DistCopClient(CopClient):
             in_specs=(P(AXIS), P(AXIS)),
             out_specs=P(),
         )
-        return jax.jit(mapped)
+        return named_jit(mapped, "titpu_mesh_agg")
 
     def _bucket_size(self, n: int) -> int:
         """Round the shape bucket so the rows axis shards evenly AND each
@@ -327,13 +327,13 @@ class DistCopClient(CopClient):
                 merged, mesh=self.mesh,
                 in_specs=(P(AXIS), P(AXIS), build_specs),
                 out_specs=P())
-            return jax.jit(mapped)
+            return named_jit(mapped, "titpu_mesh_frag_agg")
         if mode == "hc":
             mapped = shard_map(
                 kernel, mesh=self.mesh,
                 in_specs=(P(AXIS), P(AXIS), build_specs),
                 out_specs=self._hc_out_specs(prepared))
-            return jax.jit(mapped)
+            return named_jit(mapped, "titpu_mesh_frag_hc")
         if mode == "topn":
             # fused join+topn: each shard ships its own top-n candidate
             # rows, concatenated along the k axis (n·shards rows total);
@@ -342,14 +342,14 @@ class DistCopClient(CopClient):
                 kernel, mesh=self.mesh,
                 in_specs=(P(AXIS), P(AXIS), build_specs),
                 out_specs=P(None, AXIS))
-            return jax.jit(mapped)
+            return named_jit(mapped, "titpu_mesh_frag_topn")
         # row mode: per-shard packed bitmask; shards are 256-multiples so
         # byte boundaries align and concatenation is the global mask
         mapped = shard_map(
             kernel, mesh=self.mesh,
             in_specs=(P(AXIS), P(AXIS), build_specs),
             out_specs=P(AXIS))
-        return jax.jit(mapped)
+        return named_jit(mapped, "titpu_mesh_frag_rows")
 
     @staticmethod
     def _hc_out_specs(prepared) -> dict:
@@ -391,7 +391,7 @@ class DistCopClient(CopClient):
             # per-shard candidate columns concatenate along the k axis;
             # the host PhysSort+PhysLimit above merge exactly
             out_specs=P(None, AXIS))
-        return jax.jit(mapped)
+        return named_jit(mapped, "titpu_mesh_topn")
 
     def _build_rowmask_kernel(self, dag, prepared):
         raw = self._rowmask_body(dag, prepared)
@@ -399,7 +399,7 @@ class DistCopClient(CopClient):
             raw, mesh=self.mesh,
             in_specs=(P(AXIS), P(AXIS)),
             out_specs=P(AXIS))
-        return jax.jit(mapped)
+        return named_jit(mapped, "titpu_mesh_rows")
 
 
 def _collective_merge(out: dict, sched) -> dict:

@@ -16,6 +16,7 @@ import struct
 import threading
 from typing import TYPE_CHECKING, Optional
 
+from .. import obs
 from ..session.session import ResultSet, Session, SQLError
 from . import packet as P
 from ..errno import error_of
@@ -88,6 +89,9 @@ class ClientConn:
         # reactor bookkeeping: when this conn last parked idle
         # (@@wait_timeout reaping reads it on the sweep)
         self.parked_at = 0.0
+        # perf_counter stamp of the reactor's select returning this
+        # socket; serve_ready consumes it (0 = not woken by the reactor)
+        self.woke_at = 0.0
 
     def _caps(self) -> int:
         caps = _CAPS
@@ -333,6 +337,11 @@ class ClientConn:
         a command whose first bytes already arrived (the reactor woke
         us), so a slow statement — not an idle connection — is the only
         thing that holds a worker."""
+        # the command's clock starts where the reactor saw its first
+        # bytes (the hand-off since then is `wire_queue`); a pipelined
+        # command's starts where the last one ended
+        cmd = obs.command_begin(self.woke_at)
+        self.woke_at = 0.0
         try:
             while self.alive and not self.killed.is_set():
                 self.io.reset_sequence()
@@ -344,7 +353,8 @@ class ClientConn:
                     # the same reap the parked sweep applies. The
                     # statement itself runs with no deadline (below).
                     self.sock.settimeout(self._idle_timeout())
-                    data = self.io.read_packet()
+                    with obs.stage("wire_read", conn=self.conn_id):
+                        data = self.io.read_packet()
                 except (ConnectionError, OSError, ValueError):
                     self.close()
                     return
@@ -359,7 +369,9 @@ class ClientConn:
                 if not self.dispatch(data[0], data[1:]):
                     self.close()
                     return
-                self.io.flush()
+                with obs.stage("wire_write", conn=self.conn_id):
+                    self.io.flush()
+                cmd.end()
                 if not self._buffered_input():
                     break
             self._park()
@@ -369,6 +381,8 @@ class ClientConn:
             # COM_QUERY bytes, struct.error from a short COM_STMT
             # frame) leaks a zombie holding its txn locks forever
             self.close()
+        finally:
+            cmd.close()
 
     def dispatch(self, cmd: int, payload: bytes) -> bool:
         if cmd == P.COM_QUIT:
@@ -423,6 +437,10 @@ class ClientConn:
         return True
 
     def _write_resultset(self, rs: ResultSet, binary: bool = False) -> None:
+        with obs.stage("encode", conn=self.conn_id):
+            self._encode_resultset(rs, binary)
+
+    def _encode_resultset(self, rs: ResultSet, binary: bool) -> None:
         if not rs.column_names:
             self.io.write_packet(P.ok_packet(
                 affected=rs.affected, status=self._status()))

@@ -26,6 +26,7 @@ import time
 from collections import deque
 from typing import Optional
 
+from .. import obs
 from ..store.storage import Storage
 from .conn import ClientConn
 
@@ -147,6 +148,7 @@ class _Reactor:
             pass
 
     def park(self, conn: ClientConn) -> None:
+        # read twice: by the idle sweep, and by _loop for `wire_repark`
         conn.parked_at = time.monotonic()
         with self._lock:
             closed = self._closed
@@ -182,6 +184,11 @@ class _Reactor:
                                        conn)
                 except (OSError, ValueError, KeyError):
                     conn.close()
+                    continue
+                # finished statement -> socket watched again: until here
+                # the client's next command could not be noticed
+                obs.note_stage("wire_repark",
+                               time.monotonic() - conn.parked_at)
             if doomed:
                 for key in list(self._sel.get_map().values()):
                     if key.data in doomed:
@@ -200,6 +207,9 @@ class _Reactor:
                     continue
                 conn = key.data
                 self._unregister(key.fileobj)
+                # serve_ready books now - stamp as `wire_queue` and
+                # starts the command's clock here
+                conn.woke_at = time.perf_counter()
                 self.pool.submit(conn.serve_ready)
             now = time.monotonic()
             if now - last_sweep >= self.SWEEP_S:

@@ -19,6 +19,7 @@ from typing import Any, Optional
 
 import numpy as np
 
+from .. import obs
 from ..catalog.schema import Catalog, ColumnInfo, IndexInfo, TableInfo
 from ..chunk.chunk import Chunk
 from ..copr.client import CopClient
@@ -62,6 +63,15 @@ from ..errno import (
     ER_WRONG_VALUE_COUNT_ON_ROW,
     CodedError,
 )
+
+
+def _exec_stage(span_name: Optional[str] = None):
+    """The executor's stage: the plan's run with everything nested in it
+    (plan, admission, the coprocessor's stages) subtracting itself.
+    Clocked (one of the few brackets whose thread CPU time is read), and
+    out of the recorder's per-operator split, since it encloses the
+    operator frames, whose op_wall already holds its self time."""
+    return obs.stage("exec", span_name, clocked=True, op_split=False)
 
 
 class SQLError(CodedError):
@@ -212,19 +222,24 @@ class Session:
             # commits + schema changes before planning (the per-statement
             # domain-reload; store/storage.py refresh)
             self.storage.refresh()
-        import time as _time
-        t_parse = _time.perf_counter()
+        # the batch's parse books against its first statement: that
+        # statement's recorder is made here and is the active one
+        # while the lexer and parser run, so `parse` is an ordinary
+        # stage (histogram, off-CPU counter, slow log) — without it
+        # the attribution plane undercounts short statements by
+        # exactly the parse time
+        rec = obs.StageRecorder(self.conn_id or 0, self._stmt_seq)
+        prev_rec = obs.active_stage_recorder()
+        obs.install_stage_recorder(rec)
         try:
-            stmts = parse_sql(sql)
+            with obs.stage("parse"):
+                stmts = parse_sql(sql)
         except ParseError as e:
             self.storage.obs.query_errors.inc()
             raise SQLError(f"parse error: {e}",
                            errno=getattr(e, 'errno', ER_PARSE_ERROR)) from None
-        # parse happens before the per-statement recorder exists; stash
-        # it so the first statement's recorder books it as a 'parse'
-        # stage — without this the attribution plane undercounts short
-        # statements by exactly the lexer/parser time
-        self._pending_parse_s = _time.perf_counter() - t_parse
+        finally:
+            obs.install_stage_recorder(prev_rec)
         result = ResultSet([], [])
         single = len(stmts) == 1
         for i, stmt in enumerate(stmts):
@@ -253,7 +268,8 @@ class Session:
                 # text would flood the digest table with unnormalizable
                 # entries
                 result = self._execute_observed(
-                    stmt, label, digest_sql=sql if single else None)
+                    stmt, label, digest_sql=sql if single else None,
+                    rec=rec if i == 0 else None)
             finally:
                 self._plan_cache_key = None
                 self._binding_match_sql = None
@@ -268,14 +284,16 @@ class Session:
         return result
 
     def _execute_observed(self, stmt: ast.Stmt, sql: str,
-                          digest_sql: Optional[str] = None) -> ResultSet:
+                          digest_sql: Optional[str] = None,
+                          rec=None) -> ResultSet:
         """Run one statement with metrics + slow-log + statement-digest
         accounting — shared by the text protocol and COM_STMT_EXECUTE
         (reference: both paths pass through ExecStmt in
-        executor/adapter.go; digests feed util/stmtsummary)."""
+        executor/adapter.go; digests feed util/stmtsummary). `rec` is
+        the statement's recorder where execute() already made it (it
+        holds the batch's `parse`)."""
         import time as _time
 
-        from .. import obs
         from ..obs import DEFAULT_SLOW_THRESHOLD_MS
 
         from ..util import interrupt
@@ -339,19 +357,14 @@ class Session:
         # reads + a dict update per stage) feeding the slow log and
         # EXPLAIN ANALYZE (reference: execdetails on every statement)
         prev_rec = obs.active_stage_recorder()
-        rec = obs.StageRecorder()
+        if rec is None:
+            rec = obs.StageRecorder(self.conn_id or 0, self._stmt_seq)
         # typed wait-state ledger (tso/lease/backoff/2PC/fsync waits):
         # allocated ONLY while performance.wait-profile-enabled is on —
         # disabled, the statement path provably never builds or touches
         # one (the poison/zero-alloc contract test_trace pins)
         prev_led = obs.active_wait_ledger()
         led = obs.WaitLedger() if o.waitprofile.enabled else None
-        pp = getattr(self, "_pending_parse_s", 0.0)
-        if pp:
-            # the batch's parse time books against its first statement
-            rec.add("parse", pp)
-            rec.add_op_stage("(session)", "parse", pp)
-            self._pending_parse_s = 0.0
         # route @@time_zone to the scalar-function layer for the
         # statement's duration: FROM_UNIXTIME formats in the session
         # time zone like MySQL (the round-5 ADVICE finding; the %-
@@ -385,10 +398,13 @@ class Session:
                 # (SELECTs admit inside _exec_select, where the
                 # planner's cost estimate is in hand)
                 from ..util.governor import PRI_DML
-                with self._admission(PRI_DML):
+                with self._admission(PRI_DML), _exec_stage():
                     rs = self._execute_stmt(stmt)
             else:
-                rs = self._execute_stmt(stmt)
+                # executor self time: plan, admission and every
+                # coprocessor stage nested inside subtract themselves
+                with _exec_stage():
+                    rs = self._execute_stmt(stmt)
             rows_out = len(rs.rows)
             if self._stmt_auto_id is not None:
                 self.vars["last_insert_id"] = self._stmt_auto_id
@@ -437,100 +453,105 @@ class Session:
             if self._is_guard is not None:
                 self._is_guard.release()
                 self._is_guard = None
+            # what observability itself costs a statement: digest,
+            # summary, slow log, history / Top SQL / wait-profile feeds
+            # (closed after the recorder is gone: histogram only)
             dt = _time.perf_counter() - t0
-            if prof is not None:
-                self._finish_profile(prof, sql, dt)
-            o.query_seconds.observe(dt)
-            # the statement's attribution, readable by embedded callers
-            # (bench.py persists these per timed query)
-            self.last_stages = rec.totals
-            self.last_op_wall = rec.op_wall
-            self.last_op_stages = rec.ops
-            self.last_op_bytes = rec.op_bytes
-            self.last_op_mesh = rec.op_mesh
-            self.last_engines = rec.engines
-            self.last_waits = led.totals if led is not None else {}
-            # worst shard skew of the statement's sharded dispatches
-            # (0 = none); surfaces in the slow log + Top SQL
-            mesh_skew = 0.0
-            if rec.op_mesh:
-                mesh_skew = max(v[1] for v in rec.op_mesh.values())
-            # mesh skew warnings raised by the flight recorder during
-            # this statement become SHOW WARNINGS entries (self._cop,
-            # not self.cop: the property would lazily build a mesh
-            # plane on statements that never dispatched)
-            c = self._cop
-            if c is not None:
-                if failed:
-                    # an interrupted/failed statement leaves queued
-                    # per-shard stats uncollected; drop them so they
-                    # are not folded into the next statement's mesh
-                    # accounting
-                    c.discard_mesh_pending()
-                for w in c.drain_mesh_warnings():
-                    self.add_warning(w)
-            if digest_sql is not None:
-                o.statements.record(digest_sql, self.current_db, dt,
-                                    rows_out, failed,
-                                    mem_peak=self.last_mem_peak,
-                                    spill_count=self.last_spill_count)
-            try:
-                thresh = float(
-                    self._sysvar_value("tidb_slow_log_threshold"))
-            except (TypeError, ValueError, SQLError):
-                thresh = DEFAULT_SLOW_THRESHOLD_MS
-            slow = dt * 1e3 >= thresh
-            # the Top SQL aggregator feed: gated on `enabled` HERE so a
-            # disabled plane costs zero work and zero allocations on
-            # the statement path (the digest/normalize hash is the
-            # expensive part)
-            topsql = o.topsql
-            # workload-history feed: gated on `enabled` HERE like the
-            # Top SQL plane, so a disabled history plane costs zero
-            # work and zero allocations on the statement path
-            history = self.storage.history
-            hist_on = history.enabled and digest_sql is not None
-            # wait-profile feed: the ledger only exists while the plane
-            # is enabled, so this adds zero work when it is off
-            wp_on = led is not None and led.totals \
-                and digest_sql is not None
-            if slow or hist_on or wp_on or \
-                    (topsql.enabled and digest_sql is not None):
-                import hashlib
-                # same digest the statements_summary uses, so slow-log
-                # and top-sql entries join against the digest table
-                norm = o.statements.normalize(digest_sql or sql)
-                digest = hashlib.sha256(norm.encode()).hexdigest()[:32]
-                if hist_on:
-                    history.observe(
-                        digest, norm[:512], self.current_db, dt,
-                        engines=rec.engines, stages=rec.totals,
-                        rows=rows_out, failed=failed,
-                        op_mesh=rec.op_mesh)
-                if wp_on:
-                    o.waitprofile.record(digest, norm[:512],
-                                         self.current_db, dt,
-                                         led.totals)
-                if topsql.enabled and digest_sql is not None:
-                    topsql.record(
-                        digest, norm[:512], self.current_db, dt,
-                        stages=rec.totals, op_wall=rec.op_wall,
-                        op_stages=rec.ops, op_bytes=rec.op_bytes,
-                        rows=rows_out, failed=failed, shed=shed,
-                        killed=self._governor_killed,
-                        op_mesh={k: v[0] for k, v in
-                                 rec.op_mesh.items()} or None,
-                        waits=led.totals if led is not None else None)
-                if slow:
-                    o.record_slow(sql, self.current_db, dt,
-                                  plan_digest=digest,
-                                  stages=rec.snapshot(),
-                                  mem_peak=self.last_mem_peak,
-                                  spill_count=self.last_spill_count,
-                                  op_wall=rec.op_wall,
-                                  mesh_skew=mesh_skew,
-                                  waits=dict(led.totals)
-                                  if led is not None else None)
+            with obs.stage("epilogue"):
+                if prof is not None:
+                    self._finish_profile(prof, sql, dt)
+                o.query_seconds.observe(dt)
+                # the statement's attribution, readable by embedded callers
+                # (bench.py persists these per timed query)
+                self.last_stages = rec.totals
+                self.last_op_wall = rec.op_wall
+                self.last_op_stages = rec.ops
+                self.last_op_bytes = rec.op_bytes
+                self.last_op_mesh = rec.op_mesh
+                self.last_engines = rec.engines
+                self.last_waits = led.totals if led is not None else {}
+                # worst shard skew of the statement's sharded dispatches
+                # (0 = none); surfaces in the slow log + Top SQL
+                mesh_skew = 0.0
+                if rec.op_mesh:
+                    mesh_skew = max(v[1] for v in rec.op_mesh.values())
+                # mesh skew warnings raised by the flight recorder during
+                # this statement become SHOW WARNINGS entries (self._cop,
+                # not self.cop: the property would lazily build a mesh
+                # plane on statements that never dispatched)
+                c = self._cop
+                if c is not None:
+                    if failed:
+                        # an interrupted/failed statement leaves queued
+                        # per-shard stats uncollected; drop them so they
+                        # are not folded into the next statement's mesh
+                        # accounting
+                        c.discard_mesh_pending()
+                    for w in c.drain_mesh_warnings():
+                        self.add_warning(w)
+                if digest_sql is not None:
+                    o.statements.record(digest_sql, self.current_db, dt,
+                                        rows_out, failed,
+                                        mem_peak=self.last_mem_peak,
+                                        spill_count=self.last_spill_count)
+                try:
+                    thresh = float(
+                        self._sysvar_value("tidb_slow_log_threshold"))
+                except (TypeError, ValueError, SQLError):
+                    thresh = DEFAULT_SLOW_THRESHOLD_MS
+                slow = dt * 1e3 >= thresh
+                # the Top SQL aggregator feed: gated on `enabled` HERE so a
+                # disabled plane costs zero work and zero allocations on
+                # the statement path (the digest/normalize hash is the
+                # expensive part)
+                topsql = o.topsql
+                # workload-history feed: gated on `enabled` HERE like the
+                # Top SQL plane, so a disabled history plane costs zero
+                # work and zero allocations on the statement path
+                history = self.storage.history
+                hist_on = history.enabled and digest_sql is not None
+                # wait-profile feed: the ledger only exists while the plane
+                # is enabled, so this adds zero work when it is off
+                wp_on = led is not None and led.totals \
+                    and digest_sql is not None
+                if slow or hist_on or wp_on or \
+                        (topsql.enabled and digest_sql is not None):
+                    import hashlib
+                    # same digest the statements_summary uses, so slow-log
+                    # and top-sql entries join against the digest table
+                    norm = o.statements.normalize(digest_sql or sql)
+                    digest = hashlib.sha256(norm.encode()).hexdigest()[:32]
+                    if hist_on:
+                        history.observe(
+                            digest, norm[:512], self.current_db, dt,
+                            engines=rec.engines, stages=rec.totals,
+                            rows=rows_out, failed=failed,
+                            op_mesh=rec.op_mesh)
+                    if wp_on:
+                        o.waitprofile.record(digest, norm[:512],
+                                             self.current_db, dt,
+                                             led.totals)
+                    if topsql.enabled and digest_sql is not None:
+                        topsql.record(
+                            digest, norm[:512], self.current_db, dt,
+                            stages=rec.totals, op_wall=rec.op_wall,
+                            op_stages=rec.ops, op_bytes=rec.op_bytes,
+                            rows=rows_out, failed=failed, shed=shed,
+                            killed=self._governor_killed,
+                            op_mesh={k: v[0] for k, v in
+                                     rec.op_mesh.items()} or None,
+                            waits=led.totals if led is not None else None)
+                    if slow:
+                        o.record_slow(sql, self.current_db, dt,
+                                      plan_digest=digest,
+                                      stages=rec.snapshot(),
+                                      mem_peak=self.last_mem_peak,
+                                      spill_count=self.last_spill_count,
+                                      op_wall=rec.op_wall,
+                                      mesh_skew=mesh_skew,
+                                      waits=dict(led.totals)
+                                      if led is not None else None,
+                                      offcpu=dict(rec.offcpu))
 
     def query(self, sql: str) -> list[tuple[Any, ...]]:
         return self.execute(sql).rows
@@ -594,8 +615,9 @@ class Session:
         from ..sql.parser import Parser
 
         try:
-            parser = Parser(sql)
-            stmts = parser.parse()
+            with obs.stage("parse"):
+                parser = Parser(sql)
+                stmts = parser.parse()
         except ParseError as e:
             raise SQLError(f"parse error: {e}",
                            errno=getattr(e, 'errno', ER_PARSE_ERROR)) from None
@@ -1697,7 +1719,6 @@ class Session:
             with outer:
                 if getattr(stmt, "for_update", False):
                     self._lock_for_update(stmt)
-                from .. import obs
                 with obs.stage("plan_build", span_name="planner.optimize"):
                     plan = self._plan_cached(stmt, uncacheable=has_vars)
                 self._check_column_privs(plan)
@@ -1803,7 +1824,6 @@ class Session:
         caller's slow path is authoritative for everything else."""
         if not self._fast_path_eligible(stmt):
             return None
-        from .. import obs
         from ..plan import fastpath
         with obs.stage("fast_plan"):
             fp = self._fast_plan_cached(stmt)
@@ -3417,10 +3437,10 @@ class Session:
                     finally:
                         ctx.close()
 
-                with obs.span("executor.run"):
+                with _exec_stage("executor.run"):
                     self._run_in_txn(run)
             else:
-                with obs.span("executor.dml"):
+                with _exec_stage("executor.dml"):
                     self._execute_stmt(target)
         rows: list[tuple] = spans.rows()
         if plan is not None:

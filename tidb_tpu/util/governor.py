@@ -44,6 +44,7 @@ import time
 from contextlib import contextmanager
 from typing import Any, Callable, Optional
 
+from .. import obs
 from . import failpoint
 
 # statement priorities for the admission gate: point lookups and DML
@@ -422,36 +423,42 @@ class AdmissionGate:
             budget = timeout_s if timeout_s is not None \
                 else self.timeout_ms / 1000.0
             deadline = time.monotonic() + budget
-            try:
-                while True:
-                    if self.tokens <= 0:
-                        return False  # reconfigured to unlimited
+            # the only place that knows a statement waits for a token:
+            # the stage is that wait, not the token's hold (the wait on
+            # the condition gives the lock up, so a stage that closes
+            # under it holds nobody back)
+            with obs.stage("admission"):
+                try:
+                    while True:
+                        if self.tokens <= 0:
+                            return False  # reconfigured to unlimited
+                        self._prune()
+                        if self._running < self.tokens and self._waiters \
+                                and self._waiters[0] is ent:
+                            heapq.heappop(self._waiters)
+                            self._running += 1
+                            self._admitted_count += 1
+                            self.admitted.inc()
+                            self.running_gauge.set(self._running)
+                            # the next-highest waiter may also fit
+                            self._cv.notify_all()
+                            return True
+                        remaining = deadline - time.monotonic()
+                        if remaining <= 0:
+                            self._shed_count += 1
+                            self.shed.inc()
+                            raise AdmissionTimeout(
+                                f"Server is busy: no execution token "
+                                f"within {int(budget * 1000)}ms "
+                                f"(token-limit {self.tokens}, "
+                                f"{self._running} executing, "
+                                f"{self._depth} queued)")
+                        self._cv.wait(remaining)
+                finally:
+                    ent[2] = False
                     self._prune()
-                    if self._running < self.tokens and self._waiters \
-                            and self._waiters[0] is ent:
-                        heapq.heappop(self._waiters)
-                        self._running += 1
-                        self._admitted_count += 1
-                        self.admitted.inc()
-                        self.running_gauge.set(self._running)
-                        # the next-highest waiter may also fit
-                        self._cv.notify_all()
-                        return True
-                    remaining = deadline - time.monotonic()
-                    if remaining <= 0:
-                        self._shed_count += 1
-                        self.shed.inc()
-                        raise AdmissionTimeout(
-                            f"Server is busy: no execution token within "
-                            f"{int(budget * 1000)}ms (token-limit "
-                            f"{self.tokens}, {self._running} executing, "
-                            f"{self._depth} queued)")
-                    self._cv.wait(remaining)
-            finally:
-                ent[2] = False
-                self._prune()
-                self._depth -= 1
-                self.depth_gauge.set(self._depth)
+                    self._depth -= 1
+                    self.depth_gauge.set(self._depth)
 
     def release(self) -> None:
         with self._cv:
