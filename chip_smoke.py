@@ -295,9 +295,9 @@ class PallasProbe:
         self.traces: list = []
         streamseg.rank_sums_pallas = self
 
-    def __call__(self, vals, f_dev, meta):
-        self.traces.append((vals.shape, f_dev.shape, meta))
-        return self.inner(vals, f_dev, meta)
+    def __call__(self, vals, aux, meta):
+        self.traces.append((vals.shape, meta))
+        return self.inner(vals, aux, meta)
 
     def mosaic_line(self) -> str:
         """Lower the served kernel once more and look for Mosaic's
@@ -306,16 +306,21 @@ class PallasProbe:
         import jax.numpy as jnp
         check(bool(self.traces), "the Pallas kernel was never traced into "
               "a served program (group_top10 took another path)")
-        vshape, fshape, meta = max(self.traces, key=lambda t: t[0][1])
-        text = jax.jit(lambda v, f: self.inner(v, f, meta)).lower(
+        vshape, meta = max(self.traces, key=lambda t: t[0][1])
+        aux = {k: jax.ShapeDtypeStruct(meta[k].shape, jnp.int32)
+               for k in ("lr", "cb")}
+        text = jax.jit(lambda v, a: self.inner(v, a, meta)).lower(
             jax.ShapeDtypeStruct(vshape, jnp.float32),
-            jax.ShapeDtypeStruct(fshape, jnp.int32)).compile().as_text()
+            aux).compile().as_text()
         check("tpu_custom_call" in text and self.mod.KERNEL_NAME in text,
               "compiled rank_sums module has no Mosaic custom call")
         return (f"pallas: {len(self.traces)} trace(s) into served "
                 f"programs {[list(t[0]) for t in self.traces]}; "
                 f"{self.mod.KERNEL_NAME} vals{list(vshape)} maxd="
-                f"{meta['maxd']} compiles to a tpu_custom_call (Mosaic)")
+                f"{meta['maxd']} geometry blk={meta['blk']} x nb="
+                f"{meta['nb']} ohw={meta['ohw']} operands "
+                f"{jnp.dtype(self.mod.OPERAND_DTYPE).name} compiles to a "
+                "tpu_custom_call (Mosaic)")
 
 
 class CollectiveProbe:

@@ -314,9 +314,7 @@ def _device_fragment(cop, frag, snaps) -> CopResult:
                 meta = cop._rank_meta(psnap, segcols)
                 if meta is not None:
                     prepared["__rank_meta__"] = meta
-                    prepared["__sig__"].append(
-                        ("rankseg", meta["nd"], meta["maxd"],
-                         meta["n0"], meta["identity"]))
+                    prepared["__sig__"].append(SS.program_key(meta))
         # hc None (HAVING-filtered or all-groups "group" mode) runs in
         # rank space when the epoch is run-ordered, else through the
         # sorted-run body's gate-scored candidate buffer
@@ -803,8 +801,9 @@ def _decode_frag_topn(frag, snaps, out) -> Optional[Chunk]:
 
 
 def _stage_rank_aux(cop, snap, prepared):
-    """Device-resident epoch arrays for the streamseg rank kernel: change
-    flags f and first-row-per-rank r0 (cached per epoch)."""
+    """Device-resident epoch arrays for the streamseg rank kernel: the
+    in-block local ranks lr with the per-block rank counts cb, and
+    first-row-per-rank r0 (cached per epoch)."""
     meta = prepared["__rank_meta__"]
     key = (snap.epoch.epoch_id, "rankaux", meta["n0"], meta["nd"])
     with cop._lock:
@@ -812,8 +811,8 @@ def _stage_rank_aux(cop, snap, prepared):
         cacheable = cop._live_epochs.get(snap.store.table.id) \
             == snap.epoch.epoch_id
     if hit is None:
-        hit = {"f": jnp.asarray(meta["f"]),
-               "r0": jnp.asarray(meta["r0"])}
+        from . import streamseg as SS
+        hit = {**SS.rank_aux(meta), "r0": jnp.asarray(meta["r0"])}
         if cacheable:
             with cop._lock:
                 cop._col_cache[key] = hit
@@ -1422,7 +1421,7 @@ def _hc_rank_body(frag, prepared, cols, mask, aux):
             t_list.append((shift, limb_ids))
         term_ix.append(t_list)
 
-    tot = SS.rank_sums(jnp.stack(arrs), aux["f"], meta)  # f32[K, nd_pad]
+    tot = SS.rank_sums(jnp.stack(arrs), aux, meta)  # f32[K, nd_pad]
     gate = tot[0] > 0
     r0 = aux["r0"]
 
