@@ -585,16 +585,17 @@ class CopClient:
                     terms = decompose_terms(d.arg, col_bounds)
                     if terms is None:
                         return (f"agg arg {d.arg!r} not int32-decomposable")
-                    # the TRUE total must fit int64 for the host Horner
-                    # recombination (sumexact.combine_partials)
+                    # where largest value x rows cannot prove that the
+                    # total fits the int64 Horner, the host recombines
+                    # the limb partials in arithmetic that cannot wrap
+                    # (sumexact.combine_terms)
                     b = expr_bounds(d.arg, col_bounds)
                     if b is None:
                         return "agg arg unbounded"
-                    mag = max(abs(b[0]), abs(b[1]))
-                    if mag * max(n_rows, 1) >= 2**62:
-                        return "sum magnitude exceeds int64 accumulator"
                     sched.append({
                         "kind": "isum",
+                        "wide": SE.needs_wide(
+                            max(abs(b[0]), abs(b[1])), n_rows),
                         "terms": [
                             (t, s, limbs_for(expr_bounds(t, col_bounds),
                                              SE.LIMB_BITS))
@@ -1555,9 +1556,6 @@ def decode_agg_partials(agg, prepared, cards, out, group_dicts,
     order as laid out by the planner's partial schema."""
     offsets = prepared["__key_offsets__"]
     sched = prepared["__agg_sched__"]
-    segments = 1
-    for c in cards:
-        segments *= max(c, 1)
     rows_per_seg = SE.combine_partials(out["rows"])
     present = rows_per_seg > 0
     seg_idx = np.nonzero(present)[0]
@@ -1605,10 +1603,10 @@ def decode_agg_partials(agg, prepared, cards, out, group_dicts,
         if s["kind"] == "count":
             vcol = Column(val_t, cnt.astype(np.int64))
         elif s["kind"] == "isum":
-            total = np.zeros(segments, dtype=np.int64)
-            for ti, (_, shift, _) in enumerate(s["terms"]):
-                total += SE.combine_partials(out[f"s{ai}_{ti}"]) << shift
-            val = total[seg_idx]
+            val = SE.combine_terms(
+                [out[f"s{ai}_{ti}"] for ti in range(len(s["terms"]))],
+                [shift for _, shift, _ in s["terms"]],
+                wide=s["wide"], sel=seg_idx)
             vcol = Column(val_t, val.astype(val_t.np_dtype),
                           None if (cnt > 0).all() else (cnt > 0))
         elif s["kind"] == "fsum":

@@ -1050,10 +1050,11 @@ def _prepare_hc(frag, comb_bounds, prepared, n_rows) -> bool:
         b = expr_bounds(d.arg, comb_bounds)
         if b is None:
             return False
-        if max(abs(b[0]), abs(b[1])) * max(n_rows, 1) >= 2**62:
-            return False
         sched.append({
             "kind": "isum",
+            # past the int64 bound the host recombines in arithmetic that
+            # cannot wrap (sumexact.combine_terms), as the dense path does
+            "wide": _SE.needs_wide(max(abs(b[0]), abs(b[1])), n_rows),
             "terms": [(t, s, limbs_for(expr_bounds(t, comb_bounds),
                                        _SE.LIMB_BITS))
                       for t, s in terms],
@@ -1873,10 +1874,10 @@ def _decode_hc_rows(frag, snaps, prepared, out, picked) -> Chunk:
             vcol = Column(val_t, val.astype(val_t.np_dtype),
                           None if (cnt > 0).all() else (cnt > 0))
         else:
-            total = np.zeros(len(picked), dtype=np.int64)
-            for ti, (_, shift, _) in enumerate(s["terms"]):
-                total += _SE.combine_partials(out[f"s{ai}_{ti}"]) << shift
-            val = total[sel]
+            val = _SE.combine_terms(
+                [out[f"s{ai}_{ti}"] for ti in range(len(s["terms"]))],
+                [shift for _, shift, _ in s["terms"]],
+                wide=s["wide"], sel=sel)
             vcol = Column(val_t, val.astype(val_t.np_dtype),
                           None if (cnt > 0).all() else (cnt > 0))
         columns.append(vcol)

@@ -194,13 +194,21 @@ class MeshFlightRecorder:
         routed_total = 0
         shards = 0
         now = time.time()
-        for kind, digest, stats, routed, op in pend:
+        # one fetch for every dispatch of the statement (a tile each: 72
+        # at 300M rows), booked as the fetch it is
+        with obs.stage("device_get", prog="titpu_mesh_stats"):
+            try:
+                fetched = jax.device_get(
+                    [None if isinstance(st, dict) else st
+                     for _, _, st, _, _ in pend])
+            except Exception:  # noqa: BLE001 — telemetry only
+                return None
+        for (kind, digest, stats, routed, op), a in zip(pend, fetched):
             inp = rows = None
             try:
                 if isinstance(stats, dict) and "bits" in stats:
                     rows = _bits_shard_counts(stats["bits"])
                 else:
-                    a = np.asarray(stats)
                     inp = a[:, 0].astype(np.int64)
                     rows = a[:, 1].astype(np.int64)
                     if (rows < 0).any():
@@ -464,6 +472,9 @@ class MeshPlane:
             if c is None:
                 c = MeshCopClient(self)
                 self._clients[storage] = c
+                # a counter that never moved is not rendered: a mesh that
+                # moved nothing must read 0 on /metrics, not be absent
+                obs.MESH_RESHARD_BYTES.inc(0)
         # the flight recorder's event sink: this storage's event ring
         # receives mesh_skew / mesh_compile_storm / mesh_hbm_watermark
         if c.recorder.obs is None:

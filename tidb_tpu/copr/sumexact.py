@@ -24,8 +24,14 @@ Scheme (SURVEY.md §7 hard-part 1, "scaled int32-pair kernels"):
   collective.
 
 The host combines with int64 Horner:  p = hi*4096 + lo per limb, then
-value = sum_i p_i << (12*i).  True totals are assumed to fit int64 (SQL
-DECIMAL sums; the planner's interval analysis guarantees it).
+value = sum_i p_i << (12*i), while the planner's interval analysis proves
+that no total can pass int64 (largest value x rows < 2**62). Past that
+bound `combine_terms(wide=True)` recombines the same partials in Python
+integers, which cannot wrap, and a
+total that does not fit the int64 the result column carries is the
+statement's out-of-range error (`SumOutOfRange`), never a wrapped number.
+What still bounds the device side is above: hi/lo sums exact to 2**31
+rows a dispatch.
 
 Reference analog: the partial/final two-stage hash aggregation
 (reference: executor/aggregate.go:146) — partials here are limb sums
@@ -37,6 +43,9 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from .. import obs
+from ..errno import ER_DATA_OUT_OF_RANGE, CodedError
 
 LIMB_BITS = 12
 _LIMB_MASK = (1 << LIMB_BITS) - 1
@@ -142,18 +151,68 @@ def merge_additive(vals) -> np.ndarray:
                   axis=0)
 
 
-def combine_partials(p: np.ndarray) -> np.ndarray:
+def combine_partials(p: np.ndarray, dtype=np.int64) -> np.ndarray:
     """int32[n_limbs, 2, segments] -> int64[segments], exact.
 
     Horner over limbs of (hi*4096 + lo); intermediates stay within int64
-    because the true total does.
+    because the true total does. `dtype=object` does the same in Python
+    integers, for a total that may not (combine_terms).
     """
-    p = np.asarray(p, dtype=np.int64)
+    p = np.asarray(p).astype(dtype)
     n_limbs = p.shape[0]
-    total = np.zeros(p.shape[2], dtype=np.int64)
+    total = np.zeros(p.shape[2], dtype=dtype)
     for i in range(n_limbs - 1, -1, -1):
         total = total * (1 << LIMB_BITS) + (p[i, 0] * _L2 + p[i, 1])
     return total
+
+
+# largest |value| x rows under which the int64 Horner cannot wrap
+INT64_SAFE = 2**62
+_I64_MIN, _I64_MAX = -(2**63), 2**63 - 1
+# the counter's label tuples, kept: one per aggregate on the decode path
+_WIDTH_KEY = {False: (("width", "int64"),), True: (("width", "wide"),)}
+
+
+class SumOutOfRange(CodedError):
+    """An exact SUM whose total does not fit the int64 its result column
+    carries (MySQL: ER_DATA_OUT_OF_RANGE, 'BIGINT value is out of
+    range'). The statement's error: no wrapped number, no host re-run."""
+
+    errno = ER_DATA_OUT_OF_RANGE
+    sqlstate = "22003"
+
+
+def needs_wide(magnitude: int, n_rows: int) -> bool:
+    """True where largest |value| x rows cannot prove that the total fits
+    the int64 Horner: the aggregate's schedule entry is marked `wide`."""
+    return magnitude * max(n_rows, 1) >= INT64_SAFE
+
+
+def check_int64(total: np.ndarray) -> np.ndarray:
+    """object[...] of Python integers -> int64[...], or SumOutOfRange."""
+    if total.size and (total.max() > _I64_MAX or total.min() < _I64_MIN):
+        raise SumOutOfRange("BIGINT value is out of range in 'sum'")
+    return total.astype(np.int64)
+
+
+def combine_terms(parts, shifts, wide: bool = False,
+                  sel=None) -> np.ndarray:
+    """sum_i combine(parts[i]) << shifts[i] -> int64[segments], exact.
+
+    parts: one int32[n_limbs, 2, segments] array per term of a decomposed
+    aggregate argument (bounds.decompose_terms), each already merged over
+    tiles and shards; `sel` keeps only those segments. Under the int64
+    bound (`wide` false) the Horner runs in int64. Past it the same
+    partials recombine in Python integers, and a total outside int64
+    raises SumOutOfRange."""
+    obs.SUM_RECOMBINE.inc_key(_WIDTH_KEY[bool(wide)], 1.0)
+    dtype = object if wide else np.int64
+    total = 0
+    for p, shift in zip(parts, shifts):
+        if sel is not None:
+            p = np.asarray(p)[:, :, sel]
+        total = total + (combine_partials(p, dtype) << shift)
+    return check_int64(total) if wide else total
 
 
 def float_seg_sums(

@@ -1032,6 +1032,8 @@ def _merge_partials(plan: PhysHashAgg, child: Chunk) -> Chunk:
             else:
                 masked = np.where(vvalid, vdata.astype(np.int64), 0)
             sums = _seg_reduce(np.add, masked, order, bounds)
+            if n > n_seg and masked.dtype.kind == "i":
+                sums = _unwrapped_sums(masked, sums, order, bounds)
             if d.func == "sum":
                 valid = cnts > 0
                 out_cols.append(Column(out_t, sums.astype(out_t.np_dtype),
@@ -1092,6 +1094,24 @@ def _gc_render(v, ft) -> str:
     if ft.is_float:
         return repr(float(v))
     return str(int(v))
+
+
+def _unwrapped_sums(values: np.ndarray, sums: np.ndarray,
+                    order: np.ndarray, bounds: np.ndarray) -> np.ndarray:
+    """`sums` (int64 segment sums of several int64 partials a group), once
+    it is certain that none of them wrapped: each partial is exact (the
+    coprocessor raises where one does not fit), but epoch + overlay or
+    partition partials can pass int64 together. A float estimate of the
+    absolute sums rules that out for all but totals near the edge; those
+    are added again in Python integers, and a total that does not fit is
+    the statement's out-of-range error, not a wrapped number."""
+    est = _seg_reduce(np.add, np.abs(values.astype(np.float64)), order,
+                      bounds)
+    if not len(est) or est.max() < 2.0 ** 62:
+        return sums
+    from ..copr.sumexact import check_int64
+    return check_int64(_seg_reduce(np.add, values.astype(object), order,
+                                   bounds))
 
 
 def _seg_reduce(ufunc, values: np.ndarray, order: np.ndarray,

@@ -1006,6 +1006,12 @@ PROCESS_METRICS = Registry()
 COPR_REQUESTS = PROCESS_METRICS.counter(
     "tidb_copr_requests_total",
     "coprocessor executions, by engine (device / host fallback)")
+SUM_RECOMBINE = PROCESS_METRICS.counter(
+    "tidb_copr_sum_recombine_total",
+    "exact integer SUM/AVG aggregates recombined from their int32 limb "
+    "partials on the host, by width: int64 (largest value x rows is under "
+    "2**62, Horner in int64) or wide (past it: arithmetic that cannot "
+    "wrap, and a total that does not fit the result is an error)")
 FRAG_FALLBACKS = PROCESS_METRICS.counter(
     "tidb_copr_fragment_fallbacks_total",
     "device-fragment gate rejections, by reason")
