@@ -57,6 +57,7 @@ from ..store.table_store import TableSnapshot
 from ..types.field_type import FieldType, TypeKind
 from . import host_exec
 from . import sumexact as SE
+from . import topnsel
 from .bounds import (
     Bound,
     decompose_terms,
@@ -222,6 +223,12 @@ class CopClient:
         # scan accounts its table's record span on the heatmap. None on
         # bare clients; one gated attribute test per execute() when off
         self.heat = None
+        # program key -> [the topnsel path its TopN body was traced with]
+        self._topn_paths: dict[Any, list] = {}
+        # a counter that never moved is not rendered: a client that has
+        # served no TopN yet must read 0 on /metrics, not be absent
+        for sel_path in topnsel.PATHS:
+            obs.TOPN_SELECT.inc(0, path=sel_path)
         _LIVE_CLIENTS.add(self)
 
     def _evict_stale(self, table_id: int, epoch_id: int) -> None:
@@ -1236,11 +1243,13 @@ class CopClient:
         bucket = tiles[0][1].shape[0]
         key = ("topn", _dag_key(dag, prepared), bucket, n,
                tuple(d for _, d in dag.topn.items))
+        taken = self._topn_taken(key, prepared)
         kern = self._kernel(key, lambda: self._build_topn_kernel(
             dag, prepared, expr, desc, n))
         with obs.stage("kernel", span_name="device.dispatch",
                        prog="titpu_topn"):
             devs = [kern(cols, vis) for cols, vis, _ in tiles]
+        obs.TOPN_SELECT.inc(path=taken[0])
         with obs.stage("device_get", span_name="device.fetch", clocked=True,
                        prog="titpu_topn"):
             outs = jax.device_get(devs)
@@ -1250,6 +1259,18 @@ class CopClient:
             if c is not None:
                 chunks.append(c)
         return chunks
+
+    def _topn_taken(self, key, prepared) -> list:
+        """The one-slot record, kept beside the program cached under
+        `key`, of the path its body selects the winners by: a body built
+        from `prepared` hands it to topnsel.select, which fills it in
+        when the program is traced (at its first dispatch) with what it
+        does for the shape it ranks. tidb_copr_topn_select_total counts
+        a read under it after the dispatch."""
+        with self._lock:
+            taken = self._topn_paths.setdefault(key, [])
+        prepared["__topn_taken__"] = taken
+        return taken
 
     def _topn_decode(self, dag, snap, out) -> Optional[Chunk]:
         ints = out["ints"]  # int32[2 + n_int_cols*2, k]
@@ -1299,6 +1320,7 @@ class CopClient:
         out_types = dag.output_types
 
         pack = prepared.get("__topn_pack__")
+        taken = prepared.get("__topn_taken__")
 
         def kernel(cols, row_mask):
             cols = widen32(cols)
@@ -1328,8 +1350,7 @@ class CopClient:
                     score = jnp.where(vl, v32 if desc else -v32,
                                       null_score)
                 score = jnp.where(mask, score, drop_score)
-            k = min(n, score.shape[0])
-            _, idx = jax.lax.top_k(score, k)
+            idx = topnsel.select(score, min(n, score.shape[0]), taken)
             # gather the k result rows in-kernel: the packed output is the
             # ONLY device->host transfer (k rows, not full columns)
             int_rows = [idx.astype(jnp.int32),

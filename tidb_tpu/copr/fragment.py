@@ -41,6 +41,7 @@ from ..chunk.chunk import Chunk
 from ..chunk.column import Column
 from ..plan.expr import Col
 from ..plan.fragment import FragmentDAG
+from . import topnsel
 from .bounds import expr_bounds, expr_device_safe, fits_int32
 from .client import (
     CopClient,
@@ -638,6 +639,7 @@ def _run_frag_batch(cop, frag, snaps, prepared, spans, builds, overlay,
                else ("bm", b["bm"].shape[0]) if "bm" in b
                else b["cols"][0][0].shape[0]
                for b in kern_builds))
+    taken = cop._topn_taken(key, prepared) if mode == "topn" else None
     kern = cop._kernel(key, lambda: cop._frag_jit(
         _build_frag_kernel(frag, prepared, spans, mode, raw=True, cop=cop),
         mode, prepared))
@@ -646,6 +648,8 @@ def _run_frag_batch(cop, frag, snaps, prepared, spans, builds, overlay,
         with obs.stage("kernel", span_name="device.dispatch", prog=prog):
             dev = kern(pcols, pvis, kern_builds) if aux is None \
                 else kern(pcols, pvis, kern_builds, aux)
+        if taken is not None:
+            obs.TOPN_SELECT.inc(path=taken[0])
         with obs.stage("device_get", span_name="device.fetch",
                        clocked=True, prog=prog):
             out = jax.device_get(dev)
@@ -687,6 +691,7 @@ def _run_frag_tiled(cop, frag, snaps, prepared, spans, builds, mode):
     kern = None
     devs = []
     kop = _mode_op(frag, mode)
+    taken = None
     jb_t, sb_t = builds[:len(frag.joins)], builds[len(frag.joins):]
     for ti, (cols, vis, cnt) in enumerate(tiles):
         kb = builds
@@ -702,6 +707,8 @@ def _run_frag_tiled(cop, frag, snaps, prepared, spans, builds, mode):
                        else ("bm", b["bm"].shape[0]) if "bm" in b
                        else b["cols"][0][0].shape[0]
                        for b in kb))
+            if mode == "topn":
+                taken = cop._topn_taken(key, prepared)
             kern = cop._kernel(key, lambda: cop._frag_jit(
                 _build_frag_kernel(frag, prepared, spans, mode, raw=True,
                                    cop=cop), mode, prepared))
@@ -723,6 +730,8 @@ def _run_frag_tiled(cop, frag, snaps, prepared, spans, builds, mode):
 
     if mode == "topn":
         # per-tile candidate rows; the host Sort/Limit above merge them
+        if taken:
+            obs.TOPN_SELECT.inc(path=taken[0])
         chunks = []
         for out in outs:
             c = _decode_frag_topn(frag, snaps, out)
@@ -1252,8 +1261,8 @@ def _build_frag_kernel(frag, prepared, spans, mode, raw=False, cop=None):
             comp = TP.composite_score(prepared["__topn_pack__"], cols,
                                       prepared, eval_expr)
             score = jnp.where(mask, comp, jnp.iinfo(jnp.int32).min)
-            k = min(frag.topn.n, score.shape[0])
-            _, idx = jax.lax.top_k(score, k)
+            idx = topnsel.select(score, min(frag.topn.n, score.shape[0]),
+                                 prepared.get("__topn_taken__"))
             int_rows = [idx.astype(jnp.int32),
                         mask[idx].astype(jnp.int32)]
             flt_rows = []

@@ -1012,6 +1012,12 @@ SUM_RECOMBINE = PROCESS_METRICS.counter(
     "partials on the host, by width: int64 (largest value x rows is under "
     "2**62, Horner in int64) or wide (past it: arithmetic that cannot "
     "wrap, and a total that does not fit the result is an error)")
+TOPN_SELECT = PROCESS_METRICS.counter(
+    "tidb_copr_topn_select_total",
+    "TopN coprocessor reads, by how their program selects a tile's k "
+    "winners: block (copr/topnsel.py: block maxima, the k best blocks, a "
+    "top-k of their rows) or full (top-k over the whole tile, where the "
+    "tile is too short for blocks to pay)")
 FRAG_FALLBACKS = PROCESS_METRICS.counter(
     "tidb_copr_fragment_fallbacks_total",
     "device-fragment gate rejections, by reason")
