@@ -4,8 +4,8 @@ and partitioned (non-broadcast) joins must match single-device bit-for-bit.
 Counterpart of the reference's MPP exchange modes (reference:
 planner/core/fragment.go:45 hash-partition vs broadcast ExchangeSender,
 store/tikv/mpp.go:372): parallel/exchange.py routes rows between devices
-with one all_to_all; parallel/dist.py uses it to (a) partition group
-spaces for high-cardinality aggregation and (b) shard large builds by key
+with one all_to_all; the sharded placement (copr/placement.py) uses it to
+(a) partition group spaces for high-cardinality aggregation and (b) shard large builds by key
 range with probe-row routing.
 """
 
@@ -13,10 +13,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from tidb_tpu.parallel import DistCopClient, make_mesh
-from tidb_tpu.parallel.dist import shard_map
+from sharded_client import sharded_client
+from tidb_tpu.copr.placement import make_mesh
 from tidb_tpu.parallel.exchange import capacity_for, mix_hash, route_rows
 from tidb_tpu.session import Session
 
@@ -84,6 +85,9 @@ def corpus():
     return single
 
 
+MESH = f"@mesh{N_DEV}"  # every tag of a sharded dispatch ends in it
+
+
 def _engines(session, sql):
     return {r[3] for r in session.execute("EXPLAIN ANALYZE " + sql).rows
             if r[3]}
@@ -93,12 +97,12 @@ def test_distributed_hc_groupby(corpus):
     """Q3's full l_orderkey group space shards via the group exchange."""
     from tidb_tpu.bench.tpch_queries import TPCH_QUERIES
 
-    dist = Session(corpus.storage, cop=DistCopClient(make_mesh()))
+    dist = Session(corpus.storage, cop=sharded_client(corpus.storage))
     sql = TPCH_QUERIES["q3"]
     assert dist.query(sql) == corpus.query(sql)
     # Q3's full ORDER BY resolves, so the fused join+agg+topn cut
     # (device[fat]) serves it; device[hc] is the unfused candidate path
-    assert _engines(dist, sql) & {"device[fat]", "device[hc]"}
+    assert _engines(dist, sql) & {"device[fat]" + MESH, "device[hc]" + MESH}
 
 
 def test_partitioned_join(corpus):
@@ -106,7 +110,7 @@ def test_partitioned_join(corpus):
     rows route over the mesh, results stay bit-identical."""
     from tidb_tpu.bench.tpch_queries import TPCH_QUERIES
 
-    cop = DistCopClient(make_mesh())
+    cop = sharded_client(corpus.storage)
     cop.partition_join_threshold = 1000  # force orders (15k) to partition
     dist = Session(corpus.storage, cop=cop)
     for q, want_engines in (("q12", {"device[agg]"}),
@@ -114,7 +118,7 @@ def test_partitioned_join(corpus):
                             ("q5", {"device[agg]"})):
         sql = TPCH_QUERIES[q]
         assert dist.query(sql) == corpus.query(sql), q
-        assert _engines(dist, sql) & want_engines, q
+        assert _engines(dist, sql) & {e + MESH for e in want_engines}, q
         part_keys = [k for k in cop._col_cache if "partb" in str(k)]
         assert part_keys, "partitioned build staging did not engage"
 
@@ -123,7 +127,7 @@ def test_partitioned_join_with_dml_visibility(corpus):
     """Deleted probe/build rows stay invisible through the exchange."""
     from tidb_tpu.bench.tpch_queries import TPCH_QUERIES
 
-    cop = DistCopClient(make_mesh())
+    cop = sharded_client(corpus.storage)
     cop.partition_join_threshold = 1000
     s = Session(corpus.storage, cop=cop)
     s.execute("BEGIN")

@@ -261,19 +261,75 @@ def test_truncate_partition_keeps_epoch_listeners():
         assert mc.on_epoch_replaced in st.evict_hooks, st.table.name
 
 
+def _spy_recorder(monkeypatch) -> list:
+    """Every flight-recorder entry point, intercepted."""
+    calls: list[str] = []
+    for meth in ("note_pending", "collect", "note_compile"):
+        orig = getattr(M.MeshFlightRecorder, meth)
+
+        def spy(self, *a, _m=meth, _o=orig, **k):
+            calls.append(_m)
+            return _o(self, *a, **k)
+
+        monkeypatch.setattr(M.MeshFlightRecorder, meth, spy)
+    return calls
+
+
 class TestFallback:
-    def test_disabled_plane_hands_out_plain_client(self):
+    def test_disabled_plane_hands_out_plain_client(self, monkeypatch):
+        """A disabled plane shards nothing and queues no recorder work:
+        its client is the plain class without a recorder, every epoch —
+        far above the row threshold or not — is placed single, and the
+        statement path never enters the flight recorder."""
         assert not make_plane(enabled=False).active
+        calls = _spy_recorder(monkeypatch)
         old = M.get_plane().cfg
         try:
-            M.configure(enabled=False)
+            M.configure(enabled=False, shard_threshold_rows=0)
             s = Session()
             assert type(s.cop) is CopClient
+            assert s.cop.recorder is None
+            load_lineitem(s, 2048)
+            want = Session(s.storage, cop=CopClient()).query(TPCH_Q6)
+            assert s.query(TPCH_Q6) == want
+            assert not sharded_arrays(s.cop)
+            eng = engines(s, TPCH_Q6)
+            assert eng and all("@mesh" not in e for e in eng), eng
+            assert calls == [], calls
+            assert s.cop.take_mesh_note() is None
+            assert s.cop.drain_mesh_warnings() == ()
+            assert M.client_of(s.storage) is None
+            assert M.shard_rows(s.storage) == []
+            assert M.storage_rows(s.storage) == []
         finally:
             M.configure(enabled=old.enabled, axis_size=old.axis_size,
                         shard_threshold_rows=old.shard_threshold_rows,
                         replicate_threshold_bytes=(
                             old.replicate_threshold_bytes))
+
+    @pytest.mark.parametrize("cfg", [dict(enabled=False),
+                                     dict(axis_size=1), dict()],
+                             ids=["disabled", "one-device", "active"])
+    def test_client_for_is_the_one_client_class(self, cfg):
+        """Whatever the plane's state, client_for hands out an object
+        whose type is exactly CopClient, the same object to every
+        session of a storage, and a different one to another storage;
+        only an active plane gives it a flight recorder."""
+        plane = make_plane(**cfg)
+        st = Session(cop=CopClient()).storage
+        s1 = Session(st, cop=plane.client_for(st))
+        s2 = Session(st, cop=plane.client_for(st))
+        assert type(s1.cop) is CopClient
+        assert s1.cop is s2.cop, "sessions of one storage must share"
+        assert s1.cop.plane is plane
+        assert (s1.cop.recorder is not None) == plane.active
+        other = Session(cop=CopClient()).storage
+        assert plane.client_for(other) is not s1.cop, \
+            "storages must not share"
+        # another plane over the same storage gets its own client, and
+        # the first plane still answers with the one it made
+        assert make_plane(**cfg).client_for(st) is not s1.cop
+        assert plane.client_for(st) is s1.cop
 
     def test_single_axis_inactive(self):
         plane = make_plane(axis_size=1)
@@ -294,13 +350,58 @@ class TestFallback:
 
     def test_default_session_uses_mesh_client(self):
         """Session() defaults route through the process plane: with 8
-        devices visible the storage gets ONE shared mesh client."""
+        devices visible the storage gets ONE shared client, attached to
+        that plane and carrying its flight recorder; sessions of one
+        storage share it and storages do not."""
         s1 = Session()
         s2 = Session(s1.storage)
-        assert isinstance(s1.cop, M.MeshCopClient)
+        assert type(s1.cop) is CopClient
+        assert s1.cop.plane is M.get_plane() and M.get_plane().active
+        assert s1.cop.recorder is not None
+        assert M.client_of(s1.storage) is s1.cop
         assert s1.cop is s2.cop, "sessions of one storage must share"
         other = Session()
         assert other.cop is not s1.cop, "storages must not share"
+
+    def test_sharded_fact_joins_single_dimension_on_one_client(self):
+        """One client, both placements: a fact table above the row
+        threshold joined to a dimension table below it runs sharded
+        (the probe decides; the dimension replicates) and equals the
+        single-device answer, while the dimension scanned alone on the
+        SAME client is placed single, without a mesh tag and without a
+        multi-device array of its own."""
+        single = Session(cop=CopClient())
+        single.execute("create table dim (k int not null primary key, "
+                       "tag varchar(8) not null)")
+        single.execute("create table fact (id int not null primary key, "
+                       "k int not null, v int not null)")
+        single.execute("insert into dim values (1,'a'),(2,'b'),(3,'c')")
+        vals = ",".join(f"({i},{i % 3 + 1},{i % 100})"
+                        for i in range(1, 6001))
+        single.execute(f"insert into fact values {vals}")
+        single.storage.flush()
+        plane = make_plane()  # threshold 512: fact shards, dim does not
+        mesh = Session(single.storage,
+                       cop=plane.client_for(single.storage))
+        join = ("select dim.tag, sum(fact.v) from fact join dim "
+                "on fact.k = dim.k group by dim.tag order by dim.tag")
+        solo = "select count(*), min(k), max(k) from dim"
+        assert mesh.query(join) == single.query(join)
+        assert mesh.query(solo) == single.query(solo)
+        assert any(e.endswith("@mesh8") for e in engines(mesh, join))
+        assert engines(mesh, solo) == {"device"}
+        dim = next(st for st in single.storage.tables.values()
+                   if st.table.name == "dim")
+        eid = dim.epoch.epoch_id
+        with mesh.cop._lock:
+            solo_arrays = [v for k, v in mesh.cop._col_cache.items()
+                           if k[0] == eid and len(k) == 3
+                           and isinstance(k[1], int)]
+            spaces = {k[0] for k in mesh.cop._kernels}
+        assert solo_arrays and all(
+            len(a.sharding.device_set) == 1
+            for a in M._walk_arrays(solo_arrays))
+        assert spaces == {"single", "shard"}, spaces
 
 
 class TestConfig:

@@ -389,8 +389,8 @@ class TestScrapeHygiene:
     def test_device_bytes_memoized_per_generation(self, sessions):
         _, mesh, plane = sessions
         mesh.query(TPCH_Q6)
-        t1 = mesh.cop.telemetry()
-        t2 = mesh.cop.telemetry()
+        t1 = M.telemetry(mesh.cop)
+        t2 = M.telemetry(mesh.cop)
         assert t1 is t2, "telemetry walk not memoized across scrapes"
         walks = []
         orig = M._walk_arrays
@@ -440,10 +440,10 @@ class TestScrapeHygiene:
 
 def test_plain_client_statement_path_does_zero_recorder_work(
         monkeypatch):
-    """With the mesh plane inactive the plain CopClient path must not
-    touch the recorder at all: no pendings, no collections, no ring
-    allocations — asserted by intercepting every recorder entry
-    point."""
+    """A client without a plane must not touch the recorder at all: no
+    pendings, no collections, no ring allocations — asserted by
+    intercepting every recorder entry point. (A disabled plane's client
+    is held to the same in tests/test_mesh.py::TestFallback.)"""
     calls: list[str] = []
     for meth in ("note_pending", "collect", "note_compile"):
         orig = getattr(M.MeshFlightRecorder, meth)
@@ -459,7 +459,8 @@ def test_plain_client_statement_path_does_zero_recorder_work(
     s.query("select sum(b) from z where a >= 1")
     s.query("explain analyze select sum(b) from z where a >= 1")
     assert calls == [], calls
-    # the base hooks are allocation-free constants
+    # without a recorder the hooks are allocation-free constants
+    assert s.cop.recorder is None
     assert s.cop.take_mesh_note() is None
     assert s.cop.drain_mesh_warnings() == ()
 

@@ -5,7 +5,7 @@ import jax
 import pytest
 
 from tidb_tpu.bench.tpch import TPCH_Q1, TPCH_Q6, load_lineitem
-from tidb_tpu.parallel import DistCopClient, make_mesh
+from sharded_client import sharded_client
 from tidb_tpu.session import Session
 
 N_ROWS = 20_000
@@ -16,7 +16,7 @@ def sessions():
     assert len(jax.devices()) >= 8, "conftest must provide 8 virtual devices"
     single = Session()
     load_lineitem(single, N_ROWS)
-    dist = Session(single.storage, cop=DistCopClient(make_mesh()))
+    dist = Session(single.storage, cop=sharded_client(single.storage))
     return single, dist
 
 
@@ -55,7 +55,6 @@ def test_dist_fragment_join_agg_device_path(monkeypatch):
     import numpy as np
 
     import tidb_tpu.copr.fragment as F
-    from tidb_tpu.parallel import DistCopClient, make_mesh
     from tidb_tpu.session import Session
 
     def boom(frag, snaps):
@@ -74,8 +73,8 @@ def test_dist_fragment_join_agg_device_path(monkeypatch):
     for st in single.storage.tables.values():
         st.compact(safe)
 
-    mesh = make_mesh(jax.devices()[:8])
-    dist = Session(single.storage, cop=DistCopClient(mesh))
+    dist = Session(single.storage,
+                   cop=sharded_client(single.storage, jax.devices()[:8]))
     q = ("SELECT g, SUM(v), COUNT(*), MIN(v), MAX(v) FROM f, d "
          "WHERE f.k = d.k GROUP BY g ORDER BY g")
     got = dist.query(q)
@@ -86,7 +85,6 @@ def test_dist_fragment_join_agg_device_path(monkeypatch):
 
 def test_dist_topn_and_rows(monkeypatch):
     import tidb_tpu.copr.fragment as F  # noqa: F401
-    from tidb_tpu.parallel import DistCopClient, make_mesh
     from tidb_tpu.session import Session
 
     single = Session()
@@ -96,8 +94,8 @@ def test_dist_topn_and_rows(monkeypatch):
     safe = single.storage.safe_ts()
     for st in single.storage.tables.values():
         st.compact(safe)
-    mesh = make_mesh(jax.devices()[:8])
-    dist = Session(single.storage, cop=DistCopClient(mesh))
+    dist = Session(single.storage,
+                   cop=sharded_client(single.storage, jax.devices()[:8]))
     for q in ("SELECT a, b FROM s ORDER BY b DESC, a LIMIT 9",
               "SELECT a FROM s WHERE b < 50 ORDER BY a"):
         assert dist.query(q) == single.query(q), q

@@ -121,7 +121,7 @@ def test_distributed_min_max():
     """min/max partials must merge with pmin/pmax, not psum (P2 over ICI)."""
     import jax
 
-    from tidb_tpu.parallel import DistCopClient, make_mesh
+    from sharded_client import sharded_client
 
     single = Session()
     single.execute(
@@ -130,8 +130,8 @@ def test_distributed_min_max():
     ins = ",".join(f"({g},{v})" for g, v in vals)
     single.execute(f"insert into m values {ins}")
 
-    mesh = make_mesh(jax.devices()[:4])
-    dist = Session(single.storage, cop=DistCopClient(mesh))
+    dist = Session(single.storage,
+                   cop=sharded_client(single.storage, jax.devices()[:4]))
     sql = ("select g, min(v), max(v), sum(v), count(*) from m "
            "group by g order by g")
     assert dist.query(sql) == single.query(sql)

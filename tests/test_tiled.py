@@ -16,7 +16,7 @@ import pytest
 
 from tidb_tpu.bench.tpch import TPCH_Q1, TPCH_Q6, load_lineitem
 from tidb_tpu.copr.client import CopClient
-from tidb_tpu.parallel import DistCopClient, make_mesh
+from sharded_client import sharded_client
 from tidb_tpu.session import Session
 
 N_ROWS = 4096
@@ -88,7 +88,7 @@ def test_tiled_distributed_mesh():
     """Tiles x shards: every tile row-sharded over the 8-device mesh."""
     single = Session()
     load_lineitem(single, N_ROWS)
-    cop = DistCopClient(make_mesh())
+    cop = sharded_client(single.storage)
     cop.TILE_ROWS = TILE
     dist = Session(single.storage, cop=cop)
     for _, sql in QUERIES:

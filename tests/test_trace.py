@@ -966,6 +966,113 @@ def test_profiler_session_shows_stages_in_the_host_plane(tmp_path):
     assert both == []
 
 
+# ---- the program each (placement, kind) runs, and the tag it answers
+# under: one client class, two placements (copr/placement.py) ----
+
+_KIND_SQL = {
+    # kind: (statement, engine tag, program when single, when sharded)
+    "agg": ("select sum(v) from f where c > 0",
+            "device", "titpu_agg", "titpu_mesh_agg"),
+    "topn": ("select k, c from f order by c desc limit 5",
+             "device", "titpu_topn", "titpu_mesh_topn"),
+    "rows": ("select k from f where c = 7 order by k",
+             "device", "titpu_rowmask", "titpu_mesh_rows"),
+    "frag_agg": ("select x, sum(v) from f, dim where fg = dg group by x",
+                 "device[agg]", "titpu_frag_agg", "titpu_mesh_frag_agg"),
+    "frag_hc": ("select dg, x, sum(v) from f, dim where fg = dg "
+                "group by dg, x order by sum(v) desc, x limit 5",
+                "device[fat]", "titpu_frag_hc", "titpu_mesh_frag_hc"),
+    "frag_topn": ("select k, x, b from f, dim where fg = dg "
+                  "order by x desc, b, k limit 7",
+                  "device[topn]", "titpu_frag_topn", "titpu_mesh_frag_topn"),
+    "frag_rows": ("select k, x from f, dim where fg = dg and c = 7 "
+                  "order by k",
+                  "device[rows]", "titpu_frag_rows", "titpu_mesh_frag_rows"),
+}
+
+
+@pytest.fixture(scope="module")
+def placed_corpus():
+    """A 6 000-row fact table and a 300-row dimension table, loaded in
+    bulk, with every statement's single-device answer."""
+    import numpy as np
+
+    from tidb_tpu.copr.client import CopClient
+
+    rng = np.random.default_rng(31)
+    base = Session(cop=CopClient())
+    n, nd = 6000, 300
+    base.execute("create table f (k bigint primary key, fg int, b int, "
+                 "c int, v int)")
+    base.storage.table_store(base.catalog.table("test", "f").id).bulk_load(
+        [np.arange(n, dtype=np.int64), rng.integers(0, nd, n),
+         rng.integers(0, 7, n), rng.integers(-50, 100, n),
+         rng.integers(-30, 30, n)])
+    base.execute("create table dim (dg bigint primary key, x int)")
+    base.storage.table_store(base.catalog.table("test", "dim").id).bulk_load(
+        [np.arange(nd, dtype=np.int64), rng.integers(0, 40, nd)])
+    return base, {k: base.query(v[0]) for k, v in _KIND_SQL.items()}
+
+
+@pytest.mark.parametrize("kind", list(_KIND_SQL))
+@pytest.mark.parametrize("placement", ["single", "sharded"])
+def test_program_name_and_engine_tag(placed_corpus, monkeypatch,
+                                     placement, kind):
+    """Each kind of device program under each placement: the one name it
+    is jitted under (what the profiler and the compile cache see) and
+    the engine tag EXPLAIN ANALYZE answers with. A fresh client builds
+    its programs through placement.named_jit and nowhere else."""
+    from sharded_client import sharded_client
+    from tidb_tpu.copr import placement as PL
+    from tidb_tpu.copr.client import CopClient
+
+    base, answers = placed_corpus
+    sql, tag, single_prog, sharded_prog = _KIND_SQL[kind]
+    built = []
+    orig = PL.named_jit
+
+    def spy(fn, name):
+        built.append(name)
+        return orig(fn, name)
+
+    monkeypatch.setattr(PL, "named_jit", spy)
+    if placement == "single":
+        cop, want_prog, want_tag = CopClient(), single_prog, tag
+    else:
+        cop = sharded_client(base.storage)
+        want_prog, want_tag = sharded_prog, tag + "@mesh8"
+    s = Session(base.storage, cop=cop)
+    assert sorted(s.query(sql)) == sorted(answers[kind])
+    assert built == [want_prog], built
+    tags = {r[3] for r in s.execute("explain analyze " + sql).rows if r[3]}
+    assert tags == {want_tag}, tags
+    assert built == [want_prog], "the warm run built a program again"
+    assert type(cop) is CopClient and {k[0] for k in cop._kernels} == {
+        "single" if placement == "single" else "shard"}
+
+
+def test_parallel_imports_nothing_from_copr():
+    """The arrows point one way: copr/placement -> parallel/exchange.
+    No module under tidb_tpu/parallel imports tidb_tpu.copr."""
+    import ast
+
+    pdir = os.path.join(ROOT, "tidb_tpu", "parallel")
+    files = [f for f in os.listdir(pdir) if f.endswith(".py")]
+    assert "exchange.py" in files and "dist.py" not in files, files
+    for f in files:
+        with open(os.path.join(pdir, f)) as fh:
+            tree = ast.parse(fh.read())
+        for node in ast.walk(tree):
+            mods = []
+            if isinstance(node, ast.Import):
+                mods = [a.name for a in node.names]
+            elif isinstance(node, ast.ImportFrom):
+                # `from ..copr import x`, `from .. import copr`
+                mods = [(node.module or "")] + [
+                    f"{node.module or ''}.{a.name}" for a in node.names]
+            assert not any("copr" in m.split(".") for m in mods), (f, mods)
+
+
 def _bench_statements() -> list[str]:
     return sorted(f[:-5] for f in os.listdir(
         os.path.join(ROOT, "benchmarks", "statements")))

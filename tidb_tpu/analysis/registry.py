@@ -78,7 +78,9 @@ TLS_FRAME_FNS: tuple[str, ...] = (
 # return feeding one) leaves the frame management to the caller and is
 # almost always a leak
 TLS_FRAME_CTX_ONLY: tuple[str, ...] = (
-    "placement_scope",             # copr/client.py, copr/mesh.py
+    # copr/client.py; opened by executor/engine.py, copr/fragment.py
+    # and copr/analyze.py
+    "placement_scope",
 )
 
 # ---- thread discipline ------------------------------------------------------

@@ -206,7 +206,7 @@ def device_column_stats(cop, snap, offsets: list[int]):
         return {}
     dag = CopDAG(scan=DAGScan(snap.store.table.id, usable))
     # placement must match the query path's: an ANALYZE staging outside
-    # the scope would seed the SHARED mesh client's epoch cache with
+    # the scope would seed the SHARED client's epoch cache with
     # single-device arrays under the keys sharded queries hit, silently
     # defeating the persistent sharded residency
     with cop.placement_scope(snap):
@@ -218,7 +218,7 @@ def device_column_stats(cop, snap, offsets: list[int]):
                 from .client import widen32
                 (d, v), = widen32([(d, v)])
                 return _column_partials(d, v & vis)
-            from .client import named_jit
+            from .placement import named_jit
             return named_jit(kernel, "titpu_analyze")
 
         # one kernel per (dtype, bucket) — shared across all columns of

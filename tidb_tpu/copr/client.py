@@ -40,6 +40,7 @@ string ordering compares.
 from __future__ import annotations
 
 import threading
+from contextlib import contextmanager
 from dataclasses import dataclass
 from typing import Any, Optional
 
@@ -68,6 +69,7 @@ from .bounds import (
 )
 from .eval import CompileError, DeviceError, eval_expr, selection_mask
 from .npeval import NumpyEval
+from .placement import SINGLE, _mask_digest, _narrow, _plan_digest
 
 _I32_MAX = np.int32(2**31 - 1)
 _I32_MIN = np.int32(-(2**31) + 1)
@@ -94,16 +96,6 @@ _FLOAT_BLOCKS = 32  # per-segment f32 block partials (host sums in f64)
 import os as _os
 
 TILE_ROWS_DEFAULT = int(_os.environ.get("TIDB_TPU_TILE_ROWS", 1 << 22))
-
-
-def _bucket(n: int) -> int:
-    """Static shape bucket: smallest of {2^k, 1.5*2^k} >= max(n, 256)."""
-    b = 256
-    while b < n:
-        if b + b // 2 >= n:
-            return b + b // 2
-        b *= 2
-    return b
 
 
 # ---- device telemetry -------------------------------------------------------
@@ -201,10 +193,15 @@ class CopClient:
     TILE_ROWS = TILE_ROWS_DEFAULT
 
     def __init__(self) -> None:
-        # per-thread placement state (the mesh client keeps its current
-        # shard/single mode and build-staging flag here; a client is
-        # shared by every session of a storage, so this must be TLS)
+        # per-thread placement (placement_scope); a client is shared by
+        # every session of a storage, so this must be TLS
         self._tls = threading.local()
+        # the mesh plane that chooses placements (copr/mesh.py MeshPlane)
+        # and, while that plane is active, its flight recorder for this
+        # client; both attached by MeshPlane.client_for. A bare client
+        # places every epoch on one device and records nothing
+        self.plane = None
+        self.recorder = None
         # (epoch_id, offset, bucket) -> (device data, device valid);
         # mutation-versioned so telemetry walks memoize per generation
         self._col_cache: _VersionedDict = _VersionedDict()
@@ -265,58 +262,61 @@ class CopClient:
         lifetime."""
         self._evict_stale(store.table.id, store.epoch.epoch_id)
 
-    # ---- placement plane (overridden by the mesh client) -----------------
+    # ---- placement (copr/placement.py) -----------------------------------
+    # builds larger than this many rows replicate no more under a
+    # sharded placement: they shard by key (Sharded.partition_build).
+    # Tests shrink it to force the partitioned path at toy scale.
+    partition_join_threshold = 1 << 21
+
+    @property
+    def placement(self):
+        """This thread's placement for the dispatch in flight."""
+        return getattr(self._tls, "placement", None) or SINGLE
+
+    @contextmanager
     def placement_scope(self, snap):
-        """Context manager pinning this thread's placement decision for
-        one dispatch (engine.py opens it per plan node; the mesh client
-        decides shard-vs-single from the probe epoch here)."""
-        from contextlib import nullcontext
-        return nullcontext()
+        """Pin this thread's placement for one dispatch, as the plane
+        decides it from the snapshot's epoch (engine.py opens it per plan
+        node, the fragment executor from the probe table), so every
+        staging / kernel decision below sees one consistent answer."""
+        plane = self.plane
+        prev = getattr(self._tls, "placement", None)
+        self._tls.placement = SINGLE if plane is None \
+            else plane.placement_for(snap)
+        try:
+            yield
+        finally:
+            self._tls.placement = prev
 
-    def _device_engine(self) -> str:
-        """EXPLAIN ANALYZE engine tag for single-table device paths."""
-        return "device"
-
-    # mesh flight-recorder hooks (overridden by the mesh client): the
-    # single-device statement path pays ONE no-op method call per plan
-    # node / statement and allocates nothing — the zero-work contract
-    # the recorder tests pin
+    # mesh flight recorder (copr/mesh.py), read by the engine per plan
+    # node and by the session per statement. A client without one pays an
+    # attribute test and allocates nothing — the zero-work contract the
+    # recorder tests pin
     def take_mesh_note(self):
         """Collect + return this thread's pending per-shard dispatch
-        accounting (None on the single-device client)."""
-        return None
+        accounting (None when nothing sharded ran)."""
+        rec = self.recorder
+        return None if rec is None else rec.collect()
 
     def drain_mesh_warnings(self) -> tuple:
-        """Pop this thread's pending mesh skew warnings (empty on the
-        single-device client)."""
-        return ()
+        """Pop this thread's pending mesh skew warnings."""
+        rec = self.recorder
+        return () if rec is None else rec.drain_warnings()
 
     def discard_mesh_pending(self) -> None:
-        """Drop per-shard accounting queued by a failed statement
-        (no-op on the single-device client)."""
-        return None
-
-    def _frag_engine(self, mode: str) -> str:
-        return f"device[{mode}]"
-
-    def _partition_build(self, snap: TableSnapshot) -> bool:
-        """True when a join build side is too large to replicate and
-        should shard by key range (the hash-partition vs broadcast
-        exchange election; the mesh client also gates on bytes)."""
-        thr = self.partition_join_threshold
-        return thr is not None and snap.epoch.num_rows > thr
-
-    def _stage_key_suffix(self) -> tuple:
-        """Placement tag appended to staging cache keys. The dist client
-        returns ("rep",) while staging a broadcast build: one epoch can
-        be BOTH a sharded probe and a replicated build, and aliasing the
-        two placements under one key would pin a full replica on every
-        device and re-shard it per dispatch."""
-        return ()
+        """Drop per-shard accounting queued by a failed statement."""
+        rec = self.recorder
+        if rec is not None:
+            rec.discard_pending()
 
     # ==================== public entry ====================
     def execute(self, dag: CopDAG, snap: TableSnapshot) -> CopResult:
         try:
+            if getattr(self._tls, "placement", None) is None:
+                # no scope open (a direct caller, not engine.py): ask
+                # the plane here
+                with self.placement_scope(snap):
+                    return self._execute(dag, snap)
             return self._execute(dag, snap)
         except jax.errors.JaxRuntimeError as e:
             raise DeviceError.of(e) from e
@@ -388,7 +388,7 @@ class CopClient:
             if not chunks:
                 chunks = [self._empty_chunk(dag, snap)]
             return CopResult(chunks, is_partial_agg=dag.agg is not None,
-                             engine=self._device_engine())
+                             engine=self.placement.engine())
 
     def _try_group_fragment(self, dag: CopDAG, snap: TableSnapshot,
                             reason: str) -> Optional[CopResult]:
@@ -847,9 +847,6 @@ class CopClient:
             return None, None
         return cards, offsets
 
-    def _bucket_size(self, n: int) -> int:
-        return _bucket(n)
-
     # ==================== batch execution ====================
     def _run_batch(
         self,
@@ -900,7 +897,8 @@ class CopClient:
             cols, vis, _, _ = self._stage_inputs(dag, snap, overlay=False)
             return [(cols, vis, n)]
         T = self.TILE_ROWS
-        b = self._bucket_size(T)
+        pl = self.placement
+        b = pl.bucket_size(T)
         with self._lock:
             cacheable = self._live_epochs.get(dag.scan.table_id) \
                 == epoch.epoch_id
@@ -931,7 +929,7 @@ class CopClient:
                         data, self._col_stats(snap, off)), b)
                     pvalid = _pad_bool(vslice, b)
                     with obs.stage("transfer"):
-                        cached = self._place_cols(padded, pvalid)
+                        cached = pl.place_cols(padded, pvalid)
                     _note_transfer(cached)
                     if cacheable:
                         with self._lock:
@@ -945,7 +943,7 @@ class CopClient:
             if vis is None:
                 pmask = _pad_bool(snap.base_visible[lo:lo + cnt], b)
                 with obs.stage("transfer"):
-                    vis = self._place_mask(pmask)
+                    vis = pl.place_mask(pmask)
                 _note_transfer(vis)
                 if cacheable:
                     with self._lock:
@@ -953,28 +951,23 @@ class CopClient:
             tiles.append((dev_cols, vis, cnt))
         return tiles
 
-    # placement hooks: EVERY staged scan column/mask is created through
-    # these, and the PLACED arrays are what the caches hold — so the
-    # distributed client's row-sharded epochs stay device-resident across
-    # queries instead of being resharded per dispatch (host numpy in,
-    # device arrays out)
-    def _place_cols(self, data, valid):
-        return jnp.asarray(data), jnp.asarray(valid)
-
-    def _place_mask(self, mask):
-        return jnp.asarray(mask)
-
-    def _stage_inputs(self, dag: CopDAG, snap: TableSnapshot, overlay: bool):
+    def _stage_inputs(self, dag: CopDAG, snap: TableSnapshot, overlay: bool,
+                      build: bool = False):
         """Pad + upload scan columns as 32-bit device buffers; returns device
         (data, valid) pairs, the device row-visibility mask, host numpy
         views, and the host-side visibility mask (so paths that need no
-        device work never touch the device)."""
+        device work never touch the device). EVERY staged scan column and
+        mask is created through the placement (host numpy in, device
+        arrays out), and the PLACED arrays are what the caches hold, so a
+        sharded epoch stays device-resident across queries; `build` says
+        the table is staged as a join's build side."""
         offsets = dag.scan.col_offsets
         narrow = _narrow
+        pl = self.placement
 
         if overlay:
             n = len(snap.overlay_handles)
-            b = self._bucket_size(n)
+            b = pl.bucket_size(n)
             host_cols = []
             dev_cols = []
             for ci, off in enumerate(offsets):
@@ -983,18 +976,18 @@ class CopClient:
                 vfull = np.ones(n, bool) if valid is None else valid
                 host_cols.append((data, vfull))
                 with obs.stage("transfer"):
-                    dev_cols.append(self._place_cols(
+                    dev_cols.append(pl.place_cols(
                         _pad(narrow(data), b), _pad_bool(vfull, b)))
                 _note_transfer(dev_cols[-1])
             mask = np.zeros(b, bool)
             mask[:n] = True
             with obs.stage("transfer"):
-                dev_mask = self._place_mask(mask)
+                dev_mask = pl.place_mask(mask)
             return dev_cols, dev_mask, host_cols, mask[:n]
 
         epoch = snap.epoch
         n = epoch.num_rows
-        b = self._bucket_size(n)
+        b = pl.bucket_size(n)
         with self._lock:
             # a session on an already-superseded snapshot must not re-seed
             # the cache: eviction only clears the immediately superseded
@@ -1003,7 +996,7 @@ class CopClient:
                 == epoch.epoch_id
         dev_cols = []
         host_cols = []
-        sfx = self._stage_key_suffix()
+        sfx = pl.stage_key_suffix(build)
         for off in offsets:
             key = (epoch.epoch_id, off, b) + sfx
             data = epoch.columns[off]
@@ -1017,7 +1010,7 @@ class CopClient:
                     data, self._col_stats(snap, off)), b)
                 pvalid = _pad_bool(vfull, b)
                 with obs.stage("transfer"):
-                    cached = self._place_cols(padded, pvalid)
+                    cached = pl.place_cols(padded, pvalid, build)
                 _note_transfer(cached)
                 if cacheable:
                     with self._lock:
@@ -1033,7 +1026,7 @@ class CopClient:
         if vis is None:
             pmask = _pad_bool(snap.base_visible, b)
             with obs.stage("transfer"):
-                vis = self._place_mask(pmask)
+                vis = pl.place_mask(pmask, build)
             _note_transfer(vis)
             if cacheable:
                 with self._lock:
@@ -1048,53 +1041,35 @@ class CopClient:
                     self._mask_cache[vis_key] = vis
         return dev_cols, vis, host_cols, snap.base_visible
 
-    # ---- fragment placement/compilation hooks (the distributed client
-    # overrides these: probe shards over the mesh, build tables replicate
-    # — the MPP broadcast-join placement, store/tikv/batch_coprocessor.go
-    # analog) ----
-    supports_hc = True
-    hc_exchange_blocks = 1  # candidate partitions in hc outputs
-    # builds never partition on a single device (everything is local);
-    # the distributed client sets a row threshold + the staging/routing
-    partition_join_threshold = None
-    frag_axis = None
-
-    def _hc_exchange_fn(self, frag, prepared):
-        """Group-partition exchange for the hc path; None on a single
-        device (all groups are already local). The distributed client
-        returns an all_to_all router (parallel/exchange.py)."""
-        return None
-
-    def _join_exchange_fn(self, frag, prepared, spans):
-        return None
-
-    def _stage_partitioned_build(self, t, snap, lo, span, j):
-        raise NotImplementedError(
-            "partitioned builds require the distributed client")
-
-    def _stage_build_table(self, facade, snap):
-        return self._stage_inputs(facade, snap, overlay=False)
-
-    def _place_build_array(self, arr, key=None):
-        return arr
-
-    def _frag_jit(self, kernel, mode, prepared):
-        return named_jit(kernel, f"titpu_frag_{mode}")
-
     def _kernel(self, key, build):
+        # programs built for the two placements differ (shard_map vs
+        # plain jit) while their keys could coincide; the placement's
+        # prefix keeps them apart
+        full = (self.placement.key,) + tuple(key)
         with self._lock:
-            k = self._kernels.get(key)
+            k = self._kernels.get(full)
         if k is None:
             obs.JIT_CACHE.inc(result="miss")
             k = build()
             with self._lock:
-                self._kernels[key] = k
+                self._kernels[full] = k
             # jax.jit is lazy: trace + XLA compile happen on the FIRST
             # invocation, so that call — not build() — is the compile
             # stage (nested stages subtract, so the kernel stage keeps
             # only execute time). The raw kernel is already cached —
             # only this dispatch pays the wrapper.
-            return _FirstCallCompile(k, str(key[0]))
+            kind = str(key[0])
+            fn = _FirstCallCompile(k, kind)
+            rec = self.recorder
+            if rec is not None:
+                # compile observability: the signature EXCLUDES the shape
+                # bucket and the placement, so bucket/placement churn
+                # that re-enters compile lands on one signature — the
+                # recompile-storm detector's grouping
+                sig = _plan_digest(kind, key[1] if len(key) > 1 else "")
+                fn.on_first = lambda dt: rec.note_compile(
+                    kind, sig, dt, full)
+            return fn
         obs.JIT_CACHE.inc(result="hit")
         return k
 
@@ -1136,14 +1111,16 @@ class CopClient:
         return [] if chunk is None else [chunk]
 
     def _build_agg_kernel(self, dag, prepared, cards, segments):
-        body = self._agg_kernel_body(dag, prepared, cards, segments)
-        return named_jit(body, "titpu_agg")
+        return self.placement.agg_program(
+            self._agg_kernel_body(dag, prepared, cards, segments),
+            prepared["__agg_sched__"], _dag_key(dag, prepared),
+            self.recorder)
 
     def _agg_kernel_body(self, dag, prepared, cards, segments):
         """Pure (cols, row_mask) -> {partials} function. All leaves are
         int32 (exact limb partials, sentinel min/max) or f32 (block float
-        sums); the distributed client wraps it in shard_map and merges with
-        native-int32 psum / pmin / pmax (parallel/dist.py)."""
+        sums), so a sharded placement merges them with native-int32
+        psum / pmin / pmax (placement._collective_merge)."""
         agg = dag.agg
         sel = dag.selection
 
@@ -1190,7 +1167,9 @@ class CopClient:
         return self._host_rows(dag, snap, host_cols, idx)
 
     def _build_rowmask_kernel(self, dag, prepared):
-        return named_jit(self._rowmask_body(dag, prepared), "titpu_rowmask")
+        return self.placement.rows_program(
+            self._rowmask_body(dag, prepared), _survivors(dag, prepared),
+            _dag_key(dag, prepared), self.recorder)
 
     def _rowmask_body(self, dag, prepared):
         sel = dag.selection
@@ -1303,8 +1282,10 @@ class CopClient:
         return Chunk(columns)
 
     def _build_topn_kernel(self, dag, prepared, expr, desc, n):
-        return named_jit(self._topn_body(dag, prepared, expr, desc, n),
-                         "titpu_topn")
+        return self.placement.topn_program(
+            self._topn_body(dag, prepared, expr, desc, n),
+            _survivors(dag, prepared), _dag_key(dag, prepared),
+            self.recorder)
 
     def _topn_body(self, dag, prepared, expr, desc, n):
         sel = dag.selection
@@ -1408,20 +1389,23 @@ class CopClient:
         return Chunk(columns)
 
 
-def named_jit(fn, name: str):
-    """jax.jit(fn) under the program's own name, taken from the key it
-    is cached under (and adding nothing to that key): the host event
-    reads PjitFunction(<name>) and the XLA module jit_<name>, where
-    every program used to be `kernel` or `body`."""
-    fn.__name__ = fn.__qualname__ = name
-    return jax.jit(fn)
+def _survivors(dag: CopDAG, prepared):
+    """(cols, row_mask) -> the mask the DAG's selection leaves: what a
+    sharded program counts for its per-shard survivor stat."""
+    sel = dag.selection
+
+    def survivors(cols, row_mask):
+        return row_mask if sel is None else selection_mask(
+            sel.conditions, widen32(list(cols)), prepared, row_mask)
+
+    return survivors
 
 
 class _FirstCallCompile:
     """Times a fresh jitted kernel's first invocation as the `compile`
     dispatch stage (jax.jit compiles lazily at first call); later calls
-    delegate straight through. `on_first`, when set (the mesh plane's
-    compile observer), receives the first call's wall seconds — the
+    delegate straight through. `on_first`, when set (the flight
+    recorder's compile observer), receives the first call's wall seconds — the
     feed for compile counts/durations and recompile-storm detection."""
 
     __slots__ = ("fn", "note", "done", "on_first")
@@ -1456,7 +1440,7 @@ def _merge_tile_outs(outs: list[dict], sched) -> dict:
     tiles); float block partials concatenate along the block axis (the
     host combine already sums blocks in f64); min/max merge elementwise
     against their sentinels. Mirrors the cross-shard collective merge
-    (parallel/dist.py _collective_merge), but on fetched partials."""
+    (placement._collective_merge), but on fetched partials."""
     if len(outs) == 1:
         return outs[0]
     minmax = {f"m{ai}": s["kind"] for ai, s in enumerate(sched)
@@ -1674,16 +1658,6 @@ def widen32(cols):
     return out
 
 
-def _narrow(a: np.ndarray) -> np.ndarray:
-    """64-bit host columns -> 32-bit device staging (the device is
-    64-bit-free; see module docstring)."""
-    if a.dtype == np.int64:
-        return a.astype(np.int32)
-    if a.dtype == np.float64:
-        return a.astype(np.float32)
-    return a
-
-
 def _pad(a: np.ndarray, b: int) -> np.ndarray:
     if len(a) == b:
         return a
@@ -1721,14 +1695,6 @@ def _lex_runs_ordered(snap, offsets) -> bool:
                 return False
             tie = tie & (a == b)
     return True
-
-
-def _mask_digest(m: np.ndarray) -> str:
-    if m.all():
-        return "all"
-    import hashlib
-
-    return hashlib.md5(np.packbits(m).tobytes()).hexdigest()[:16]
 
 
 def _dag_key(dag: CopDAG, prepared: dict[Any, Any]) -> str:

@@ -48,7 +48,6 @@ from .client import (
     CopResult,
     agg_partials,
     decode_agg_partials,
-    named_jit,
     widen32,
 )
 from .eval import CompileError, DeviceError, eval_expr, selection_mask
@@ -228,8 +227,8 @@ def _device_fragment(cop, frag, snaps) -> CopResult:
     # One partitioned join per fragment; output must be merge-safe
     # partials (agg/hc), since routed rows lose probe-row identity.
     part_ji = None
-    if frag.agg is not None and \
-            getattr(cop, "frag_axis", None) is not None:
+    pl = cop.placement
+    if frag.agg is not None and pl.axis is not None:
         n_probe_cols = len(frag.tables[0].col_offsets)
 
         def probe_prefix_only(e) -> bool:
@@ -241,11 +240,12 @@ def _device_fragment(cop, frag, snaps) -> CopResult:
             return all(probe_prefix_only(a) for a in getattr(e, "args", ()))
 
         # a build too large to replicate — by row count or by bytes
-        # (the mesh client's replicate-threshold-bytes) — shards by key
-        # range; the client decides (cop._partition_build)
+        # (the plane's replicate-threshold-bytes) — shards by key; the
+        # placement decides (Sharded.partition_build)
         big = [(snaps[frag.tables[j.build].table.id].epoch.num_rows, ji)
                for ji, j in enumerate(frag.joins)
-               if cop._partition_build(snaps[frag.tables[j.build].table.id])
+               if pl.partition_build(
+                   cop, snaps[frag.tables[j.build].table.id])
                and probe_prefix_only(j.probe_key)]
         if big:
             part_ji = max(big)[1]
@@ -284,11 +284,6 @@ def _device_fragment(cop, frag, snaps) -> CopResult:
                     prepared["__sig__"].append(
                         ("hcall", FragmentDAG.HAVING_CAP))
 
-    if mode == "hc" and not getattr(cop, "supports_hc", True):
-        # a client with neither single-device hc nor a group exchange
-        # routes hc to the host
-        raise _Fallback("hc-unsupported")
-
     if mode == "hc":
         # run-ordered fast path: storage order already groups the segment
         # keys (fact tables are clustered by their join/PK key), so the
@@ -300,8 +295,7 @@ def _device_fragment(cop, frag, snaps) -> CopResult:
         has_mm = any(s["kind"] in ("min", "max")
                      for s in prepared["__hc_sched__"])
         if segcols is not None and part_ji is None and not has_mm and \
-                getattr(cop, "frag_axis", None) is None and \
-                cop._runs_ordered(psnap, segcols):
+                pl.axis is None and cop._runs_ordered(psnap, segcols):
             prepared["__hc_runordered__"] = True
             prepared["__sig__"].append(("runord",))
             # streamseg (Pallas) eligibility: rank-space per-group sums
@@ -384,16 +378,16 @@ def _device_fragment(cop, frag, snaps) -> CopResult:
             snap = snaps[t.table.id]
             lo, span = spans[ji]
             if ji == part_ji:
-                builds.append(cop._stage_partitioned_build(
-                    t, snap, lo, span, j))
+                builds.append(pl.stage_partitioned_build(
+                    cop, t, snap, lo, span, j))
                 continue
-            cols, vis, host_cols, host_mask = cop._stage_build_table(
-                _facade_dag(t), snap)
+            cols, vis, host_cols, host_mask = pl.stage_build_table(
+                cop, _facade_dag(t), snap)
             key_off = t.col_offsets[j.build_key_local]
             perm = _perm_array(cop, snap, key_off, lo, span, host_mask)
-            perm = cop._place_build_array(
-                perm, key=(snap.epoch.epoch_id, "perm-rep", key_off, lo,
-                           span, _mask_digest_of(host_mask)))
+            perm = pl.place_build_array(
+                cop, perm, key=(snap.epoch.epoch_id, "perm-rep", key_off,
+                                lo, span, _mask_digest_of(host_mask)))
             builds.append({"cols": cols, "vis": vis, "perm": perm})
         # membership bitmaps ride BEHIND the join builds in the same
         # kernel-argument list (replicated on the mesh); their host-side
@@ -426,7 +420,7 @@ def _device_fragment(cop, frag, snaps) -> CopResult:
     if getattr(frag, "semis", None):
         emode = f"{emode}+semi"
     return CopResult(chunks, is_partial_agg=frag.agg is not None,
-                     engine=cop._frag_engine(emode))
+                     engine=pl.engine(emode))
 
 
 def _mask_digest_of(mask):
@@ -558,10 +552,10 @@ def _stage_semi_bitmap(cop, sm, snap, lo: int, span: int) -> dict:
     bm = np.zeros(span, dtype=bool)
     if len(idx):
         bm[kd[idx].astype(np.int64) - lo] = True
-    dev = cop._place_build_array(
-        jnp.asarray(bm), key=(snap.epoch.epoch_id, "semibm-rep", key_off,
-                              lo, span, _mask_digest(snap.base_visible),
-                              hash(fsig)))
+    dev = cop.placement.place_build_array(
+        cop, jnp.asarray(bm),
+        key=(snap.epoch.epoch_id, "semibm-rep", key_off, lo, span,
+             _mask_digest(snap.base_visible), hash(fsig)))
     from .client import _note_transfer
     _note_transfer(dev)
     entry = {"bm": dev, "has_null": has_null,
@@ -599,8 +593,9 @@ def _run_frag_batch(cop, frag, snaps, prepared, spans, builds, overlay,
     # untiled 60M-row fragment kernel plans ~16GB of HBM intermediates
     # and fails to compile. The rank-space hc kernel streams internally
     # (bounded VMEM window) and keeps whole-epoch staging.
+    pl = cop.placement
     if mode in ("agg", "rows", "topn") and not overlay and \
-            getattr(cop, "frag_axis", None) is None and \
+            pl.axis is None and \
             prepared.get("__part_join__") is None and \
             psnap.epoch.num_rows > cop.TILE_ROWS:
         return _run_frag_tiled(cop, frag, snaps, prepared, spans, builds,
@@ -619,8 +614,7 @@ def _run_frag_batch(cop, frag, snaps, prepared, spans, builds, overlay,
     # + MXU work
     jb, sb = builds[:len(frag.joins)], builds[len(frag.joins):]
     kern_builds = builds
-    if jb and not overlay and \
-            getattr(cop, "frag_axis", None) is None and \
+    if jb and not overlay and pl.axis is None and \
             prepared.get("__part_join__") is None:
         with obs.operator("join"), \
                 obs.stage("staging", span_name="copr.staging"):
@@ -640,9 +634,9 @@ def _run_frag_batch(cop, frag, snaps, prepared, spans, builds, overlay,
                else b["cols"][0][0].shape[0]
                for b in kern_builds))
     taken = cop._topn_taken(key, prepared) if mode == "topn" else None
-    kern = cop._kernel(key, lambda: cop._frag_jit(
-        _build_frag_kernel(frag, prepared, spans, mode, raw=True, cop=cop),
-        mode, prepared))
+    kern = cop._kernel(key, lambda: pl.frag_program(
+        _build_frag_kernel(frag, prepared, spans, mode, pl), mode,
+        prepared, cop.recorder))
     prog = f"titpu_frag_{mode}"
     with obs.operator(_mode_op(frag, mode)):
         with obs.stage("kernel", span_name="device.dispatch", prog=prog):
@@ -656,7 +650,7 @@ def _run_frag_batch(cop, frag, snaps, prepared, spans, builds, overlay,
 
     if mode == "hc":
         # candidate blocks = exchange partitions (1 on a single device)
-        prepared["__hc_blocks__"] = getattr(cop, "hc_exchange_blocks", 1)
+        prepared["__hc_blocks__"] = pl.n_devices
         chunk = _decode_hc(frag, snaps, prepared, out)
         return [] if chunk is None else [chunk]
     if mode == "agg":
@@ -709,9 +703,10 @@ def _run_frag_tiled(cop, frag, snaps, prepared, spans, builds, mode):
                        for b in kb))
             if mode == "topn":
                 taken = cop._topn_taken(key, prepared)
-            kern = cop._kernel(key, lambda: cop._frag_jit(
-                _build_frag_kernel(frag, prepared, spans, mode, raw=True,
-                                   cop=cop), mode, prepared))
+            pl = cop.placement
+            kern = cop._kernel(key, lambda: pl.frag_program(
+                _build_frag_kernel(frag, prepared, spans, mode, pl), mode,
+                prepared, cop.recorder))
         from ..util import interrupt
         interrupt.check()
         with obs.operator(kop), \
@@ -1122,7 +1117,9 @@ def _prepare_hc(frag, comb_bounds, prepared, n_rows) -> bool:
     return True
 
 
-def _build_frag_kernel(frag, prepared, spans, mode, raw=False, cop=None):
+def _build_frag_kernel(frag, prepared, spans, mode, pl):
+    """The fragment's (pcols, pvis, builds[, aux]) -> outputs body for
+    placement `pl`, which makes it a program (pl.frag_program)."""
     sel = frag.selection
     agg = frag.agg
     if mode == "agg":
@@ -1130,21 +1127,21 @@ def _build_frag_kernel(frag, prepared, spans, mode, raw=False, cop=None):
         segments = 1
         for c in cards:
             segments *= max(c, 1)
-    # group-partition exchange hook: the distributed client routes joined
-    # rows by group-key hash so each device owns whole groups (the MPP
+    # group-partition exchange: a sharded placement routes joined rows
+    # by group-key hash so each device owns whole groups (the MPP
     # hash-partition exchange mode, planner/core/fragment.go:45)
     hc_exchange = None
-    if mode == "hc" and cop is not None:
-        hc_exchange = cop._hc_exchange_fn(frag, prepared)
+    if mode == "hc":
+        hc_exchange = pl.hc_exchange_fn(frag, prepared)
     # partitioned-join exchange: probe rows route by join-key range to the
     # device holding that slice of the key-ordered build shard
     part_ji = prepared.get("__part_join__")
     join_exchange = None
-    if part_ji is not None and cop is not None:
-        join_exchange = cop._join_exchange_fn(frag, prepared, spans)
-        part_axis = cop.frag_axis
+    if part_ji is not None:
+        join_exchange = pl.join_exchange_fn(frag, prepared, spans)
+        part_axis = pl.axis
         part_span = spans[part_ji][1]
-        part_n_dev = cop.mesh.devices.size
+        part_n_dev = pl.n_devices
         part_per_dev = -(-part_span // part_n_dev)
     semi_spans = prepared.get("__semi_spans__", ())
     semi_flags = prepared.get("__semi_flags__", ())
@@ -1282,7 +1279,7 @@ def _build_frag_kernel(frag, prepared, spans, mode, raw=False, cop=None):
             return res
         return jnp.packbits(mask)
 
-    return kernel if raw else named_jit(kernel, f"titpu_frag_{mode}")
+    return kernel
 
 
 def _maybe_fused_cut(frag, prepared, res):

@@ -1,71 +1,68 @@
-"""Mesh plane: process-wide device mesh + placement-aware coprocessor.
+"""Mesh plane: the placement policy and the mesh telemetry.
 
-The multi-chip DATA plane (ROADMAP item 2). MULTICHIP_r05 showed 8
-devices visible while every fragment executed on one: the sharded
-client (parallel/dist.py) existed but nothing *chose* it, and it
-re-placed cached epochs onto the mesh on every dispatch. This module
-owns both decisions:
+One coprocessor client serves a storage (copr/client.py CopClient);
+where an epoch's rows live is a value (copr/placement.py: SINGLE or
+the plane's Sharded) that the client asks for everything that differs.
+This module is what CHOOSES that value, and what watches the result:
 
-* **MeshPlane** — one per process. Owns the 1-D device mesh
-  (`jax.sharding.Mesh` over the `shard` axis, SNIPPETS.md [1]-[3]
-  idiom), the placement policy, and the per-storage shared clients.
-  Configured from the server's `[mesh]` TOML section or the
-  `TIDB_TPU_MESH*` env knobs for embedded use.
+    session / executor ──> copr/client ──> copr/placement ──> parallel/exchange
+                                 ▲                ▲
+                           copr/mesh (policy, telemetry; hands out the client)
 
-* **Placement policy** — per TABLE EPOCH, decided once per plan node
-  (executor/engine.py opens `placement_scope` around every dispatch):
+* **MeshPlane** — one per process (`get_plane`, `configure`). Owns the
+  1-D device mesh (`jax.sharding.Mesh` over the `shard` axis), the
+  policy below, and `client_for(storage)`: the storage's ONE client,
+  shared by every session, attached to this plane. Configured from the
+  server's `[mesh]` TOML section or the `TIDB_TPU_MESH*` variables.
+
+* **Policy** — per TABLE EPOCH, decided once per plan node
+  (executor/engine.py opens `placement_scope` around every dispatch,
+  which asks `placement_for(snap)`):
   - epochs with >= `shard-threshold-rows` rows shard on the row axis
     (`NamedSharding(mesh, P('shard'))`) — the fact-table side;
-  - smaller epochs run the unchanged single-device path — sharding a
-    4k-row dimension table across 8 chips would pay collective latency
-    for no bandwidth;
+  - smaller epochs are placed single — sharding a 4k-row dimension
+    table across 8 chips would pay collective latency for no bandwidth;
   - join build sides REPLICATE (broadcast exchange) unless bigger than
-    `replicate-threshold-bytes` or the row threshold, in which case
-    they shard by key range and probe rows route over the mesh
-    (hash-partition exchange, parallel/exchange.py). This mirrors the
-    reference's MPP broadcast-vs-hash-partition election
-    (planner/core/fragment.go:45).
+    `replicate-threshold-bytes` or the client's row threshold, in which
+    case they shard by key and probe rows route over the mesh
+    (hash-partition exchange, parallel/exchange.py; the reference's MPP
+    election, planner/core/fragment.go:45).
+  `mesh.enabled = false` or a single visible device means every epoch
+  is placed single and the client carries no recorder: the statement
+  path does no mesh work at all. A backend that fails to initialise is
+  an error, never "single-device".
 
-* **Persistent sharded residency** — staged columns are PLACED at
-  creation (client._place_cols) and the placed arrays are what the
-  epoch caches hold, so a sharded epoch stays device-resident across
-  queries and sessions; `tidb_device_transfer_bytes` stops paying a
-  re-shard per dispatch. DML that folds a new epoch invalidates the
-  old epoch's device buffers eagerly (Storage.add_epoch_listener).
+* **Residency** — staged columns are PLACED at creation and the placed
+  arrays are what the client's epoch caches hold, so a sharded epoch
+  stays device-resident across queries and sessions. DML that folds a
+  new epoch invalidates the old epoch's device buffers eagerly
+  (Storage.add_epoch_listener).
 
-* **Single-device path** — `mesh.enabled = false`, a single visible
-  device, or a below-threshold table all take the EXACT single-device
-  path: `client_for` hands out the storage's shared plain CopClient
-  when the plane is inactive, and MeshCopClient in `single` mode
-  dispatches every hook to the base implementations. A backend that
-  fails to initialise is an error, never "single-device".
+* **MeshFlightRecorder** — per client of an active plane: per-shard
+  dispatch accounting, skew warnings, compile counts and the
+  recompile-storm detector, the HBM provenance ledger and watermark.
 
-Results are bit-identical to the single-device path by construction:
-the sharded kernels produce the same exact limb partials and merge
-with native-int32 collectives (parallel/dist.py docstring).
+Results are bit-identical under both placements by construction: the
+sharded programs produce the same exact limb partials and merge them
+with native-int32 collectives (copr/placement.py).
 """
 
 from __future__ import annotations
 
 import threading
 import time
+import weakref
 from collections import OrderedDict
-from contextlib import contextmanager
 from dataclasses import dataclass
 from typing import Optional
 
 import jax
-import jax.numpy as jnp
 import numpy as np
-from jax.sharding import PartitionSpec as P
 
 from .. import obs
-from ..parallel.dist import AXIS, DistCopClient, _collective_merge, \
-    make_mesh, shard_map
 from ..util import failpoint
-from .client import CopClient, _FirstCallCompile, _dag_key, _obj_nbytes, \
-    named_jit, widen32
-from .eval import selection_mask
+from .client import CopClient
+from .placement import AXIS, SINGLE, Sharded, make_mesh
 
 
 @dataclass
@@ -94,42 +91,7 @@ class MeshConfig:
     shard_ring_cap: int = 256
 
 
-def epoch_nbytes(epoch) -> int:
-    """Host bytes of one columnar epoch (columns + validity lanes)."""
-    n = 0
-    for data, valid in zip(epoch.columns, epoch.valids):
-        n += int(data.nbytes)
-        if valid is not None:
-            n += int(valid.nbytes)
-    return n
-
-
 # ==================== flight recorder ====================
-
-def _plan_digest(kind: str, identity) -> str:
-    """Stable per-logical-kernel digest: the plan identity WITHOUT the
-    shape bucket or placement mode — the same key the recompile-storm
-    detector groups by (bucket/mode churn re-enters compile under ONE
-    signature)."""
-    import hashlib
-    return hashlib.sha256(
-        (str(kind) + "|" + str(identity)).encode()).hexdigest()[:16]
-
-
-def _stat_pair(in_rows, out_rows):
-    """int32[1, 2] per-shard (input rows, post-filter survivors); the
-    P(AXIS) out_spec concatenates shards into [n_devices, 2]."""
-    return jnp.stack([jnp.asarray(in_rows, dtype=jnp.int32),
-                      jnp.asarray(out_rows, dtype=jnp.int32)])[None]
-
-
-def _rows_partial_total(p):
-    """Device-side total of a 1-limb 'rows' agg partial
-    (int32[1, 2, segments], value = hi*4096 + lo per segment): the
-    shard's post-filter survivor count, read off the partials the
-    kernel already computes — no second pass over the data."""
-    return jnp.sum(p[:, 0, :]) * 4096 + jnp.sum(p[:, 1, :])
-
 
 def _bits_shard_counts(arr) -> np.ndarray:
     """Per-shard popcount of a P(AXIS)-sharded packed row bitmask: each
@@ -153,10 +115,10 @@ class MeshFlightRecorder:
     local list — no lock, no fetch, no sync. collect() (called by the
     engine after each dispatching plan node, i.e. after the
     statement's own device_get) fetches the tiny [n_devices, 2] stats
-    arrays, computes skew, and folds everything into the ring. The
-    single-device CopClient never touches any of this (zero-work
-    contract). No background thread — rings are bounded OrderedDicts
-    trimmed at insert."""
+    arrays, computes skew, and folds everything into the ring. Only
+    sharded programs queue anything, and a client of an inactive plane
+    has no recorder at all (zero-work contract). No background thread
+    — rings are bounded OrderedDicts trimmed at insert."""
 
     STORM_COMPILES = 3   # same signature compiled this often = a storm
     COMPILE_CAP = 256    # signatures kept in the compile ring
@@ -171,6 +133,12 @@ class MeshFlightRecorder:
         self._ring: "OrderedDict[str, dict]" = OrderedDict()
         self._compiles: "OrderedDict[str, dict]" = OrderedDict()
         self._tls = threading.local()
+        # HBM ledger of the client's caches: (col version, mask version)
+        # -> telemetry dict, and per-device live-byte high-water marks
+        # (guarded by the CLIENT's lock, like the caches they describe;
+        # see telemetry())
+        self._telemetry_memo: Optional[tuple] = None
+        self._device_peak: dict[str, int] = {}
 
     # ---- dispatch side (hot path) --------------------------------------
     def note_pending(self, kind: str, digest: str, stats,
@@ -391,8 +359,9 @@ class MeshFlightRecorder:
 
 
 class MeshPlane:
-    """Process-wide mesh owner: device mesh, placement policy, shared
-    per-storage clients, and the per-device telemetry the gauges read."""
+    """Process-wide mesh owner: device mesh, placement policy, the
+    storages' shared clients, and the per-device telemetry the gauges
+    read."""
 
     AXIS = AXIS
 
@@ -401,17 +370,8 @@ class MeshPlane:
         self.cfg = cfg or MeshConfig()
         self._devices = devices  # explicit device list (tests)
         self._mesh = None
-        # RLock: client_for constructs clients (which read .mesh) under
-        # the same lock
+        self._sharded: Optional[Sharded] = None
         self._lock = threading.RLock()
-        # storage -> shared MeshCopClient (weak: a collected Storage
-        # must release its device buffers with it)
-        import weakref
-        self._clients: "weakref.WeakKeyDictionary" = \
-            weakref.WeakKeyDictionary()
-        # storage -> shared plain CopClient while the plane is inactive
-        self._single_clients: "weakref.WeakKeyDictionary" = \
-            weakref.WeakKeyDictionary()
         # devices currently above the HBM watermark (edge-triggered
         # mesh_hbm_watermark events)
         self._above_watermark: set[str] = set()
@@ -424,16 +384,16 @@ class MeshPlane:
     @property
     def mesh(self):
         """The 1-D device mesh; building it initializes the backend, so
-        it stays lazy until the first active client asks."""
+        it stays lazy until the first placement decision asks."""
         with self._lock:
             if self._mesh is None:
                 devs = self._devices
                 if devs is None:
-                    import jax
                     devs = jax.devices()
                 if self.cfg.axis_size > 0:
                     devs = list(devs)[: self.cfg.axis_size]
                 self._mesh = make_mesh(devs)
+                self._sharded = Sharded(self._mesh, self.cfg)
             return self._mesh
 
     @property
@@ -452,57 +412,57 @@ class MeshPlane:
         return self.n_devices > 1
 
     # ---- placement policy -------------------------------------------------
-    def placement_for(self, snap) -> str:
-        """'shard' | 'single' for one table snapshot. Per-EPOCH
-        deterministic (row count is fixed per epoch id), so staged-
-        array cache keys never see both placements for one epoch."""
-        if not self.active:
-            return "single"
-        if snap.epoch.num_rows >= self.cfg.shard_threshold_rows:
-            return "shard"
-        return "single"
+    def placement_for(self, snap):
+        """The placement (copr/placement.py) of one table snapshot:
+        this plane's Sharded, or SINGLE. Per-EPOCH deterministic (row
+        count is fixed per epoch id), so staged-array cache keys never
+        see both placements for one epoch."""
+        if self.active and \
+                snap.epoch.num_rows >= self.cfg.shard_threshold_rows:
+            return self._sharded
+        return SINGLE
 
     # ---- shared clients ---------------------------------------------------
-    def client_for(self, storage) -> "MeshCopClient":
-        """The storage's shared mesh client: every session of a storage
-        uses ONE client, so sharded epochs persist across queries AND
-        connections, and a folded epoch can be evicted eagerly."""
+    def client_for(self, storage) -> CopClient:
+        """The storage's shared coprocessor client under this plane:
+        every session of a storage uses ONE client, so staged epochs
+        and compiled kernels are held once per storage rather than once
+        per connection, sharded epochs persist across queries AND
+        connections, and a folded epoch can be evicted eagerly. While
+        the plane is active the client carries a flight recorder whose
+        events (mesh_skew / mesh_compile_storm / mesh_hbm_watermark) go
+        to the storage's event ring."""
         with self._lock:
-            c = self._clients.get(storage)
+            mine = _STORAGE_CLIENTS.setdefault(storage, [])
+            c = next((c for c in mine if c.plane is self), None)
             if c is None:
-                c = MeshCopClient(self)
-                self._clients[storage] = c
-                # a counter that never moved is not rendered: a mesh that
-                # moved nothing must read 0 on /metrics, not be absent
-                obs.MESH_RESHARD_BYTES.inc(0)
-        # the flight recorder's event sink: this storage's event ring
-        # receives mesh_skew / mesh_compile_storm / mesh_hbm_watermark
-        if c.recorder.obs is None:
-            c.recorder.obs = getattr(storage, "obs", None)
-        # module-level storage->client registry: the diag/infoschema
-        # read side (client_of) resolves through it, so recorder rings
-        # stay queryable whichever plane instance built the client
-        # (tests construct private planes; latest client wins)
-        _STORAGE_CLIENTS[storage] = c
-        _attach_storage(c, storage)
-        return c
-
-    def single_client_for(self, storage) -> CopClient:
-        """The storage's shared plain client while the plane is
-        inactive (one visible device, or disabled). A client per
-        session — i.e. per wire connection — would stage its own copy
-        of every epoch it scans and compile its own kernels, so device
-        memory and compile time would grow with the connection count."""
-        with self._lock:
-            c = self._single_clients.get(storage)
-            if c is None:
-                c = self._single_clients[storage] = CopClient()
-        _attach_storage(c, storage)
+                c = CopClient()
+                c.plane = self
+                if self.active:
+                    c.recorder = MeshFlightRecorder(self)
+                    c.recorder.obs = getattr(storage, "obs", None)
+                    # a counter that never moved is not rendered: a mesh
+                    # that moved nothing must read 0 on /metrics, not be
+                    # absent
+                    obs.MESH_RESHARD_BYTES.inc(0)
+            else:
+                mine.remove(c)
+            mine.append(c)  # the latest answers client_of
+        # outside the plane lock: the listener hook takes storage-side
+        # structures only
+        if c.heat is None:
+            # keyspace heat recorder: scans account per-range traffic
+            c.heat = getattr(storage, "heat", None)
+        if hasattr(storage, "add_epoch_listener"):
+            # eager device-buffer eviction on every epoch replacement
+            storage.add_epoch_listener(c.on_epoch_replaced)
         return c
 
     def clients(self) -> list:
+        """This plane's clients that carry a flight recorder."""
         with self._lock:
-            return list(self._clients.values())
+            return [c for cs in _STORAGE_CLIENTS.values() for c in cs
+                    if c.plane is self and c.recorder is not None]
 
     # ---- telemetry --------------------------------------------------------
     def device_bytes(self) -> dict[str, int]:
@@ -510,7 +470,7 @@ class MeshPlane:
         clients (sharded epochs count their shard; replicated builds
         count a full copy per device — that is what pins HBM). The
         per-client walk is memoized per cache generation
-        (MeshCopClient.telemetry), so scrapes between cache changes
+        (telemetry()), so scrapes between cache changes
         cost dict lookups, not an array walk. Crossing the HBM
         watermark is detected here (edge-triggered events)."""
         per: dict[str, int] = {}
@@ -519,7 +479,7 @@ class MeshPlane:
                 per[str(d)] = 0
         for c in self.clients():
             try:
-                for dev, b in c.telemetry()["per_device"].items():
+                for dev, b in telemetry(c)["per_device"].items():
                     per[dev] = per.get(dev, 0) + b
             except Exception:  # noqa: BLE001 — telemetry only
                 continue
@@ -586,22 +546,11 @@ class MeshPlane:
         peak: dict[str, int] = {}
         for c in self.clients():
             try:
-                for dev, b in c.telemetry()["peak"].items():
+                for dev, b in telemetry(c)["peak"].items():
                     peak[dev] = max(peak.get(dev, 0), b)
             except Exception:  # noqa: BLE001 — telemetry only
                 continue
         return peak
-
-
-def _attach_storage(c: CopClient, storage) -> None:
-    """Wire a shared client to its storage, outside the plane lock (the
-    listener hook takes storage-side structures only): the keyspace
-    heat recorder, so scans account per-range traffic, and eager
-    device-buffer eviction on every epoch replacement."""
-    if c.heat is None:
-        c.heat = getattr(storage, "heat", None)
-    if hasattr(storage, "add_epoch_listener"):
-        storage.add_epoch_listener(c.on_epoch_replaced)
 
 
 def _walk_arrays(o):
@@ -668,383 +617,60 @@ def _classify_key(key) -> tuple:
     return None, "other"
 
 
-class MeshCopClient(DistCopClient):
-    """Placement-aware coprocessor client over a MeshPlane.
-
-    Every dispatch runs under a thread-local placement mode set by
-    `placement_scope` (engine.py opens it per plan node from the probe
-    snapshot). In `shard` mode the DistCopClient machinery applies —
-    row-sharded staging, shard_map kernels, collective merges, the
-    broadcast/partition join election. In `single` mode every hook
-    dispatches to the base CopClient implementation, so a small table
-    behaves EXACTLY as on one device (same kernels, same cache keys
-    modulo the mode prefix, same engine tags)."""
-
-    def __init__(self, plane: MeshPlane) -> None:
-        super().__init__(plane.mesh)
-        self.plane = plane
-        self._part_thr_rows = DistCopClient.partition_join_threshold
-        # mesh flight recorder: per-shard dispatch accounting, compile
-        # observability, skew detection (one per client = per storage)
-        self.recorder = MeshFlightRecorder(plane)
-        # (col version, mask version) -> telemetry dict; per-device
-        # live-byte high-water marks (guarded by self._lock)
-        self._telemetry_memo: Optional[tuple] = None
-        self._device_peak: dict[str, int] = {}
-
-    # ---- placement state ---------------------------------------------------
-    def _mode(self) -> str:
-        return getattr(self._tls, "mode", None) or "single"
-
-    def _sharded(self) -> bool:
-        return self._mode() == "shard"
-
-    @contextmanager
-    def _mode_scope(self, mode: str):
-        prev = getattr(self._tls, "mode", None)
-        self._tls.mode = mode
-        try:
-            yield
-        finally:
-            self._tls.mode = prev
-
-    def placement_scope(self, snap):
-        return self._mode_scope(self.plane.placement_for(snap))
-
-    def execute(self, dag, snap):
-        # direct callers (no engine scope): decide placement here
-        if getattr(self._tls, "mode", None) is None:
-            with self.placement_scope(snap):
-                return super().execute(dag, snap)
-        return super().execute(dag, snap)
-
-    # ---- engine tags -------------------------------------------------------
-    def _device_engine(self) -> str:
-        return f"device@mesh{self._n}" if self._sharded() else "device"
-
-    def _frag_engine(self, mode: str) -> str:
-        if self._sharded():
-            return f"device[{mode}]@mesh{self._n}"
-        return f"device[{mode}]"
-
-    # ---- mode-dispatched hooks --------------------------------------------
-    # kernels compiled for the two modes differ (shard_map vs plain jit)
-    # while their cache keys could coincide; the mode prefix keeps them
-    # apart
-    def _kernel(self, key, build):
-        fn = super()._kernel((self._mode(),) + tuple(key), build)
-        if isinstance(fn, _FirstCallCompile) and fn.on_first is None:
-            # compile observability: the signature EXCLUDES the shape
-            # bucket and placement mode, so bucket/mode churn that
-            # re-enters compile lands on one signature — the
-            # recompile-storm detector's grouping
-            rec = self.recorder
-            kind = str(key[0]) if key else "?"
-            sig = _plan_digest(kind, key[1] if len(key) > 1 else "")
-            full = (self._mode(),) + tuple(key)
-            fn.on_first = lambda dt, _r=rec, _k=kind, _s=sig, _f=full: \
-                _r.note_compile(_k, _s, dt, _f)
-        return fn
-
-    def _bucket_size(self, n: int) -> int:
-        if self._sharded():
-            return DistCopClient._bucket_size(self, n)
-        return CopClient._bucket_size(self, n)
-
-    def _place_cols(self, data, valid):
-        if self._sharded():
-            return DistCopClient._place_cols(self, data, valid)
-        return CopClient._place_cols(self, data, valid)
-
-    def _place_mask(self, mask):
-        if self._sharded():
-            return DistCopClient._place_mask(self, mask)
-        return CopClient._place_mask(self, mask)
-
-    def _with_shard_stats(self, fn, kind: str, digest: str):
-        """Split a stats-augmented jitted kernel's (result, stats)
-        pair: the result flows back to the unchanged base machinery;
-        the tiny [n_devices, 2] per-shard stats arrays queue on the
-        recorder's thread-local pending list and are fetched at
-        take_mesh_note() time — AFTER the statement's own device_get,
-        so no extra sync lands inside the dispatch pipeline."""
-        rec = self.recorder
-
-        def kern(*args):
-            out, stats = fn(*args)
-            rec.note_pending(kind, digest, stats,
-                             op=obs.active_operator())
-            return out
-
-        return kern
-
-    def _build_agg_kernel(self, dag, prepared, cards, segments):
-        if not self._sharded():
-            return CopClient._build_agg_kernel(
-                self, dag, prepared, cards, segments)
-        # the DistCopClient shard_map, plus per-shard flight-recorder
-        # stats: input rows from the visibility mask, post-filter
-        # survivors read off the 'rows' partial the kernel already
-        # computes — both BEFORE the collective merge, so they are the
-        # per-shard (not global) numbers
-        body = self._agg_kernel_body(dag, prepared, cards, segments)
-        sched = prepared["__agg_sched__"]
-
-        def sharded(cols, row_mask):
-            out = body(cols, row_mask)
-            stats = _stat_pair(jnp.sum(row_mask.astype(jnp.int32)),
-                               _rows_partial_total(out["rows"]))
-            return _collective_merge(out, sched), stats
-
-        mapped = shard_map(sharded, mesh=self.mesh,
-                           in_specs=(P(AXIS), P(AXIS)),
-                           out_specs=(P(), P(AXIS)))
-        return self._with_shard_stats(
-            named_jit(mapped, "titpu_mesh_agg"), "agg",
-            _plan_digest("agg", _dag_key(dag, prepared)))
-
-    def _build_topn_kernel(self, dag, prepared, expr, desc, n):
-        if not self._sharded():
-            return CopClient._build_topn_kernel(
-                self, dag, prepared, expr, desc, n)
-        raw = self._topn_body(dag, prepared, expr, desc, n)
-        sel = dag.selection
-
-        def body(cols, row_mask):
-            out = raw(cols, row_mask)
-            # survivor count re-derives the selection mask; XLA CSEs it
-            # with the identical graph inside raw
-            m = row_mask if sel is None else selection_mask(
-                sel.conditions, widen32(list(cols)), prepared, row_mask)
-            return out, _stat_pair(jnp.sum(row_mask.astype(jnp.int32)),
-                                   jnp.sum(m.astype(jnp.int32)))
-
-        mapped = shard_map(body, mesh=self.mesh,
-                           in_specs=(P(AXIS), P(AXIS)),
-                           out_specs=(P(None, AXIS), P(AXIS)))
-        return self._with_shard_stats(
-            named_jit(mapped, "titpu_mesh_topn"), "topn",
-            _plan_digest("topn", _dag_key(dag, prepared)))
-
-    def _build_rowmask_kernel(self, dag, prepared):
-        if not self._sharded():
-            return CopClient._build_rowmask_kernel(self, dag, prepared)
-        raw = self._rowmask_body(dag, prepared)
-        sel = dag.selection
-
-        def body(cols, row_mask):
-            packed = raw(cols, row_mask)
-            m = row_mask if sel is None else selection_mask(
-                sel.conditions, widen32(list(cols)), prepared, row_mask)
-            return packed, _stat_pair(
-                jnp.sum(row_mask.astype(jnp.int32)),
-                jnp.sum(m.astype(jnp.int32)))
-
-        mapped = shard_map(body, mesh=self.mesh,
-                           in_specs=(P(AXIS), P(AXIS)),
-                           out_specs=(P(AXIS), P(AXIS)))
-        return self._with_shard_stats(
-            named_jit(mapped, "titpu_mesh_rows"), "rows",
-            _plan_digest("rows", _dag_key(dag, prepared)))
-
-    def _frag_jit(self, kernel, mode, prepared):
-        if not self._sharded():
-            return CopClient._frag_jit(self, kernel, mode, prepared)
-        rec = self.recorder
-        routed = prepared.get("__part_join__") is not None or mode == "hc"
-        kind = "frag-" + mode
-        digest = _plan_digest(kind, tuple(prepared.get("__sig__", ())))
-        build_specs = self._build_in_specs(prepared)
-        if mode == "agg":
-            sched = prepared["__agg_sched__"]
-
-            def merged(pcols, pvis, builds):
-                out = kernel(pcols, pvis, builds)
-                stats = _stat_pair(jnp.sum(pvis.astype(jnp.int32)),
-                                   _rows_partial_total(out["rows"]))
-                return _collective_merge(out, sched), stats
-
-            fn = named_jit(shard_map(
-                merged, mesh=self.mesh,
-                in_specs=(P(AXIS), P(AXIS), build_specs),
-                out_specs=(P(), P(AXIS))), "titpu_mesh_frag_agg")
-        elif mode == "hc":
-            # DistCopClient's hc specs, with the per-shard stats riding
-            # along; post-exchange survivors are not observable outside
-            # the candidate path, so only input balance is recorded
-            # (-1 = unknown survivors)
-            specs = DistCopClient._hc_out_specs(prepared)
-
-            def hc_body(pcols, pvis, builds):
-                res = kernel(pcols, pvis, builds)
-                stats = _stat_pair(jnp.sum(pvis.astype(jnp.int32)),
-                                   jnp.int32(-1))
-                return res, stats
-
-            fn = named_jit(shard_map(
-                hc_body, mesh=self.mesh,
-                in_specs=(P(AXIS), P(AXIS), build_specs),
-                out_specs=(specs, P(AXIS))), "titpu_mesh_frag_hc")
-        elif mode == "topn":
-            # fused join+topn: per-shard top-n candidate rows concatenate
-            # along the k axis; survivors are not observable outside the
-            # candidate cut, so only input balance is recorded
-            def tp_body(pcols, pvis, builds):
-                res = kernel(pcols, pvis, builds)
-                stats = _stat_pair(jnp.sum(pvis.astype(jnp.int32)),
-                                   jnp.int32(-1))
-                return res, stats
-
-            fn = named_jit(shard_map(
-                tp_body, mesh=self.mesh,
-                in_specs=(P(AXIS), P(AXIS), build_specs),
-                out_specs=(P(None, AXIS), P(AXIS))), "titpu_mesh_frag_topn")
-        else:
-            # rows mode: the packed bitmask is already P(AXIS)-sharded;
-            # each device's slice popcounts to its survivors at collect
-            # time, so the kernel needs no extra outputs
-            # rows fragments never route: the partitioned-join election
-            # (fragment.py) is agg/hc-only — routed rows would lose
-            # probe-row identity — so there are no exchange bytes to
-            # account here, only the per-shard survivor popcounts
-            inner = DistCopClient._frag_jit(self, kernel, mode, prepared)
-
-            def row_kern(pcols, pvis, builds, *rest):
-                out = inner(pcols, pvis, builds, *rest)
-                rec.note_pending(kind, digest, {"bits": out},
-                                 op=obs.active_operator())
-                return out
-
-            return row_kern
-
-        def kern(pcols, pvis, builds, *rest):
-            nbytes = 0
-            if routed:
-                # rows cross the mesh inside the kernel (all_to_all);
-                # the collective itself is untimeable host-side, so
-                # account the routed payload bytes at dispatch
-                nbytes = _obj_nbytes(pcols) + _obj_nbytes([pvis])
-                obs.MESH_RESHARD_BYTES.inc(nbytes)
-            out, stats = fn(pcols, pvis, builds, *rest)
-            rec.note_pending(kind, digest, stats, routed=nbytes,
-                             op=obs.active_operator())
-            return out
-
-        return kern
-
-    # ---- flight-recorder surface (engine + session hooks) -----------------
-    def take_mesh_note(self):
-        return self.recorder.collect()
-
-    def drain_mesh_warnings(self) -> tuple:
-        return self.recorder.drain_warnings()
-
-    def discard_mesh_pending(self) -> None:
-        self.recorder.discard_pending()
-
-    def telemetry(self) -> dict:
-        """Per-device live bytes + the HBM provenance ledger in ONE
-        cached-array walk, memoized per cache generation (the
-        _VersionedDict mutation counters): scrapes and /debug/mesh
-        reads between cache changes are dict lookups, not re-walks of
-        every cached array. Also advances the per-device peak marks."""
-        with self._lock:
-            gen = (self._col_cache.version, self._mask_cache.version)
-            memo = self._telemetry_memo
-            if memo is not None and memo[0] == gen:
-                return memo[1]
-            items = list(self._col_cache.items()) + \
-                list(self._mask_cache.items())
-            epoch_tables = {eid: tid
-                            for tid, eid in self._live_epochs.items()}
-        per: dict[str, int] = {}
-        entries: dict[tuple, list] = {}
-        seen: set = set()
-        for key, val in items:
-            eid, kind = _classify_key(key)
-            for arr in _walk_arrays(val):
-                if id(arr) in seen:
-                    continue  # dedupe rep aliases (see _cached_arrays)
-                seen.add(id(arr))
+def telemetry(client: CopClient) -> dict:
+    """Per-device live bytes + the HBM provenance ledger of one recorded
+    client's caches in ONE cached-array walk, memoized per cache
+    generation (the _VersionedDict mutation counters): scrapes and
+    /debug/mesh reads between cache changes are dict lookups, not
+    re-walks of every cached array. Also advances the per-device peak
+    marks."""
+    rec = client.recorder
+    with client._lock:
+        gen = (client._col_cache.version, client._mask_cache.version)
+        memo = rec._telemetry_memo
+        if memo is not None and memo[0] == gen:
+            return memo[1]
+        items = list(client._col_cache.items()) + \
+            list(client._mask_cache.items())
+        epoch_tables = {eid: tid
+                        for tid, eid in client._live_epochs.items()}
+    per: dict[str, int] = {}
+    entries: dict[tuple, list] = {}
+    seen: set = set()
+    for key, val in items:
+        eid, kind = _classify_key(key)
+        for arr in _walk_arrays(val):
+            if id(arr) in seen:
+                continue  # dedupe rep aliases (see _cached_arrays)
+            seen.add(id(arr))
+            try:
+                shards = list(arr.addressable_shards)
+            except Exception:  # noqa: BLE001 — telemetry only
+                continue
+            for sh in shards:
                 try:
-                    shards = list(arr.addressable_shards)
-                except Exception:  # noqa: BLE001 — telemetry only
+                    dev = str(sh.device)
+                    b = int(sh.data.nbytes)
+                except Exception:  # noqa: BLE001
                     continue
-                for sh in shards:
-                    try:
-                        dev = str(sh.device)
-                        b = int(sh.data.nbytes)
-                    except Exception:  # noqa: BLE001
-                        continue
-                    per[dev] = per.get(dev, 0) + b
-                    e = entries.setdefault((dev, eid, kind), [0, 0])
-                    e[0] += 1
-                    e[1] += b
-        rows = [{"device": d, "epoch": eid, "kind": k,
-                 "arrays": a, "bytes": b}
-                for (d, eid, k), (a, b) in sorted(
-                    entries.items(),
-                    key=lambda kv: (kv[0][0], str(kv[0][1]), kv[0][2]))]
-        with self._lock:
-            for dev, b in per.items():
-                if b > self._device_peak.get(dev, 0):
-                    self._device_peak[dev] = b
-            result = {"per_device": per, "entries": rows,
-                      "peak": dict(self._device_peak),
-                      "epoch_tables": epoch_tables}
-            self._telemetry_memo = (gen, result)
-        return result
-
-    def _stage_build_table(self, facade, snap):
-        if self._sharded():
-            return DistCopClient._stage_build_table(self, facade, snap)
-        return CopClient._stage_build_table(self, facade, snap)
-
-    def _place_build_array(self, arr, key=None):
-        if self._sharded():
-            return DistCopClient._place_build_array(self, arr, key)
-        return CopClient._place_build_array(self, arr, key)
-
-    def _hc_exchange_fn(self, frag, prepared):
-        if self._sharded():
-            return DistCopClient._hc_exchange_fn(self, frag, prepared)
-        return None
-
-    def _join_exchange_fn(self, frag, prepared, spans):
-        if self._sharded():
-            return DistCopClient._join_exchange_fn(
-                self, frag, prepared, spans)
-        return None
-
-    def _stage_partitioned_build(self, t, snap, lo, span, j):
-        # partitioned builds are only elected in shard mode
-        return DistCopClient._stage_partitioned_build(
-            self, t, snap, lo, span, j)
-
-    # ---- join build election ----------------------------------------------
-    @property
-    def partition_join_threshold(self):
-        return self._part_thr_rows if self._sharded() else None
-
-    @partition_join_threshold.setter
-    def partition_join_threshold(self, v) -> None:
-        self._part_thr_rows = v
-
-    def _partition_build(self, snap) -> bool:
-        if not self._sharded():
-            return False
-        if CopClient._partition_build(self, snap):
-            return True
-        return epoch_nbytes(snap.epoch) > \
-            self.plane.cfg.replicate_threshold_bytes
-
-    @property
-    def frag_axis(self):
-        return AXIS if self._sharded() else None
-
-    @property
-    def hc_exchange_blocks(self) -> int:
-        return self._n if self._sharded() else 1
+                per[dev] = per.get(dev, 0) + b
+                e = entries.setdefault((dev, eid, kind), [0, 0])
+                e[0] += 1
+                e[1] += b
+    rows = [{"device": d, "epoch": eid, "kind": k,
+             "arrays": a, "bytes": b}
+            for (d, eid, k), (a, b) in sorted(
+                entries.items(),
+                key=lambda kv: (kv[0][0], str(kv[0][1]), kv[0][2]))]
+    with client._lock:
+        for dev, b in per.items():
+            if b > rec._device_peak.get(dev, 0):
+                rec._device_peak[dev] = b
+        result = {"per_device": per, "entries": rows,
+                  "peak": dict(rec._device_peak),
+                  "epoch_tables": epoch_tables}
+        rec._telemetry_memo = (gen, result)
+    return result
 
 
 # ==================== process-wide plane ====================
@@ -1052,12 +678,13 @@ class MeshCopClient(DistCopClient):
 _PLANE: Optional[MeshPlane] = None
 _PLANE_LOCK = threading.Lock()
 
-# storage -> latest shared mesh client, whichever plane built it (weak:
-# dies with the storage); the diag/infoschema read side resolves here
-import weakref as _weakref  # noqa: E402
-
-_STORAGE_CLIENTS: "_weakref.WeakKeyDictionary" = \
-    _weakref.WeakKeyDictionary()
+# storage -> its shared clients, one per plane that was asked for one,
+# latest last (weak: a collected Storage releases its device buffers
+# with it). Tests build private planes over a storage the process plane
+# also serves; the diag/infoschema read side (client_of) answers from
+# the latest
+_STORAGE_CLIENTS: "weakref.WeakKeyDictionary" = \
+    weakref.WeakKeyDictionary()
 
 
 def _env_config() -> MeshConfig:
@@ -1135,16 +762,13 @@ def configure(enabled: Optional[bool] = None,
 
 
 def client_for(storage) -> CopClient:
-    """Default coprocessor client for a session over `storage`, shared
-    by every session of that storage: the mesh client when the plane is
-    active, else one plain single-device CopClient. The first caller
-    initialises the JAX backend; the device line is logged there."""
+    """Default coprocessor client for a session over `storage`: the
+    process plane's, shared by every session of that storage. The first
+    caller initialises the JAX backend; the device line is logged
+    there."""
     from .. import device
     device.describe()
-    plane = get_plane()
-    if not plane.active:
-        return plane.single_client_for(storage)
-    return plane.client_for(storage)
+    return get_plane().client_for(storage)
 
 
 def status() -> dict:
@@ -1158,11 +782,15 @@ def status() -> dict:
     return plane.status()
 
 
-def client_of(storage) -> Optional["MeshCopClient"]:
-    """The storage's EXISTING mesh client, or None — never creates one
-    and never builds a mesh (the diag/infoschema read paths must not
-    grab a backend as a side effect)."""
-    return _STORAGE_CLIENTS.get(storage)
+def client_of(storage) -> Optional[CopClient]:
+    """The storage's latest EXISTING client that carries a flight
+    recorder, or None — never creates one and never builds a mesh (the
+    diag/infoschema read paths must not grab a backend as a side
+    effect)."""
+    for c in reversed(_STORAGE_CLIENTS.get(storage, ())):
+        if c.recorder is not None:
+            return c
+    return None
 
 
 def shard_rows(storage) -> list[list]:
@@ -1180,7 +808,7 @@ def storage_rows(storage) -> list[list]:
     c = client_of(storage)
     if c is None:
         return []
-    t = c.telemetry()
+    t = telemetry(c)
     names: dict = {}
     for eid, tid in t["epoch_tables"].items():
         store = getattr(storage, "tables", {}).get(tid)
@@ -1212,7 +840,7 @@ def debug_payload() -> dict:
         out["compiles"].extend(snap["compiles"])
         if plane.mesh_built:
             try:
-                t = c.telemetry()
+                t = telemetry(c)
                 out["storage"].append({
                     "per_device": t["per_device"], "peak": t["peak"],
                     "entries": t["entries"]})
@@ -1266,8 +894,7 @@ def _mesh_telemetry_probe() -> None:
 obs.register_gauge_probe(_mesh_telemetry_probe)
 
 
-__all__ = ["MeshConfig", "MeshPlane", "MeshCopClient",
-           "MeshFlightRecorder", "epoch_nbytes", "get_plane",
-           "configure", "client_for", "client_of", "status",
-           "placement_report", "shard_rows", "storage_rows",
-           "debug_payload"]
+__all__ = ["MeshConfig", "MeshPlane", "MeshFlightRecorder",
+           "get_plane", "configure", "client_for",
+           "client_of", "status", "telemetry", "placement_report",
+           "shard_rows", "storage_rows", "debug_payload"]

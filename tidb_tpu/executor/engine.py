@@ -191,7 +191,7 @@ def run_physical(plan: PhysicalPlan, ctx: ExecContext) -> Chunk:
             chunk = _run_node(plan, ctx, engine_tag)
         stages = rec.delta_since(before) if rec is not None else None
         # mesh flight recorder: collect this node's per-shard dispatch
-        # accounting (a no-op None on the single-device CopClient) —
+        # accounting (None on a client without a recorder) —
         # feeds the EXPLAIN ANALYZE `mesh` column and the skew detector
         ctx.stats.record(plan, _time.perf_counter() - t0, chunk.num_rows,
                          engine_tag[0], stages=stages,
@@ -214,10 +214,10 @@ def _run_node(plan: PhysicalPlan, ctx: ExecContext,
         if plan.dag.scan.table_id < 0:
             return Chunk([])  # dual pseudo-table: one conceptual row, no cols
         snap = ctx.txn.snapshot(plan.dag.scan.table_id)
-        # placement-aware dispatch: the engine pins the mesh placement
-        # (shard the epoch over the device mesh vs single-device) for
-        # this node from the snapshot it just took, so every staging/
-        # kernel decision below sees one consistent answer
+        # the engine pins the placement (the epoch sharded over the
+        # device mesh, or on one device) for this node from the
+        # snapshot it just took, so every staging/kernel decision
+        # below sees one consistent answer
         with ctx.cop.placement_scope(snap):
             result = ctx.cop.execute(plan.dag, snap)
         obs.note_engine(result.engine)

@@ -10,7 +10,7 @@ device/bucket axes over ICI. Static shapes throughout: capacity is fixed
 at trace time, and skew beyond it sets an overflow flag (psum'd to every
 device) that the host turns into a fallback — never silent truncation.
 
-Used by parallel/dist.py for:
+Used by the sharded placement (copr/placement.py) for:
 * high-cardinality GROUP BY: route rows by group-key hash so every group
   lands wholly on one device, then run the per-device sorted-run
   candidate aggregation (copr/hcagg.py) on disjoint group partitions;

@@ -195,11 +195,10 @@ class Session:
     @property
     def cop(self) -> CopClient:
         """Coprocessor client, resolved on first use: the storage's
-        SHARED client, so staged epochs and compiled kernels are held
-        once per storage rather than once per connection — the mesh
-        client when the process mesh plane is active (>1 device +
-        enabled), else one plain single-device CopClient. Lazy because
-        the plane's active check initializes the JAX backend."""
+        SHARED client under the process mesh plane, so staged epochs
+        and compiled kernels are held once per storage rather than
+        once per connection. Lazy because the plane's active check
+        initializes the JAX backend."""
         if self._cop is None:
             from ..copr import mesh as _mesh
             self._cop = _mesh.client_for(self.storage)
