@@ -69,7 +69,7 @@ from .bounds import (
 )
 from .eval import CompileError, DeviceError, eval_expr, selection_mask
 from .npeval import NumpyEval
-from .placement import SINGLE, _mask_digest, _narrow, _plan_digest
+from .placement import SINGLE, _narrow, _plan_digest
 
 _I32_MAX = np.int32(2**31 - 1)
 _I32_MIN = np.int32(-(2**31) + 1)
@@ -903,7 +903,7 @@ class CopClient:
             cacheable = self._live_epochs.get(dag.scan.table_id) \
                 == epoch.epoch_id
         tiles = []
-        vis_digest = _mask_digest(snap.base_visible)
+        vis_digest = snap.mask_digest
         with self._lock:
             # evict masks of superseded visibility states (same epoch+bucket,
             # different digest) — one live mask set per epoch
@@ -1001,14 +1001,16 @@ class CopClient:
             key = (epoch.epoch_id, off, b) + sfx
             data = epoch.columns[off]
             valid = epoch.valids[off]
-            vfull = np.ones(n, bool) if valid is None else valid
             with self._lock:
                 cached = self._col_cache.get(key)
             if cached is None:
                 obs.COL_CACHE.inc(result="miss")
                 padded = _pad(_narrow_stats(
                     data, self._col_stats(snap, off)), b)
-                pvalid = _pad_bool(vfull, b)
+                # validity stays None on the host (as _host_view): ones
+                # are built for the upload only, never on a cache hit
+                pvalid = _pad_bool(
+                    np.ones(n, bool) if valid is None else valid, b)
                 with obs.stage("transfer"):
                     cached = pl.place_cols(padded, pvalid, build)
                 _note_transfer(cached)
@@ -1018,8 +1020,8 @@ class CopClient:
             else:
                 obs.COL_CACHE.inc(result="hit")
             dev_cols.append(cached)
-            host_cols.append((data, vfull))
-        vis_digest = _mask_digest(snap.base_visible)
+            host_cols.append((data, valid))
+        vis_digest = snap.mask_digest
         vis_key = (epoch.epoch_id, b, vis_digest) + sfx
         with self._lock:
             vis = self._mask_cache.get(vis_key)

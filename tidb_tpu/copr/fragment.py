@@ -387,7 +387,7 @@ def _device_fragment(cop, frag, snaps) -> CopResult:
             perm = _perm_array(cop, snap, key_off, lo, span, host_mask)
             perm = pl.place_build_array(
                 cop, perm, key=(snap.epoch.epoch_id, "perm-rep", key_off,
-                                lo, span, _mask_digest_of(host_mask)))
+                                lo, span, snap.mask_digest))
             builds.append({"cols": cols, "vis": vis, "perm": perm})
         # membership bitmaps ride BEHIND the join builds in the same
         # kernel-argument list (replicated on the mesh); their host-side
@@ -421,11 +421,6 @@ def _device_fragment(cop, frag, snaps) -> CopResult:
         emode = f"{emode}+semi"
     return CopResult(chunks, is_partial_agg=frag.agg is not None,
                      engine=pl.engine(emode))
-
-
-def _mask_digest_of(mask):
-    from .client import _mask_digest
-    return _mask_digest(mask)
 
 
 def lift_group_dag(dag, snap) -> Optional[FragmentDAG]:
@@ -473,11 +468,10 @@ def _perm_array(cop, snap, key_off: int, lo: int, span: int,
     only. Cached DEVICE-resident per (epoch, key column, visibility) —
     rebuilding and re-uploading a multi-MB lookup table per query would
     put a host pass and a host-to-device copy on every dispatch."""
-    from .client import _mask_digest
     # epoch id LEADS the key so _evict_stale (which frees every cache
     # entry with k[0] == superseded epoch) reclaims perm tables too
     key = (snap.epoch.epoch_id, "perm", key_off, lo, span,
-           _mask_digest(host_mask))
+           snap.mask_digest)
     with cop._lock:
         hit = cop._col_cache.get(key)
         cacheable = cop._live_epochs.get(snap.store.table.id) \
@@ -531,12 +525,11 @@ def _stage_semi_bitmap(cop, sm, snap, lo: int, span: int) -> dict:
     gates) and cached per (epoch, visibility, filter set) like perm
     tables; NULL-key facts for the NULL-aware NOT IN form ride along as
     host constants."""
-    from .client import _mask_digest
     t = sm.table
     key_off = t.col_offsets[sm.build_key_local]
     fsig = repr(t.filters)
     ck = (snap.epoch.epoch_id, "semibm", key_off, lo, span,
-          _mask_digest(snap.base_visible), hash(fsig))
+          snap.mask_digest, hash(fsig))
     with cop._lock:
         hit = cop._col_cache.get(ck)
         cacheable = cop._live_epochs.get(t.table.id) \
@@ -555,7 +548,7 @@ def _stage_semi_bitmap(cop, sm, snap, lo: int, span: int) -> dict:
     dev = cop.placement.place_build_array(
         cop, jnp.asarray(bm),
         key=(snap.epoch.epoch_id, "semibm-rep", key_off, lo, span,
-             _mask_digest(snap.base_visible), hash(fsig)))
+             snap.mask_digest, hash(fsig)))
     from .client import _note_transfer
     _note_transfer(dev)
     entry = {"bm": dev, "has_null": has_null,
@@ -870,8 +863,7 @@ def _stage_aligned(cop, frag, snaps, prepared, spans, builds, pcols,
         bep = bsnap.epoch.epoch_id
         ckey = (pep, "aligned", bep, t.table.id, ji, key_e.idx, bucket,
                 lo, span, tuple(t.col_offsets),
-                _mask_digest_of(psnap.base_visible),
-                _mask_digest_of(bsnap.base_visible), tag)
+                psnap.mask_digest, bsnap.mask_digest, tag)
         with cop._lock:
             hit = cop._col_cache.get(ckey)
             cacheable = (

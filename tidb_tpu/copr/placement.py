@@ -83,12 +83,6 @@ def _narrow(a: np.ndarray) -> np.ndarray:
     return a
 
 
-def _mask_digest(m: np.ndarray) -> str:
-    if m.all():
-        return "all"
-    return hashlib.md5(np.packbits(m).tobytes()).hexdigest()[:16]
-
-
 def epoch_nbytes(epoch) -> int:
     """Host bytes of one columnar epoch (columns + validity lanes)."""
     n = 0
@@ -333,7 +327,7 @@ class Sharded:
                 self.replicated(cop, (eid, "repc", off, b), d, cacheable),
                 self.replicated(cop, (eid, "repv", off, b), v, cacheable)))
         vis = self.replicated(
-            cop, (eid, "repvis", b, _mask_digest(host_mask)), vis,
+            cop, (eid, "repvis", b, snap.mask_digest), vis,
             cacheable)
         cop._tls.build_cacheable = cacheable
         return rep_cols, vis, host_cols, host_mask
@@ -393,9 +387,8 @@ class Sharded:
         per_dev = span_pad // n_dev
         epoch = snap.epoch
         key_off = t.col_offsets[j.build_key_local]
-        host_mask = snap.base_visible
         ck = (epoch.epoch_id, "partb", key_off, lo, span_pad,
-              _mask_digest(host_mask), tuple(t.col_offsets))
+              snap.mask_digest, tuple(t.col_offsets))
         with cop._lock:
             hit = cop._col_cache.get(ck)
             cacheable = cop._live_epochs.get(t.table.id) == epoch.epoch_id
@@ -403,7 +396,7 @@ class Sharded:
             return hit
         keys = epoch.columns[key_off]
         kvalid = epoch.valids[key_off]
-        sel = host_mask.copy()
+        sel = snap.base_visible.copy()
         if kvalid is not None:
             sel &= kvalid
         idx = np.nonzero(sel)[0]

@@ -1018,6 +1018,13 @@ TOPN_SELECT = PROCESS_METRICS.counter(
     "winners: block (copr/topnsel.py: block maxima, the k best blocks, a "
     "top-k of their rows) or full (top-k over the whole tile, where the "
     "tile is too short for blocks to pay)")
+SNAPSHOT_MASK = PROCESS_METRICS.counter(
+    "tidb_store_snapshot_mask_total",
+    "TableStore.snapshot calls, by the base-row visibility mask they hand "
+    "out: shared (the snapshot hides no base row and holds its epoch's one "
+    "read-only all-true array: nothing row-sized is allocated or scanned) or "
+    "private (an update or delete of a base row is visible to it: a "
+    "writable copy with those rows cleared)")
 FRAG_FALLBACKS = PROCESS_METRICS.counter(
     "tidb_copr_fragment_fallbacks_total",
     "device-fragment gate rejections, by reason")

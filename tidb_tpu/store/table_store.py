@@ -20,6 +20,8 @@ key (reference: pk-is-handle, table/tables.go).
 
 from __future__ import annotations
 
+import functools
+import hashlib
 import itertools
 import threading
 from dataclasses import dataclass, field
@@ -27,6 +29,7 @@ from typing import Any, Optional
 
 import numpy as np
 
+from .. import obs
 from ..catalog.schema import TableInfo
 from ..chunk.column import Column, Dictionary, EnumDictionary, _encode_scalar
 from ..kv.memdb import TOMBSTONE
@@ -110,6 +113,12 @@ class ColumnEpoch:
     def __post_init__(self) -> None:
         if not isinstance(self.handle_pos, HandleIndex):
             self.handle_pos = HandleIndex(self.handles)
+        # "every base row is visible", as ONE value an epoch: a snapshot
+        # that hides no base row holds this array itself, so nothing
+        # row-sized is allocated or scanned a statement. Immutable like
+        # the epoch: a consumer that wrote in place would raise
+        self.all_visible = np.ones(len(self.handles), dtype=bool)
+        self.all_visible.setflags(write=False)
 
     @property
     def num_rows(self) -> int:
@@ -123,7 +132,9 @@ class TableSnapshot:
     table: TableInfo
     dictionaries: list[Optional[Dictionary]]
     epoch: ColumnEpoch
-    # False where a base row is overridden/deleted at this snapshot's ts
+    # False where a base row is overridden/deleted at this snapshot's ts;
+    # the epoch's shared READ-ONLY all_visible where none is (copy before
+    # writing)
     base_visible: np.ndarray  # bool[epoch.num_rows]
     overlay_handles: np.ndarray  # int64[m] rows added/updated after fold_ts
     overlay_columns: list[np.ndarray]
@@ -133,8 +144,25 @@ class TableSnapshot:
     _overlay_pos: Optional[dict] = field(default=None, repr=False)
 
     @property
+    def all_base_visible(self) -> bool:
+        """This snapshot hides no base row: its mask IS the epoch's."""
+        return self.base_visible is self.epoch.all_visible
+
+    @property
     def num_visible_rows(self) -> int:
-        return int(self.base_visible.sum()) + len(self.overlay_handles)
+        base = self.epoch.num_rows if self.all_base_visible \
+            else int(self.base_visible.sum())
+        return base + len(self.overlay_handles)
+
+    @functools.cached_property
+    def mask_digest(self) -> str:
+        """Cache-key digest of base_visible (device masks, perm tables):
+        "all" without a scan for the shared array, the md5 of the packed
+        flags of a private one (which always has a row cleared), once."""
+        if self.all_base_visible:
+            return "all"
+        return hashlib.md5(
+            np.packbits(self.base_visible).tobytes()).hexdigest()[:16]
 
     def overlay_pos(self) -> dict:
         if self._overlay_pos is None:
@@ -265,6 +293,10 @@ class TableStore:
         # HBM on EVERY device) free the superseded epoch's buffers now,
         # not on the next dispatch (Storage.add_epoch_listener attaches)
         self.evict_hooks: list = []
+        # a counter that never moved is not rendered: /metrics reads 0
+        # under both labels from the first store on
+        for mask in ("shared", "private"):
+            obs.SNAPSHOT_MASK.inc(0, mask=mask)
 
     def _epoch_changed(self, required: bool = True) -> None:
         if self.on_epoch is not None:
@@ -342,16 +374,23 @@ class TableStore:
             if txn_overlay:
                 visible.update(txn_overlay)
 
-        base_visible = np.ones(epoch.num_rows, dtype=bool)
+        hidden: list[int] = []  # base positions overridden / deleted
         ov_handles: list[int] = []
         ov_rows: list[tuple] = []
         for handle, row in visible.items():
             pos = epoch.handle_pos.get(handle)
             if pos is not None:
-                base_visible[pos] = False
+                hidden.append(pos)
             if row is not TOMBSTONE:
                 ov_handles.append(handle)
                 ov_rows.append(row)
+        if hidden:
+            base_visible = np.ones(epoch.num_rows, dtype=bool)
+            base_visible[hidden] = False
+            obs.SNAPSHOT_MASK.inc(mask="private")
+        else:
+            base_visible = epoch.all_visible
+            obs.SNAPSHOT_MASK.inc(mask="shared")
 
         ncols = self.table.num_columns
         ov_columns: list[np.ndarray] = []
