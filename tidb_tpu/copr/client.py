@@ -220,12 +220,16 @@ class CopClient:
         # scan accounts its table's record span on the heatmap. None on
         # bare clients; one gated attribute test per execute() when off
         self.heat = None
-        # program key -> [the topnsel path its TopN body was traced with]
-        self._topn_paths: dict[Any, list] = {}
+        # program key -> [the topnsel path its TopN or hc body was traced
+        # with]
+        self._select_paths: dict[Any, list] = {}
         # a counter that never moved is not rendered: a client that has
-        # served no TopN yet must read 0 on /metrics, not be absent
+        # served no TopN (no hc fragment) yet must read 0 on /metrics,
+        # not be absent
         for sel_path in topnsel.PATHS:
             obs.TOPN_SELECT.inc(0, path=sel_path)
+        for sel_path in topnsel.HC_PATHS:
+            obs.HC_SELECT.inc(0, path=sel_path)
         _LIVE_CLIENTS.add(self)
 
     def _evict_stale(self, table_id: int, epoch_id: int) -> None:
@@ -1224,7 +1228,7 @@ class CopClient:
         bucket = tiles[0][1].shape[0]
         key = ("topn", _dag_key(dag, prepared), bucket, n,
                tuple(d for _, d in dag.topn.items))
-        taken = self._topn_taken(key, prepared)
+        taken = self._select_taken(key, prepared)
         kern = self._kernel(key, lambda: self._build_topn_kernel(
             dag, prepared, expr, desc, n))
         with obs.stage("kernel", span_name="device.dispatch",
@@ -1241,16 +1245,18 @@ class CopClient:
                 chunks.append(c)
         return chunks
 
-    def _topn_taken(self, key, prepared) -> list:
+    def _select_taken(self, key, prepared) -> list:
         """The one-slot record, kept beside the program cached under
         `key`, of the path its body selects the winners by: a body built
-        from `prepared` hands it to topnsel.select, which fills it in
-        when the program is traced (at its first dispatch) with what it
-        does for the shape it ranks. tidb_copr_topn_select_total counts
-        a read under it after the dispatch."""
+        from `prepared` hands it to topnsel.select (a TopN) or
+        topnsel.candidates (an hc fragment), which fills it in when the
+        program is traced (at its first dispatch) with what it does for
+        the shape it ranks. tidb_copr_topn_select_total, or
+        tidb_copr_hc_select_total, counts a read under it after the
+        dispatch."""
         with self._lock:
-            taken = self._topn_paths.setdefault(key, [])
-        prepared["__topn_taken__"] = taken
+            taken = self._select_paths.setdefault(key, [])
+        prepared["__select_taken__"] = taken
         return taken
 
     def _topn_decode(self, dag, snap, out) -> Optional[Chunk]:
@@ -1303,7 +1309,7 @@ class CopClient:
         out_types = dag.output_types
 
         pack = prepared.get("__topn_pack__")
-        taken = prepared.get("__topn_taken__")
+        taken = prepared.get("__select_taken__")
 
         def kernel(cols, row_mask):
             cols = widen32(cols)

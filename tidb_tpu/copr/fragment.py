@@ -626,7 +626,8 @@ def _run_frag_batch(cop, frag, snaps, prepared, spans, builds, overlay,
                else ("bm", b["bm"].shape[0]) if "bm" in b
                else b["cols"][0][0].shape[0]
                for b in kern_builds))
-    taken = cop._topn_taken(key, prepared) if mode == "topn" else None
+    taken = cop._select_taken(key, prepared) \
+        if mode in ("topn", "hc") else None
     kern = cop._kernel(key, lambda: pl.frag_program(
         _build_frag_kernel(frag, prepared, spans, mode, pl), mode,
         prepared, cop.recorder))
@@ -636,7 +637,8 @@ def _run_frag_batch(cop, frag, snaps, prepared, spans, builds, overlay,
             dev = kern(pcols, pvis, kern_builds) if aux is None \
                 else kern(pcols, pvis, kern_builds, aux)
         if taken is not None:
-            obs.TOPN_SELECT.inc(path=taken[0])
+            (obs.HC_SELECT if mode == "hc" else obs.TOPN_SELECT).inc(
+                path=taken[0])
         with obs.stage("device_get", span_name="device.fetch",
                        clocked=True, prog=prog):
             out = jax.device_get(dev)
@@ -695,7 +697,7 @@ def _run_frag_tiled(cop, frag, snaps, prepared, spans, builds, mode):
                        else b["cols"][0][0].shape[0]
                        for b in kb))
             if mode == "topn":
-                taken = cop._topn_taken(key, prepared)
+                taken = cop._select_taken(key, prepared)
             pl = cop.placement
             kern = cop._kernel(key, lambda: pl.frag_program(
                 _build_frag_kernel(frag, prepared, spans, mode, pl), mode,
@@ -1251,7 +1253,7 @@ def _build_frag_kernel(frag, prepared, spans, mode, pl):
                                       prepared, eval_expr)
             score = jnp.where(mask, comp, jnp.iinfo(jnp.int32).min)
             idx = topnsel.select(score, min(frag.topn.n, score.shape[0]),
-                                 prepared.get("__topn_taken__"))
+                                 prepared.get("__select_taken__"))
             int_rows = [idx.astype(jnp.int32),
                         mask[idx].astype(jnp.int32)]
             flt_rows = []
@@ -1456,8 +1458,8 @@ def _hc_rank_body(frag, prepared, cols, mask, aux):
                 ok = sv <= thr_f + eps
             pass_m = pass_m & ok
         score = jnp.where(pass_m, 1.0, -jnp.inf)
-        k_cap = min(FragmentDAG.HAVING_CAP, score.shape[0])
-        _, cand = jax.lax.approx_max_k(score, k_cap, recall_target=1.0)
+        cand = topnsel.candidates(score, FragmentDAG.HAVING_CAP,
+                                  prepared.get("__select_taken__"))
         rows_of = r0[cand]
         res = {"picked": pass_m[cand].astype(jnp.int32),
                "score": score[cand]}
@@ -1486,8 +1488,8 @@ def _hc_rank_body(frag, prepared, cols, mask, aux):
                        jnp.float32(-1e38 if hc.desc else np.inf), signed)
     score = jnp.where(gate, signed, -jnp.inf)
 
-    k_cap = min(hc.cap, score.shape[0])
-    _, cand = jax.lax.approx_max_k(score, k_cap, recall_target=1.0)
+    cand = topnsel.candidates(score, hc.cap,
+                              prepared.get("__select_taken__"))
     rows_of = r0[cand]
     res = {"picked": gate[cand].astype(jnp.int32), "score": score[cand]}
     for gi in range(len(agg.group_by)):
@@ -1519,9 +1521,10 @@ def _hc_body(frag, prepared, cols, mask, aux=None):
     Sorts by the SEGMENT keys only (the functional-dependency analysis in
     _prepare_hc proved the other group keys constant within a segment) —
     XLA's variadic sort compile time is the binding constraint. Candidate
-    selection uses approx_max_k over a score recombined from the exact
-    pair sums (elementwise, no global scan). Run-ordered epochs with rank
-    metadata dispatch to the streamseg rank-space body instead."""
+    selection is topnsel.candidates (exact by score) over a score
+    recombined from the exact pair sums (elementwise, no global scan).
+    Run-ordered epochs with rank metadata dispatch to the streamseg
+    rank-space body instead."""
     if aux is not None and prepared.get("__rank_meta__") is not None:
         return _hc_rank_body(frag, prepared, cols, mask, aux)
     from . import hcagg as HC
@@ -1682,7 +1685,7 @@ def _hc_body(frag, prepared, cols, mask, aux=None):
                 ok = sv_h <= thr_f + eps
             pass_m = pass_m & ok
         score = jnp.where(pass_m, 1.0, -jnp.inf)
-        k_cap = min(FragmentDAG.HAVING_CAP, n)
+        k_cap = FragmentDAG.HAVING_CAP
     else:
         kind, idx = hc.score
         if kind == "group":
@@ -1715,12 +1718,13 @@ def _hc_body(frag, prepared, cols, mask, aux=None):
                            jnp.float32(-1e38 if hc.desc else np.inf),
                            signed)
         score = jnp.where(gate, signed, -jnp.inf)
-        k_cap = min(hc.cap, n)
+        k_cap = hc.cap
 
-    # recall_target=1.0 keeps TPU-native compile times (~10s vs ~20s for
-    # lax.top_k at millions of rows) while selecting EXACTLY by score —
-    # required for the candidate-superset guarantee the decode relies on
-    _, cand = jax.lax.approx_max_k(score, k_cap, recall_target=1.0)
+    # EXACTLY by score: the candidate-superset guarantee the decode
+    # relies on. How (blocks, or the whole array where they do not pay)
+    # follows from (n, k_cap) alone: topnsel.candidates
+    cand = topnsel.candidates(score, k_cap,
+                              prepared.get("__select_taken__"))
     res = {"picked": (gate if hc is not None else
                       pass_m)[cand].astype(jnp.int32),
            "score": score[cand]}

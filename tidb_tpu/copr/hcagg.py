@@ -20,8 +20,9 @@ TPU formulation is sort-based and fully static-shape:
    start-1, returned as an (hi, lo+inblock) int32 pair the host combines
    exactly into int64;
 4. an f32 score (the primary ORDER BY item, recombined from the exact
-   pair sums) feeds jax.lax.approx_max_k with recall_target=1.0 (exact
-   selection, ~10s compile vs ~20s for lax.top_k) and a 4x candidate
+   pair sums) feeds topnsel.candidates (exact selection by score: block
+   maxima and two small top-ks, or approx_max_k with recall_target=1.0
+   where the buffer is too large for blocks to pay) and a 4x candidate
    buffer; the host re-ranks candidates exactly, and the decode verifies
    the score boundary (k-th strictly beats the buffer's worst — f32
    rounding is monotone, so a strict f32 gap proves no non-candidate can

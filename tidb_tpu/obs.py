@@ -1018,6 +1018,14 @@ TOPN_SELECT = PROCESS_METRICS.counter(
     "winners: block (copr/topnsel.py: block maxima, the k best blocks, a "
     "top-k of their rows) or full (top-k over the whole tile, where the "
     "tile is too short for blocks to pay)")
+HC_SELECT = PROCESS_METRICS.counter(
+    "tidb_copr_hc_select_total",
+    "coprocessor reads of a high-cardinality GROUP BY fragment, by how "
+    "their program picks its candidate buffer out of the per-group "
+    "scores: block (copr/topnsel.py: block maxima, the best blocks, a "
+    "top-k of their rows) or approx (approx_max_k at recall 1.0 over them "
+    "all, where the buffer is too large or the groups too few for blocks "
+    "to pay); both exact by score")
 SNAPSHOT_MASK = PROCESS_METRICS.counter(
     "tidb_store_snapshot_mask_total",
     "TableStore.snapshot calls, by the base-row visibility mask they hand "

@@ -101,8 +101,9 @@ class HCTopN:
     ORDER BY <score> LIMIT k, so the device may return only a candidate
     superset of the top-k groups (sorted-run kernel, copr/hcagg.py)
     instead of the full group set. score: ("group", j) ranks by group key
-    j; ("agg", ai) ranks by aggregate ai's (approximate) value. The host
-    layers above re-sort exactly.
+    j; ("agg", ai) ranks by aggregate ai's (approximate) value. The
+    superset is the `cap` best f32 scores, picked exactly
+    (copr/topnsel.py `candidates`); the host layers above re-sort exactly.
 
     `items`, when set, is the COMPLETE resolved ORDER BY list
     [(kind, idx, desc), ...] with kind in ("group", "agg") — every item
@@ -120,7 +121,9 @@ class HCTopN:
 
     @property
     def cap(self) -> int:
-        # candidate buffer absorbing f32 score ties near the k-th value
+        # candidate buffer absorbing f32 score ties near the k-th value;
+        # small against any group space worth the hc path, which is what
+        # lets topnsel.candidates pick it by blocks and not by a sort
         return max(4 * self.k, self.k + 64)
 
 
