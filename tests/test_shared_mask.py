@@ -559,7 +559,7 @@ def test_benchmark_metric_reads_the_rendered_counter():
         entry = [m for m in json.load(f)["per_layer"]
                  if m["name"] == spec["name"]]
     assert len(entry) == 1 and entry[0]["workloads"] == [
-        "tpch10_light", "tpch10_heavy", "mesh_agg"]
+        "tpch10_light", "tpch10_heavy", "mesh_agg", "tpch10_joins"]
     assert entry[0]["layer"] == spec["layer"] == "coprocessor host side"
 
     def scrape():

@@ -359,3 +359,104 @@ def limbs_for(b: Bound, limb_bits: int = 12, max_limbs: int = 6) -> int:
     need = max(int(abs(b[0])), int(abs(b[1])), 1).bit_length() + 1
     n = -(-need // limb_bits)
     return max(1, min(n, max_limbs))
+
+
+# ==================== predicate-implied key domains ====================
+# A dense GROUP BY reserves a slot for every value a key COULD take: a
+# dictionary's size, an epoch's min..max. Rows that fail the statement's
+# predicates never reach a slot, so where the predicates pin a key to a few
+# values (n_name IN two nations under an OR; a year under a date range) the
+# slots of every other value are dead weight — Q7's 26 x 26 x 9 = 6084
+# against the 3 x 3 x 3 it can ever fill. The analysis below reads what
+# the conjuncts imply; the client shrinks the key space to it.
+
+_RANGE_OPS = {"ge": (0, None), "gt": (1, None), "le": (None, 0),
+              "lt": (None, -1)}
+_FLIPPED = {"ge": "le", "gt": "lt", "le": "ge", "lt": "gt", "eq": "eq"}
+
+
+def _col_const(e: Call):
+    """(Col, Const, op) of a binary comparison between the two, with the
+    column on the left; None for any other shape."""
+    if len(e.args) != 2:
+        return None
+    a, b = e.args
+    if isinstance(a, Col) and isinstance(b, Const):
+        return a, b, e.op
+    if isinstance(a, Const) and isinstance(b, Col) and e.op in _FLIPPED:
+        return b, a, _FLIPPED[e.op]
+    return None
+
+
+def _code_sets(e: PlanExpr, base: int, dicts) -> dict:
+    """{combined column: frozenset of dictionary codes} that `e` being
+    TRUE implies for plain string columns (codes of values the dictionary
+    lacks are dropped: no row holds them)."""
+    if not isinstance(e, Call):
+        return {}
+    if e.op == "and":
+        out: dict = {}
+        for a in e.args:
+            for c, s in _code_sets(a, base, dicts).items():
+                out[c] = out[c] & s if c in out else s
+        return out
+    if e.op == "or":
+        parts = [_code_sets(a, base, dicts) for a in e.args]
+        return {c: frozenset().union(*(p[c] for p in parts))
+                for c in parts[0] if all(c in p for p in parts)}
+    values = None
+    if e.op == "eq":
+        cc = _col_const(e)
+        if cc is not None and cc[1].value is not None:
+            col, values = cc[0], [cc[1].value]
+    elif e.op == "in_values" and e.args and isinstance(e.args[0], Col):
+        col, values = e.args[0], [v for v in e.extra if v is not None]
+    if values is None or not col.ftype.is_string:
+        return {}
+    d = dicts[base + col.idx]
+    if d is None:
+        return {}
+    codes = (d.lookup(str(v)) for v in values)
+    return {base + col.idx: frozenset(c for c in codes if c >= 0)}
+
+
+def implied_domains(cond_groups, dicts, col_bounds: list[Bound]):
+    """What a statement's predicates imply for its columns' values.
+
+    cond_groups: [(conjuncts, base)] — each list is an implicit AND whose
+    Col indices are relative to `base` in the combined column space (a
+    fragment table's own filters sit at its base; the post-join selection
+    at 0). Returns (code_sets, bounds): {column: sorted tuple of the only
+    dictionary codes a passing row can hold} for plain string columns
+    pinned by =, IN or an OR of those, and a copy of `col_bounds`
+    tightened by the top-level comparisons of an integer-like column with
+    a constant of its own type. Both hold for rows that PASS; they may
+    size a key space, never an arithmetic width (masked rows still flow
+    through the arithmetic)."""
+    sets: dict = {}
+    bounds = list(col_bounds)
+    for conds, base in cond_groups:
+        for c in conds:
+            for col, s in _code_sets(c, base, dicts).items():
+                sets[col] = sets[col] & s if col in sets else s
+            cc = _col_const(c) if isinstance(c, Call) and (
+                c.op in _RANGE_OPS or c.op == "eq") else None
+            if cc is None:
+                continue
+            col, const, op = cc
+            ft, kt = col.ftype, const.ftype
+            if ft.is_string or ft.is_float or kt.kind != ft.kind or \
+                    (ft.is_decimal and kt.scale != ft.scale) or \
+                    not isinstance(const.value, (int, np.integer)) or \
+                    isinstance(const.value, bool):
+                continue
+            b = bounds[base + col.idx]
+            if b is None:
+                continue
+            v = int(const.value)
+            lo_d, hi_d = _RANGE_OPS.get(op, (0, 0))
+            lo = max(b[0], v + lo_d) if lo_d is not None else b[0]
+            hi = min(b[1], v + hi_d) if hi_d is not None else b[1]
+            # an empty range keeps one point: the space must hold a slot
+            bounds[base + col.idx] = (lo, max(lo, hi))
+    return {c: tuple(sorted(s)) for c, s in sets.items()}, bounds

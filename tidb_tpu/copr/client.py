@@ -65,6 +65,7 @@ from .bounds import (
     expr_bounds,
     expr_device_safe,
     fits_int32,
+    implied_domains,
     limbs_for,
 )
 from .eval import CompileError, DeviceError, eval_expr, selection_mask
@@ -85,6 +86,12 @@ MAX_LOOP_SEGMENTS = 64
 DENSE_SPARSE_MIN_SEGMENTS = 1024
 DENSE_MIN_ROWS_PER_SEGMENT = 128
 MAX_DENSE_SEGMENTS = 1 << 13
+# a string group key the predicates pin to at most this many values takes
+# a slot a value (one compare a value a row) instead of a slot a code
+MAX_KEY_REMAP = 16
+# a LIKE over a dictionary column that this many codes at most match (or
+# fail) compares codes instead of gathering from the code table
+LIKE_COMPARE_MAX = 32
 
 _FLOAT_BLOCKS = 32  # per-segment f32 block partials (host sums in f64)
 
@@ -205,6 +212,10 @@ class CopClient:
         # (epoch_id, offset, bucket) -> (device data, device valid);
         # mutation-versioned so telemetry walks memoize per generation
         self._col_cache: _VersionedDict = _VersionedDict()
+        # hc fragment programs (by fragment and signature) whose passing
+        # rows once overflowed the packed buffer: they run whole
+        # (copr/fragment.py HC_COMPACT_DIV)
+        self._hc_dense: set = set()
         # (epoch_id, bucket, digest) -> device visibility mask
         self._mask_cache: _VersionedDict = _VersionedDict()
         # compiled kernel cache
@@ -247,8 +258,8 @@ class CopClient:
             if old is None:
                 return
             def stale(k) -> bool:  # plain or "tile"-prefixed cache keys
-                if len(k) > 2 and k[1] == "aligned" and k[2] == old:
-                    return True  # build-side epoch of an aligned join
+                if len(k) > 2 and k[1] == "aligned" and old in k[2]:
+                    return True  # a build epoch on an aligned join's path
                 return k[0] == old or (k[0] == "tile" and k[1] == old)
 
             for k in [k for k in self._col_cache if stale(k)]:
@@ -557,7 +568,9 @@ class CopClient:
             err = self._prepare_agg(
                 dag, dicts, col_bounds, prepared,
                 snap.epoch.num_rows + len(snap.overlay_handles),
-                sparse_gate=sparse_gate)
+                sparse_gate=sparse_gate,
+                conds=[(dag.selection.conditions, 0)]
+                if dag.selection else ())
             if err is not None:
                 return None, err
         if dag.topn is not None:
@@ -567,9 +580,13 @@ class CopClient:
         return prepared, None
 
     def _prepare_agg(self, dag, dicts, col_bounds, prepared,
-                     n_rows: int, sparse_gate: bool = True
-                     ) -> Optional[str]:
-        cards, offsets = self._dense_cards(dag, dicts, col_bounds)
+                     n_rows: int, sparse_gate: bool = True,
+                     conds=()) -> Optional[str]:
+        """`conds`: the statement's predicates as [(conjuncts, base)]
+        (bounds.implied_domains) — what they pin a group key to sizes
+        its share of the dense segment space."""
+        cards, offsets, remaps = self._dense_cards(
+            dag, dicts, col_bounds, conds)
         if cards is None:
             return "group keys not dense-encodable on device"
         for g in dag.agg.group_by:
@@ -577,6 +594,7 @@ class CopClient:
                 return "group key too wide for int32 device"
         prepared["__dense_cards__"] = cards
         prepared["__key_offsets__"] = offsets
+        prepared["__key_remaps__"] = remaps
         segments = 1
         for c in cards:
             segments *= max(c, 1)
@@ -656,7 +674,7 @@ class CopClient:
         prepared["__strategy__"] = strategy
         prepared["__agg_sched__"] = sched
         prepared["__sig__"].append((
-            strategy, tuple(cards), tuple(offsets),
+            strategy, tuple(cards), tuple(offsets), tuple(remaps),
             # term EXPRESSIONS are part of the identity: the same query over
             # a different epoch can decompose differently (which factor was
             # wide) while shifts/limbs coincide — a stale kernel would wrap
@@ -776,6 +794,18 @@ class CopClient:
                     (rx.fullmatch(v) is not None for v in d.values),
                     dtype=bool, count=len(d),
                 )
+                # a per-row look-up in the code table is a gather (135 M
+                # elements a second on a v5e: 0.45 s over 60 M rows, PR
+                # 35); where few codes match, or few do not, the row
+                # compares its code with those instead
+                hits, misses = np.nonzero(table)[0], np.nonzero(~table)[0]
+                if len(table) and min(len(hits), len(misses)) \
+                        <= LIKE_COMPARE_MAX:
+                    few = hits if len(hits) <= len(misses) else misses
+                    prepared[id(e)] = (few is hits,
+                                       tuple(int(c) for c in few))
+                    prepared["__sig__"].append(("like",) + prepared[id(e)])
+                    return
                 prepared[id(e)] = jnp.asarray(table) if len(table) else \
                     jnp.zeros(1, dtype=bool)
                 prepared["__sig__"].append(("like", len(d)))
@@ -811,45 +841,58 @@ class CopClient:
 
     def _dense_cards(
         self, dag: CopDAG, dicts: list[Optional[Dictionary]],
-        col_bounds: list[Bound],
-    ) -> tuple[Optional[list[int]], Optional[list[int]]]:
-        """Per-group-key (cardinality+1 for NULL, value offset). String keys
-        use dictionary codes; integer/date/decimal keys use epoch min/max
-        stats — card = hi-lo+2, key = value-lo (reference analog: the
-        two-stage hash agg key space, executor/aggregate.go:146, made dense
-        so the reduction is a fixed-shape XLA program)."""
+        col_bounds: list[Bound], conds=(),
+    ) -> tuple[Optional[list[int]], Optional[list[int]], Optional[list]]:
+        """Per-group-key (cardinality+1 for NULL, value offset, remap).
+        String keys use dictionary codes; integer/date/decimal keys use
+        epoch min/max stats — card = hi-lo+2, key = value-lo (reference
+        analog: the two-stage hash agg key space, executor/aggregate.go:146,
+        made dense so the reduction is a fixed-shape XLA program). Where
+        the predicates `conds` pin a key (bounds.implied_domains), the
+        space holds only what a passing row can carry: a string key's
+        `remap` lists the few codes left (slot i = remap[i], in place of
+        a slot a dictionary code), an integer key's range tightens."""
         assert dag.agg is not None
+        code_sets, key_bounds = implied_domains(conds, dicts, col_bounds) \
+            if conds else ({}, col_bounds)
         cards: list[int] = []
         offsets: list[int] = []
+        remaps: list = []
         for g in dag.agg.group_by:
+            remap = None
             if isinstance(g, Col) and g.ftype.is_string:
                 d = dicts[g.idx]
                 assert d is not None
-                cards.append(len(d) + 1)
+                pinned = code_sets.get(g.idx)
+                if pinned is not None and len(pinned) <= MAX_KEY_REMAP \
+                        and len(pinned) < len(d):
+                    remap = pinned
+                cards.append((len(d) if remap is None else len(remap)) + 1)
                 offsets.append(0)
             elif g.ftype.is_string:
-                return None, None
+                return None, None, None
             elif isinstance(g, Col) and g.ftype.kind == TypeKind.BOOLEAN:
                 cards.append(3)
                 offsets.append(0)
             elif g.ftype.is_float:
-                return None, None
+                return None, None, None
             else:
-                b = expr_bounds(g, col_bounds)
+                b = expr_bounds(g, key_bounds)
                 if b is None:
-                    return None, None
+                    return None, None, None
                 lo, hi = b
                 card = hi - lo + 2
                 if card > MAX_DENSE_SEGMENTS:
-                    return None, None
+                    return None, None, None
                 cards.append(card)
                 offsets.append(lo)
+            remaps.append(remap)
         prod = 1
         for c in cards:
             prod *= max(c, 1)
         if prod > MAX_DENSE_SEGMENTS:
-            return None, None
-        return cards, offsets
+            return None, None, None
+        return cards, offsets, remaps
 
     # ==================== batch execution ====================
     def _run_batch(
@@ -1478,11 +1521,20 @@ def _merge_tile_outs(outs: list[dict], sched) -> dict:
 def segment_ids(agg, cards, offsets, cols, prepared, mask):
     """Mixed-radix dense segment id; NULL key -> card-1 slot."""
     seg = jnp.zeros(mask.shape[0], dtype=jnp.int32)
-    for g, card, off in zip(agg.group_by, cards, offsets):
+    remaps = prepared.get("__key_remaps__") or [None] * len(cards)
+    for g, card, off, remap in zip(agg.group_by, cards, offsets, remaps):
         v, vl = eval_expr(g, cols, prepared)
         if v.dtype == jnp.bool_:
             v = v.astype(jnp.int32)  # boolean keys: 0/1 codes
-        shifted = (v - jnp.asarray(off, dtype=v.dtype)).astype(jnp.int32)
+        if remap is not None:
+            # a key the predicates pin to a few codes: slot i holds
+            # remap[i]; any other code belongs to a masked row
+            shifted = sum(((v == code).astype(jnp.int32) * i
+                           for i, code in enumerate(remap)),
+                          jnp.zeros(v.shape, jnp.int32))
+        else:
+            shifted = (v - jnp.asarray(off, dtype=v.dtype)).astype(
+                jnp.int32)
         k = jnp.where(vl, shifted, card - 1)
         k = jnp.clip(k, 0, card - 1)
         seg = seg * card + k
@@ -1568,6 +1620,7 @@ def decode_agg_partials(agg, prepared, cards, out, group_dicts,
     no group matched. val_types: per-agg output types in (val, cnt) pair
     order as laid out by the planner's partial schema."""
     offsets = prepared["__key_offsets__"]
+    remaps = prepared.get("__key_remaps__") or [None] * len(cards)
     sched = prepared["__agg_sched__"]
     rows_per_seg = SE.combine_partials(out["rows"])
     present = rows_per_seg > 0
@@ -1587,7 +1640,10 @@ def decode_agg_partials(agg, prepared, cards, out, group_dicts,
         code = parts[gi]
         ft = g.ftype
         is_null = code == (card - 1)
-        data = (code + offsets[gi]).astype(ft.np_dtype)
+        if remaps[gi] is not None:  # slot -> the dictionary code it holds
+            data = np.asarray(remaps[gi] + (0,))[code].astype(ft.np_dtype)
+        else:
+            data = (code + offsets[gi]).astype(ft.np_dtype)
         columns.append(Column(
             ft, data, None if not is_null.any() else ~is_null,
             group_dicts[gi]))

@@ -143,6 +143,13 @@ def _eval_call(e: Call, columns: list[VV], prepared: dict[int, Any]) -> VV:
         # prepared: bool code-table over the dictionary
         av, avl = ev(e.args[0])
         table = prepared[id(e)]
+        if isinstance(table, tuple):
+            # (codes match?, the few codes): compares, no gather
+            matching, codes = table
+            hit = jnp.zeros(av.shape, bool)
+            for c in codes:
+                hit = hit | (av == c)
+            return (hit if matching else ~hit) & avl, avl
         safe = jnp.clip(av, 0, table.shape[0] - 1)
         return table[safe] & avl, avl
     if op == "dict_lookup":

@@ -20,13 +20,33 @@ executes the whole tree in one kernel:
   ALL outputs return in one jax.device_get — a whole multi-join
   aggregation query costs one dispatch and one host sync.
 
-Runtime gates (key span too wide, int64 columns that don't fit int32,
-overlay rows on build tables, >8192 dense segments) fall back to an
-equivalent host (numpy) interpreter of the same FragmentDAG — same
-results, same partial layout, no replanning. Only those typed gates
-(_Fallback, CompileError) and a counted HBM exhaustion (`device-oom`)
-do: any other error from the device compiler or runtime fails the
-statement (DeviceError).
+Runtime gates, each a typed `_Fallback(reason)` that hands the SAME
+FragmentDAG to an equivalent host (numpy) interpreter — same results, same
+partial layout, no replanning — counted under the reason in
+`tidb_copr_fragment_fallbacks_total` and tagged `host(fragment:<reason>)`:
+
+* `build-overlay`: uncommitted or unfolded rows on a build (or membership)
+  table — the perm tables index folded epochs only;
+* `int64-column`: a scanned int64 column whose epoch bounds leave int32;
+* `filter-unsafe` / `selection-unsafe`: a predicate whose arithmetic can
+  leave int32 under the columns' bounds;
+* `key-width`: a join or membership key that is unbounded or leaves int32;
+* `key-span`: a build key span above FRAG_SPAN_CAP (the perm table);
+* `group-space`: a GROUP BY that neither the dense segment space (at most
+  8192 slots, after the predicates have pinned what they can:
+  bounds.implied_domains) nor the sorted-run candidate path can hold;
+* `exchange-overflow`, `group-overflow`, `hc-boundary`, `fat-boundary`:
+  decode-time proofs that failed (a mesh exchange bucket, the all-groups
+  candidate buffer, a tie across the candidate cut);
+* `compile`: a CompileError from lowering an expression the device does
+  not take (a string ordering compare, LIKE over a computed string).
+
+One device error degrades the same way, counted as `device-oom`: a
+program the gates admitted did not fit HBM (device_refusal). Any other
+error from the device compiler or runtime fails the statement
+(DeviceError). A read the device answers is counted by the mode that
+served it in `tidb_copr_fragment_reads_total{mode}` and by the rows it
+brought back in `tidb_copr_fragment_fetched_rows_total`.
 """
 
 from __future__ import annotations
@@ -55,6 +75,13 @@ from .npeval import NumpyEval
 
 # widest admissible build-key span: perm table of 64M int32 = 256MB HBM
 FRAG_SPAN_CAP = 1 << 26
+# the sorted-run hc body (a GROUP BY whose keys storage order does not
+# group) sorts and gathers every row it is given: over an epoch of this
+# many rows or more it first packs the rows that pass the predicates into
+# a buffer a HC_COMPACT_DIV-th as long (_compact_rows), and runs whole
+# only where they do not fit (Q10 at SF10: 1.9% of 60 M rows pass)
+HC_COMPACT_MIN_ROWS = 1 << 22
+HC_COMPACT_DIV = 32
 
 
 class _Fallback(Exception):
@@ -76,10 +103,17 @@ def execute_fragment(cop: CopClient, frag: FragmentDAG, snaps: dict
     with cop.placement_scope(snaps[frag.tables[0].table.id]):
         try:
             with obs.span("copr.fragment") as sp:
-                if sp:
-                    sp.note = f"{len(frag.tables)} tables"
                 r = _device_fragment(cop, frag, snaps)
+                mode = r.engine.split("[", 1)[1].split("]", 1)[0]
+                if sp:
+                    build_rows = sum(
+                        snaps[t.table.id].epoch.num_rows
+                        for t in frag.tables[1:])
+                    sp.note = (f"{len(frag.tables)} tables, mode {mode}, "
+                               f"{build_rows} build rows")
             obs.COPR_REQUESTS.inc(engine="device-fragment")
+            obs.FRAG_READS.inc(mode=mode)
+            obs.FRAG_FETCHED_ROWS.inc(sum(c.num_rows for c in r.chunks))
             return r
         except jax.errors.JaxRuntimeError as e:
             reason = device_refusal(e)
@@ -255,8 +289,14 @@ def _device_fragment(cop, frag, snaps) -> CopResult:
     if frag.agg is not None:
         n_rows = psnap.epoch.num_rows + len(psnap.overlay_handles)
         facade = _agg_facade(frag)
+        # every predicate a joined row has to pass, for the key space:
+        # each table's own filters at its base, the selection at 0
+        conds, base = [(frag.selection, 0)], 0
+        for t in frag.tables:
+            conds.append((t.filters, base))
+            base += len(t.col_offsets)
         err = cop._prepare_agg(facade, comb_dicts, comb_bounds, prepared,
-                               n_rows)
+                               n_rows, conds=conds)
         if err is not None:
             # dense segment space rejected (or deliberately skipped:
             # the sparse-occupancy gate routes wide, mostly-empty
@@ -271,8 +311,8 @@ def _device_fragment(cop, frag, snaps) -> CopResult:
                     not _prepare_hc(frag, comb_bounds, prepared, n_rows):
                 if not err.startswith("sparse segment space") or \
                         cop._prepare_agg(facade, comb_dicts, comb_bounds,
-                                         prepared, n_rows,
-                                         sparse_gate=False) is not None:
+                                         prepared, n_rows, sparse_gate=False,
+                                         conds=conds) is not None:
                     raise _Fallback("group-space")
                 # the sparse-occupancy preference could not take the
                 # sorted-run path here (overlay rows / an hc gate):
@@ -364,6 +404,18 @@ def _device_fragment(cop, frag, snaps) -> CopResult:
                 prepared["__sig__"].append(("hcrank", idx, len(d)))
             prepared["__sig__"].append(
                 ("fat", frag.hc.k, tuple(frag.hc.items)))
+
+    if mode == "hc" and not prepared.get("__hc_runordered__") and \
+            pl.axis is None and part_ji is None and \
+            n_rows >= HC_COMPACT_MIN_ROWS:
+        # the sorted-run body over a big epoch packs the passing rows
+        # first; a statement whose rows once overflowed the buffer is
+        # remembered in cop._hc_dense and runs whole from then on
+        dense_key = (_frag_key(frag), _sig(prepared))
+        if dense_key not in cop._hc_dense:
+            prepared["__hc_dense_key__"] = dense_key
+            prepared["__hc_compact__"] = HC_COMPACT_DIV
+            prepared["__sig__"].append(("hccompact", HC_COMPACT_DIV))
 
     # ---- staging ----
     from .. import obs
@@ -643,6 +695,16 @@ def _run_frag_batch(cop, frag, snaps, prepared, spans, builds, overlay,
                        clocked=True, prog=prog):
             out = jax.device_get(dev)
 
+    if mode == "hc" and np.any(np.asarray(
+            out.pop("compact_overflow", 0)) > 0):
+        # more rows passed than the packed buffer holds: the statement
+        # runs whole, now and from now on
+        prepared["__sig__"].remove(
+            ("hccompact", prepared.pop("__hc_compact__")))
+        with cop._lock:
+            cop._hc_dense.add(prepared["__hc_dense_key__"])
+        return _run_frag_batch(cop, frag, snaps, prepared, spans, builds,
+                               overlay, mode)
     if mode == "hc":
         # candidate blocks = exchange partitions (1 on a single device)
         prepared["__hc_blocks__"] = pl.n_devices
@@ -818,6 +880,41 @@ def _stage_rank_aux(cop, snap, prepared):
     return hit
 
 
+def _expr_cols(exprs, base: int = 0) -> set:
+    """Combined-space columns the expressions read (`base`: where the
+    expressions' own column space starts in the combined one)."""
+    read: set = set()
+
+    def walk(e) -> None:
+        if isinstance(e, Col):
+            read.add(base + e.idx)
+        for a in getattr(e, "args", ()):
+            walk(a)
+
+    for e in exprs:
+        walk(e)
+    return read
+
+
+def _agg_read_cols(frag) -> Optional[set]:
+    """Combined-space columns an aggregating fragment's program reads:
+    join and membership keys, the tables' own filters, the selection,
+    group keys and aggregate arguments. None for the row modes, whose
+    output is every column."""
+    if frag.agg is None:
+        return None
+    used = _expr_cols(
+        [j.probe_key for j in frag.joins]
+        + [sm.probe_key for sm in frag.semis]
+        + frag.selection + list(frag.agg.group_by)
+        + [d.arg for d in frag.agg.aggs if d.arg is not None])
+    base = 0
+    for t in frag.tables:  # a table's filters are in its local space
+        used |= _expr_cols(t.filters, base)
+        base += len(t.col_offsets)
+    return used
+
+
 def _stage_aligned(cop, frag, snaps, prepared, spans, builds, pcols,
                    tag=None):
     """Materialize build columns ALIGNED to the padded probe rows as
@@ -836,6 +933,18 @@ def _stage_aligned(cop, frag, snaps, prepared, spans, builds, pcols,
     executor/index_lookup_join.go re-probes per batch, which this design
     deliberately avoids).
 
+    A probe-length column is as large as a fact column, so the cache is
+    kept PER COLUMN and per join PATH (the chain of (key column, build
+    epoch) hops from the probe), not per statement: two statements that
+    reach ORDERS through l_orderkey share o_orderdate's aligned copy,
+    and an aggregating program aligns only the columns it reads
+    (_agg_read_cols) — a build's key column, needed by the join alone,
+    is never gathered; its slot in the kernel's column list holds the
+    'found' bitmap as a placeholder nothing reads. A NULL-free build
+    column's validity IS the path's 'found' bitmap (one shared array).
+    At SF10 that is what lets seven join programs' columns sit beside
+    LINEITEM in one chip's HBM.
+
     Returns a per-join list: {'acols': ((data, valid), ...), 'found': m}
     for joins it could align (probe key is a plain Col over the probe
     prefix or an earlier aligned column), else the original builds entry
@@ -844,48 +953,76 @@ def _stage_aligned(cop, frag, snaps, prepared, spans, builds, pcols,
     psnap = snaps[probe.table.id]
     pep = psnap.epoch.epoch_id
     bucket = pcols[0][0].shape[0] if pcols else 0
-    # combined-index -> (data, valid) device pair, or None if that slot
-    # belongs to a join the kernel will gather itself
-    combined: list = list(pcols)
+    used = _agg_read_cols(frag)
+    # combined-index -> ((data, valid) device pair, build epochs hopped
+    # through to reach it, identity of how it was reached), or None where
+    # the slot was not materialized (a join the kernel gathers itself, or
+    # a column nothing reads)
+    combined: list = [(c, (), ("probe", off))
+                      for c, off in zip(pcols, probe.col_offsets)]
     out = []
-    for ji, (j, (lo, span), b) in enumerate(
-            zip(frag.joins, spans, builds)):
+    for j, (lo, span), b in zip(frag.joins, spans, builds):
         t = frag.tables[j.build]
+        width = len(t.col_offsets)
+        base = len(combined)
         key_e = j.probe_key
         src = None
         if "cols" in b and isinstance(key_e, Col) and \
-                key_e.idx < len(combined) and \
-                combined[key_e.idx] is not None:
+                key_e.idx < len(combined):
             src = combined[key_e.idx]
         if src is None:
             out.append(b)
-            combined.extend([None] * len(t.col_offsets))
+            combined.extend([None] * width)
             continue
+        (kd, kv), src_hops, src_id = src
         bsnap = snaps[t.table.id]
         bep = bsnap.epoch.epoch_id
-        ckey = (pep, "aligned", bep, t.table.id, ji, key_e.idx, bucket,
-                lo, span, tuple(t.col_offsets),
-                psnap.mask_digest, bsnap.mask_digest, tag)
+        # every build epoch on the path sits at k[2] so that _evict_stale
+        # frees the chain when ANY hop's epoch is replaced
+        hops = src_hops + (bep,)
+        path = (src_id, t.table.id, t.col_offsets[j.build_key_local], lo,
+                span, bsnap.mask_digest)
+
+        def ckey(what):
+            return (pep, "aligned", hops, path, bucket, psnap.mask_digest,
+                    tag, what)
+
+        want = [ci for ci in range(width)
+                if used is None or base + ci in used]
         with cop._lock:
-            hit = cop._col_cache.get(ckey)
+            found = cop._col_cache.get(ckey("found"))
+            got = {ci: cop._col_cache.get(ckey(t.col_offsets[ci]))
+                   for ci in want}
             cacheable = (
                 cop._live_epochs.get(probe.table.id) == pep
                 and cop._live_epochs.get(t.table.id) == bep)
-        if hit is None:
-            kd, kv = src
+        missing = [ci for ci in want if got[ci] is None]
+        if found is None or missing:
             k = kd.astype(jnp.int32) - jnp.int32(lo)
             inrange = (k >= 0) & (k < span)
             ridx = b["perm"][jnp.clip(k, 0, span - 1)]
             gidx = jnp.clip(ridx, 0)
-            found = inrange & (ridx >= 0) & kv & b["vis"][gidx]
-            acols = tuple((d[gidx], v[gidx] & found)
-                          for (d, v) in b["cols"])
-            hit = {"acols": acols, "found": found}
+            fresh = {}
+            if found is None:
+                found = inrange & (ridx >= 0) & kv & b["vis"][gidx]
+                fresh[ckey("found")] = found
+            for ci in missing:
+                d, v = b["cols"][ci]
+                off = t.col_offsets[ci]
+                got[ci] = (d[gidx], found
+                           if bsnap.epoch.valids[off] is None
+                           else v[gidx] & found)
+                fresh[ckey(off)] = got[ci]
             if cacheable:
                 with cop._lock:
-                    cop._col_cache[ckey] = hit
-        out.append(hit)
-        combined.extend(hit["acols"])
+                    for ck, arr in fresh.items():
+                        cop._col_cache[ck] = arr
+        out.append({"acols": tuple(got.get(ci, (found, found))
+                                   for ci in range(width)),
+                    "found": found})
+        combined.extend(
+            (got[ci], hops, (path, t.col_offsets[ci])) if ci in got
+            else None for ci in range(width))
     return out
 
 
@@ -1139,6 +1276,7 @@ def _build_frag_kernel(frag, prepared, spans, mode, pl):
         part_per_dev = -(-part_span // part_n_dev)
     semi_spans = prepared.get("__semi_spans__", ())
     semi_flags = prepared.get("__semi_flags__", ())
+    hc_compact = prepared.get("__hc_compact__")
 
     def kernel(pcols, pvis, builds, aux=None):
         cols = widen32(list(pcols))
@@ -1240,7 +1378,7 @@ def _build_frag_kernel(frag, prepared, spans, mode, pl):
                 res["overflow"] = overflow if overflow_j is None \
                     else overflow + overflow_j
                 return res
-            res = _hc_body(frag, prepared, cols, mask, aux)
+            res = _hc_body(frag, prepared, cols, mask, aux, hc_compact)
             if overflow_j is not None:
                 res["overflow"] = overflow_j
             return res
@@ -1515,7 +1653,30 @@ def _emit_pairs(res, sched, term_ix, cnt_ix, tot, cand):
                 [pairs(tot[ix][cand]) for ix in limb_ids])
 
 
-def _hc_body(frag, prepared, cols, mask, aux=None):
+def _compact_rows(cols, mask, cap: int, read: set):
+    """Pack the rows `mask` passes to the front of a `cap`-row buffer:
+    (the columns `read` gathered at the passing rows, the buffer's row
+    mask, whether more passed than fit, the epoch row of every slot).
+    One single-operand sort of the row numbers (failing rows sent to the
+    end) finds the passing rows in storage order: over 67 M rows that
+    sort is 184 ms on a v5e where a binary search a slot over the mask's
+    prefix counts is 1.26 s, a two-level search 0.36 s and a scatter
+    0.31 s (my chip run, PR 35); every gather is then `cap` long, not
+    the epoch's length."""
+    n = mask.shape[0]
+    rows = jnp.arange(n, dtype=jnp.int32)
+    src = jnp.minimum(
+        jax.lax.sort(jnp.where(mask, rows, jnp.int32(n)))[:cap], n - 1)
+    total = jnp.sum(mask.astype(jnp.int32))
+    slot = jnp.arange(cap, dtype=jnp.int32)
+    keep = slot < total
+    # a column the body does not read keeps its slot with a stand-in
+    packed = [(c[0][src], c[1][src]) if i in read else (keep, keep)
+              for i, c in enumerate(cols)]
+    return packed, keep, total > cap, src
+
+
+def _hc_body(frag, prepared, cols, mask, aux=None, compact=None):
     """Sorted-run candidate aggregation (copr/hcagg.py machinery).
 
     Sorts by the SEGMENT keys only (the functional-dependency analysis in
@@ -1531,6 +1692,22 @@ def _hc_body(frag, prepared, cols, mask, aux=None):
     from . import sumexact as _SE
 
     agg = frag.agg
+    # group keys read only at the candidate rows, from the epoch's own
+    # columns (where the body is packed)
+    late: set = set()
+    if compact:
+        # the sort and the sums read the segment keys, the score's key and
+        # the aggregates' arguments at every packed row; a group key that
+        # the segment keys determine is read at the candidates alone
+        eager = set(prepared["__hc_segkeys__"])
+        if frag.hc is not None and frag.hc.score[0] == "group":
+            eager.add(frag.hc.score[1])
+        late = set(range(len(agg.group_by))) - eager
+        epoch_cols = cols
+        cols, mask, spilled, src = _compact_rows(
+            cols, mask, mask.shape[0] // compact, _expr_cols(
+                [agg.group_by[gi] for gi in eager]
+                + [d.arg for d in agg.aggs if d.arg is not None]))
     hc = frag.hc
     nulls = prepared["__hc_nulls__"]
     sched = prepared["__hc_sched__"]
@@ -1538,13 +1715,14 @@ def _hc_body(frag, prepared, cols, mask, aux=None):
     runord = bool(prepared.get("__hc_runordered__"))
     n = mask.shape[0]
 
-    encs = []
-    for gi, g in enumerate(agg.group_by):
-        v, vl = eval_expr(g, cols, prepared)
+    def encode(gi, at):
+        v, vl = eval_expr(agg.group_by[gi], at, prepared)
         if v.dtype == jnp.bool_:
             v = v.astype(jnp.int32)
-        encs.append(jnp.where(vl, v.astype(jnp.int32),
-                              jnp.int32(nulls[gi])))
+        return jnp.where(vl, v.astype(jnp.int32), jnp.int32(nulls[gi]))
+
+    encs = [None if gi in late else encode(gi, cols)
+            for gi in range(len(agg.group_by))]
 
     # min/max rides the sort: one extra ascending operand (complement
     # for max) after the segment keys, so each segment's first row holds
@@ -1610,7 +1788,7 @@ def _hc_body(frag, prepared, cols, mask, aux=None):
         v_sorted = P(values_unsorted_i32)
         outs = []
         for li in _SE.limbs_of(v_sorted, n_limbs):
-            hi, lo = HC.seg_sum_pairs(li, iota, end_idx)
+            hi, lo = HC.seg_sum_pairs(li, end_idx)
             outs.append(jnp.stack([hi, lo]))
         return jnp.stack(outs)
 
@@ -1728,15 +1906,25 @@ def _hc_body(frag, prepared, cols, mask, aux=None):
     res = {"picked": (gate if hc is not None else
                       pass_m)[cand].astype(jnp.int32),
            "score": score[cand]}
+    if late:
+        # the candidates' epoch rows, and the epoch's columns there
+        at = src[P(iota)[cand]]
+        read = _expr_cols(agg.group_by[gi] for gi in late)
+        at_cols = [(c[0][at], c[1][at]) if i in read else (at, at)
+                   for i, c in enumerate(epoch_cols)]
     for gi in range(len(agg.group_by)):
-        res[f"gk{gi}"] = P(encs[gi])[cand]
+        res[f"gk{gi}"] = encode(gi, at_cols) if gi in late \
+            else P(encs[gi])[cand]
     for ai, s in enumerate(sched):
         res[f"cnt{ai}"] = out[f"hc_cnt{ai}"][:, :, cand]
         for ti in range(len(s.get("terms", ()))):
             res[f"s{ai}_{ti}"] = out[f"hc_s{ai}_{ti}"][:, :, cand]
     if mm_ai is not None:
         res[f"mm{mm_ai}"] = sk[-1][cand]
-    return _maybe_fused_cut(frag, prepared, res)
+    res = _maybe_fused_cut(frag, prepared, res)
+    if compact:
+        res["compact_overflow"] = spilled.astype(jnp.int32)
+    return res
 
 
 def _decode_hc(frag, snaps, prepared, out) -> Optional[Chunk]:

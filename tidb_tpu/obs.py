@@ -1035,7 +1035,23 @@ SNAPSHOT_MASK = PROCESS_METRICS.counter(
     "writable copy with those rows cleared)")
 FRAG_FALLBACKS = PROCESS_METRICS.counter(
     "tidb_copr_fragment_fallbacks_total",
-    "device-fragment gate rejections, by reason")
+    "fragment reads the host interpreter answered, by the reason the "
+    "device path gave them up (copr/fragment.py lists the gates: "
+    "build-overlay, int64-column, filter-unsafe, selection-unsafe, "
+    "key-width, key-span, group-space, exchange-overflow, group-overflow, "
+    "hc-boundary, fat-boundary, compile, and device-oom for a program "
+    "that did not fit HBM)")
+FRAG_READS = PROCESS_METRICS.counter(
+    "tidb_copr_fragment_reads_total",
+    "fragment reads the device answered, by the mode that served them: "
+    "agg (dense segments), group (every group, sorted runs), hc (top-k or "
+    "HAVING candidates), fat (the final k groups), topn (top-n joined "
+    "rows), rows (every joined row goes back to the host); +semi with a "
+    "membership gate")
+FRAG_FETCHED_ROWS = PROCESS_METRICS.counter(
+    "tidb_copr_fragment_fetched_rows_total",
+    "rows the device's fragment reads brought back to the host: groups, "
+    "candidates or joined rows")
 DISPATCH_STAGE_SECONDS = PROCESS_METRICS.histogram(
     "tidb_dispatch_stage_duration_seconds",
     "exclusive wall time of one obs.stage, labeled by stage: every layer "

@@ -492,11 +492,14 @@ def _match_agg_fragment(plan: PhysHashAgg, allow_single: bool = False
     degenerate fragment (useful only with an hc TopN hint)."""
     # a projection between agg and joins (e.g. Q9's amount column)
     # composes into the agg expressions instead of blocking the match
+    # (a derived table stacks them: Q7/Q8's computed columns over the
+    # planner's column trim — the chain composes top-down)
     child = plan.children[0]
     proj = None
-    if isinstance(child, PhysProjection) and \
+    while isinstance(child, PhysProjection) and \
             all(not _has_subq(e) for e in child.exprs):
-        proj = child.exprs
+        proj = child.exprs if proj is None else \
+            [_subst_cols(e, child.exprs) for e in proj]
         child = child.children[0]
     group_by = plan.group_by
     aggs = plan.aggs
