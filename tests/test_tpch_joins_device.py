@@ -24,6 +24,7 @@ import pytest
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import tidb_tpu.copr.fragment as F  # noqa: E402
+import tidb_tpu.copr.rowpack as RP  # noqa: E402
 from benchmarks.datagen import tpch  # noqa: E402
 from tidb_tpu import obs  # noqa: E402
 from tidb_tpu.bench.tpch_data import TPCH_DDL, load_table  # noqa: E402
@@ -465,11 +466,15 @@ def small_epochs_compact(monkeypatch):
     calls = []
     inner = F._compact_rows
 
-    def spy(cols, mask, cap, read):
+    def spy(cols, mask, cap, read, nullable):
         calls.append((mask.shape[0], cap, len(read)))
-        return inner(cols, mask, cap, read)
+        return inner(cols, mask, cap, read, nullable)
     monkeypatch.setattr(F, "_compact_rows", spy)
     return calls
+
+
+def _packed(path: str) -> float:
+    return obs.HC_PACK.get(path=path)
 
 
 def _q10_is_right(session, conn, data):
@@ -487,7 +492,9 @@ def test_q10_sorts_a_packed_buffer(joins, device_only, small_epochs_compact):
     """Under 1% of LINEITEM passes Q10's predicates: the body sorts and
     gathers a buffer a 32nd of the epoch, and the answer is the same."""
     session, conn, data = joins
+    before = _packed("packed")
     _q10_is_right(session, conn, data)
+    assert _packed("packed") == before + 1
     (n, cap, read), = small_epochs_compact      # traced once
     assert cap == n // F.HC_COMPACT_DIV
     # c_custkey and the revenue's two columns are packed; the six keys
@@ -526,12 +533,171 @@ def test_rows_that_overflow_the_buffer_run_whole(joins, device_only,
     monkeypatch.setattr(F, "HC_COMPACT_DIV", 4096)   # a 64-row buffer
     dense = session.cop._hc_dense
     before = set(dense)
+    counts = {p: _packed(p) for p in ("packed", "spilled", "whole")}
     _q10_is_right(session, conn, data)
     assert len(small_epochs_compact) == 1 and len(dense - before) == 1
     _q10_is_right(session, conn, data)
     assert len(small_epochs_compact) == 1    # remembered: no second try
+    assert {p: _packed(p) - v for p, v in counts.items()} == {
+        "packed": 0, "spilled": 1, "whole": 1}
     with session.cop._lock:
         dense.difference_update(dense - before)
+
+
+def test_packed_column_with_nulls_keeps_its_validity(device_only,
+                                                     small_epochs_compact):
+    """A column the packed body reads holds NULLs, another holds none:
+    COUNT and SUM over each agree with the host's join and aggregation."""
+    import tidb_tpu.plan.fragment as PF
+    s = Session()
+    s.execute("create table dim (k int not null primary key, w int)")
+    s.execute("create table fact (id int not null primary key, d int, "
+              "g int, v int, u int)")
+    s.execute("insert into dim values " + ",".join(
+        f"({k},{int(k == 7)})" for k in range(40)))   # 1 row in 60 passes
+    n = 20_000
+    rng = np.random.default_rng(37)
+    v = rng.integers(-50, 50, n)
+    store = s.storage.table_store(s.catalog.table("test", "fact").id)
+    store.bulk_load([np.arange(n, dtype=np.int64),
+                     rng.integers(0, 60, n), rng.integers(0, 15_000, n),
+                     v, rng.integers(0, 9, n)],
+                    [None, None, None, rng.random(n) < 0.7, None])
+    s.storage.table_store(s.catalog.table("test", "dim").id).compact(
+        s.storage.safe_ts())
+    for t in ("dim", "fact"):
+        s.execute(f"analyze table {t}")
+    sql = ("select g, count(v), sum(v), count(u), sum(u) from fact, dim "
+           "where fact.d = dim.k and dim.w = 1 group by g "
+           "order by sum(v) desc, g limit 5")
+    before = _packed("packed")
+    got = s.query(sql)
+    assert s.last_engines == ["device[fat]"], s.last_engines
+    assert _packed("packed") == before + 1 and len(small_epochs_compact) == 1
+    with pytest.MonkeyPatch.context() as m:
+        m.setattr(PF, "apply_fragments", lambda p: p)
+        want = s.query(sql + " ")
+    assert got == want
+
+
+def test_pack_counter_is_rendered_at_zero_from_the_first_client():
+    import subprocess
+    code = ("from tidb_tpu import obs\n"
+            "from tidb_tpu.copr.client import CopClient\n"
+            "assert 'hc_pack_total{' not in obs.PROCESS_METRICS.render()\n"
+            "CopClient()\n"
+            "print(obs.PROCESS_METRICS.render())\n")
+    out = subprocess.run([sys.executable, "-c", code], text=True,
+                         env=dict(os.environ, JAX_PLATFORMS="cpu"),
+                         capture_output=True, timeout=120, check=True,
+                         cwd=os.path.dirname(os.path.dirname(__file__)))
+    for path in ("packed", "spilled", "whole"):
+        assert f'tidb_copr_hc_pack_total{{path="{path}"}} 0' in out.stdout
+    assert obs.lint_metrics([obs.PROCESS_METRICS]) == []
+
+
+def _q10_passing(data) -> np.ndarray:
+    """LINEITEM's rows that pass Q10's predicates, in storage order."""
+    from benchmarks.datagen.tpch import parse_date
+    from benchmarks.oracles.q7 import lookup
+    o, li = data["orders"], data["lineitem"]
+    ok = (o["o_orderdate"] >= parse_date("1993-10-01")) & \
+        (o["o_orderdate"] < parse_date("1994-01-01"))
+    in_quarter = lookup(o["o_orderkey"], np.where(ok, 1, -1))
+    flags, codes = li["l_returnflag"]
+    return (np.asarray(codes) == list(flags).index("R")) & \
+        (in_quarter[li["l_orderkey"]] > 0)
+
+
+def test_rows_clustered_in_one_tile_still_pack(joins, device_only,
+                                               small_epochs_compact,
+                                               monkeypatch):
+    """Tiles of 128 rows: a tile holds more passing rows than its even
+    share of the buffer, and the statement still packs them all (the
+    overflow rule is the buffer's, not a tile's)."""
+    from tidb_tpu.copr.placement import _bucket
+    session, conn, data = joins
+    monkeypatch.setattr(RP, "TILE", 128)
+    passing = _q10_passing(data)
+    n = _bucket(len(passing))
+    cap = n // F.HC_COMPACT_DIV
+    per_tile = np.bincount(np.flatnonzero(passing) // 128)
+    assert per_tile.max() > cap // (n // 128) and passing.sum() <= cap
+    dense = set(session.cop._hc_dense)
+    before = _packed("packed")
+    monkeypatch.setattr(session.cop, "_kernels", {})   # traced anew
+    _q10_is_right(session, conn, data)
+    assert len(small_epochs_compact) == 1
+    assert session.cop._hc_dense == dense
+    assert _packed("packed") == before + 1
+
+
+# ---- the packing itself, against numpy ------------------------------------
+
+def _mask_case(case: str, n: int, tile: int, cap: int) -> np.ndarray:
+    rng = np.random.default_rng(37)
+    m = np.zeros(n, bool)
+    if case == "one_full_tile":
+        m[3 * tile:4 * tile] = True
+    elif case == "all_in_last_tile":
+        last = (n - 1) // tile * tile
+        m[rng.choice(np.arange(last, n), min(cap, n - last) // 2,
+                     replace=False)] = True
+    elif case in ("total_is_cap", "total_is_cap_plus_one"):
+        extra = case.endswith("plus_one")
+        m[rng.choice(n, cap + extra, replace=False)] = True
+    elif case in ("density_2pct", "ragged_last_tile"):
+        m = rng.random(n) < 0.02
+    return m
+
+
+PACK_CASES = {   # case -> rows of the epoch (tiles of 256 rows)
+    "none_pass": 8192,
+    "one_full_tile": 8192,
+    "all_in_last_tile": 8192,
+    "total_is_cap": 8192,
+    "total_is_cap_plus_one": 8192,
+    "ragged_last_tile": 8192 + 300,
+    "density_2pct": 16384,
+}
+
+
+@pytest.mark.parametrize("case", sorted(PACK_CASES))
+def test_compact_rows_packs_what_numpy_packs(case, monkeypatch):
+    """`_compact_rows` against `np.flatnonzero(mask)[:cap]`: the epoch row
+    of every slot, the buffer's mask, the overflow flag and each packed
+    column; a column that holds NULLs keeps its own validity, a NULL-free
+    one is valid exactly where a slot holds a row."""
+    import jax.numpy as jnp
+    tile = 256
+    monkeypatch.setattr(RP, "TILE", tile)
+    n = PACK_CASES[case]
+    cap = 512
+    mask = _mask_case(case, n, tile, cap)
+    rng = np.random.default_rng(7)
+    data = [rng.integers(-2**31, 2**31, n, dtype=np.int64).astype(np.int32),
+            rng.random(n).astype(np.float32),
+            rng.integers(0, 9, n, dtype=np.int32)]
+    valid0 = rng.random(n) < 0.8          # column 0 holds NULLs
+    cols = [(jnp.asarray(data[0]), jnp.asarray(valid0)),
+            (jnp.asarray(data[1]), jnp.ones(n, bool)),
+            (jnp.asarray(data[2]), jnp.ones(n, bool))]
+    packed, keep, over, src = F._compact_rows(
+        cols, jnp.asarray(mask), cap, {0, 1}, (0,))
+    want = np.flatnonzero(mask)[:cap]
+    k, total = len(want), int(mask.sum())
+    src, keep = np.asarray(src), np.asarray(keep)
+    assert bool(over) == (total > cap)
+    np.testing.assert_array_equal(keep, np.arange(cap) < total)
+    np.testing.assert_array_equal(src[:k], want)
+    assert src.min() >= 0 and src.max() < n    # every slot can be read at
+    (d0, v0), (d1, v1), stand_in = [
+        (np.asarray(d), np.asarray(v)) for d, v in packed]
+    np.testing.assert_array_equal(d0[:k], data[0][want])
+    np.testing.assert_array_equal(v0[:k], valid0[want])
+    np.testing.assert_array_equal(d1[:k], data[1][want])
+    np.testing.assert_array_equal(v1, keep)
+    np.testing.assert_array_equal(stand_in[0], keep)   # column 2 not read
 
 
 # ---- the control of `correct` ----------------------------------------------

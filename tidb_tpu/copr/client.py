@@ -241,6 +241,8 @@ class CopClient:
             obs.TOPN_SELECT.inc(0, path=sel_path)
         for sel_path in topnsel.HC_PATHS:
             obs.HC_SELECT.inc(0, path=sel_path)
+        for pack_path in ("packed", "spilled", "whole"):
+            obs.HC_PACK.inc(0, path=pack_path)
         _LIVE_CLIENTS.add(self)
 
     def _evict_stale(self, table_id: int, epoch_id: int) -> None:

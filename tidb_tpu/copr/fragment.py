@@ -78,8 +78,10 @@ FRAG_SPAN_CAP = 1 << 26
 # the sorted-run hc body (a GROUP BY whose keys storage order does not
 # group) sorts and gathers every row it is given: over an epoch of this
 # many rows or more it first packs the rows that pass the predicates into
-# a buffer a HC_COMPACT_DIV-th as long (_compact_rows), and runs whole
-# only where they do not fit (Q10 at SF10: 1.9% of 60 M rows pass)
+# a buffer a HC_COMPACT_DIV-th as long, tile by tile with the columns it
+# reads riding along (_compact_rows, copr/rowpack.py), and runs whole only
+# where more pass than the buffer holds (Q10 at SF10: 1.9% of 60 M rows
+# pass, packed in 16 ms where a sort and gathers took 395 ms)
 HC_COMPACT_MIN_ROWS = 1 << 22
 HC_COMPACT_DIV = 32
 
@@ -412,10 +414,16 @@ def _device_fragment(cop, frag, snaps) -> CopResult:
         # first; a statement whose rows once overflowed the buffer is
         # remembered in cop._hc_dense and runs whole from then on
         dense_key = (_frag_key(frag), _sig(prepared))
-        if dense_key not in cop._hc_dense:
+        if dense_key in cop._hc_dense:
+            prepared["__hc_pack__"] = "whole"
+        else:
+            nullable = _nullable_cols(frag, snaps)
+            prepared["__hc_pack__"] = "packed"
             prepared["__hc_dense_key__"] = dense_key
             prepared["__hc_compact__"] = HC_COMPACT_DIV
-            prepared["__sig__"].append(("hccompact", HC_COMPACT_DIV))
+            prepared["__hc_nullable__"] = nullable
+            prepared["__sig__"].append(
+                ("hccompact", HC_COMPACT_DIV, nullable))
 
     # ---- staging ----
     from .. import obs
@@ -695,12 +703,18 @@ def _run_frag_batch(cop, frag, snaps, prepared, spans, builds, overlay,
                        clocked=True, prog=prog):
             out = jax.device_get(dev)
 
-    if mode == "hc" and np.any(np.asarray(
-            out.pop("compact_overflow", 0)) > 0):
+    # one count a read the packing was eligible for, whatever it did
+    pack = prepared.pop("__hc_pack__", None)
+    spilled = mode == "hc" and np.any(np.asarray(
+        out.pop("compact_overflow", 0)) > 0)
+    if pack is not None:
+        obs.HC_PACK.inc(path="spilled" if spilled else pack)
+    if spilled:
         # more rows passed than the packed buffer holds: the statement
         # runs whole, now and from now on
         prepared["__sig__"].remove(
-            ("hccompact", prepared.pop("__hc_compact__")))
+            ("hccompact", prepared.pop("__hc_compact__"),
+             prepared.pop("__hc_nullable__")))
         with cop._lock:
             cop._hc_dense.add(prepared["__hc_dense_key__"])
         return _run_frag_batch(cop, frag, snaps, prepared, spans, builds,
@@ -913,6 +927,21 @@ def _agg_read_cols(frag) -> Optional[set]:
         used |= _expr_cols(t.filters, base)
         base += len(t.col_offsets)
     return used
+
+
+def _nullable_cols(frag, snaps) -> tuple:
+    """Combined-space columns whose epoch holds a NULL. Every other
+    column is valid at every row the program's mask passes: a probe
+    column's validity is all true, and a build column's is its join's
+    `found`, which the mask includes."""
+    out = []
+    base = 0
+    for t in frag.tables:
+        valids = snaps[t.table.id].epoch.valids
+        out.extend(base + ci for ci, off in enumerate(t.col_offsets)
+                   if valids[off] is not None)
+        base += len(t.col_offsets)
+    return tuple(out)
 
 
 def _stage_aligned(cop, frag, snaps, prepared, spans, builds, pcols,
@@ -1653,26 +1682,30 @@ def _emit_pairs(res, sched, term_ix, cnt_ix, tot, cand):
                 [pairs(tot[ix][cand]) for ix in limb_ids])
 
 
-def _compact_rows(cols, mask, cap: int, read: set):
+def _compact_rows(cols, mask, cap: int, read: set, nullable: tuple):
     """Pack the rows `mask` passes to the front of a `cap`-row buffer:
-    (the columns `read` gathered at the passing rows, the buffer's row
-    mask, whether more passed than fit, the epoch row of every slot).
-    One single-operand sort of the row numbers (failing rows sent to the
-    end) finds the passing rows in storage order: over 67 M rows that
-    sort is 184 ms on a v5e where a binary search a slot over the mask's
-    prefix counts is 1.26 s, a two-level search 0.36 s and a scatter
-    0.31 s (my chip run, PR 35); every gather is then `cap` long, not
-    the epoch's length."""
-    n = mask.shape[0]
-    rows = jnp.arange(n, dtype=jnp.int32)
-    src = jnp.minimum(
-        jax.lax.sort(jnp.where(mask, rows, jnp.int32(n)))[:cap], n - 1)
-    total = jnp.sum(mask.astype(jnp.int32))
-    slot = jnp.arange(cap, dtype=jnp.int32)
-    keep = slot < total
+    (the columns `read` at the passing rows in storage order, the
+    buffer's row mask, whether more passed than fit, the epoch row of
+    every slot). The columns ride the packing itself (copr/rowpack.py:
+    a shift network inside tiles of 65 536 rows, then the tiles placed
+    in order), so nothing is sorted or gathered at the epoch's length:
+    67 M rows with three columns pack in 15.7 ms on a v5e, where one sort
+    of the row numbers and gathers of the data and validity at the packed
+    rows take 395 ms (PERF.md, section 6). A slot that holds a row is
+    valid in every column not in `nullable` (copr/fragment.py
+    _nullable_cols), so only those carry their validity along."""
+    from . import rowpack
+
+    read = sorted(read)
+    riders = [cols[i][0] for i in read] + [cols[i][1] for i in read
+                                           if i in nullable]
+    src, vals, total = rowpack.pack(mask, riders, cap)
+    keep = jnp.arange(cap, dtype=jnp.int32) < total
+    data = dict(zip(read, vals))
+    valid = dict(zip([i for i in read if i in nullable], vals[len(read):]))
     # a column the body does not read keeps its slot with a stand-in
-    packed = [(c[0][src], c[1][src]) if i in read else (keep, keep)
-              for i, c in enumerate(cols)]
+    packed = [(data[i], valid.get(i, keep)) if i in data else (keep, keep)
+              for i in range(len(cols))]
     return packed, keep, total > cap, src
 
 
@@ -1707,7 +1740,8 @@ def _hc_body(frag, prepared, cols, mask, aux=None, compact=None):
         cols, mask, spilled, src = _compact_rows(
             cols, mask, mask.shape[0] // compact, _expr_cols(
                 [agg.group_by[gi] for gi in eager]
-                + [d.arg for d in agg.aggs if d.arg is not None]))
+                + [d.arg for d in agg.aggs if d.arg is not None]),
+            prepared["__hc_nullable__"])
     hc = frag.hc
     nulls = prepared["__hc_nulls__"]
     sched = prepared["__hc_sched__"]

@@ -1026,6 +1026,14 @@ HC_SELECT = PROCESS_METRICS.counter(
     "top-k of their rows) or approx (approx_max_k at recall 1.0 over them "
     "all, where the buffer is too large or the groups too few for blocks "
     "to pay); both exact by score")
+HC_PACK = PROCESS_METRICS.counter(
+    "tidb_copr_hc_pack_total",
+    "coprocessor reads of a high-cardinality GROUP BY fragment whose "
+    "sorted-run body may first pack the rows that pass its predicates "
+    "(copr/fragment.py _compact_rows), by what its program did: packed (the "
+    "buffer held every passing row), spilled (more passed than it holds: "
+    "the read ran again whole) or whole (a statement that once spilled "
+    "runs whole from then on)")
 SNAPSHOT_MASK = PROCESS_METRICS.counter(
     "tidb_store_snapshot_mask_total",
     "TableStore.snapshot calls, by the base-row visibility mask they hand "
