@@ -1151,14 +1151,15 @@ class CopClient:
             outs = jax.device_get(devs)
         with obs.stage("merge"):
             out = _merge_tile_outs(outs, prepared["__agg_sched__"])
-        group_dicts = [
-            snap.dictionaries[dag.scan.col_offsets[g.idx]]
-            if g.ftype.is_string and isinstance(g, Col) else None
-            for g in agg.group_by
-        ]
-        chunk = decode_agg_partials(
-            agg, prepared, cards, out, group_dicts,
-            dag.output_types[len(agg.group_by):])
+        with obs.stage("decode"):
+            group_dicts = [
+                snap.dictionaries[dag.scan.col_offsets[g.idx]]
+                if g.ftype.is_string and isinstance(g, Col) else None
+                for g in agg.group_by
+            ]
+            chunk = decode_agg_partials(
+                agg, prepared, cards, out, group_dicts,
+                dag.output_types[len(agg.group_by):])
         return [] if chunk is None else [chunk]
 
     def _build_agg_kernel(self, dag, prepared, cards, segments):
@@ -1207,14 +1208,15 @@ class CopClient:
         with obs.stage("device_get", span_name="device.fetch", clocked=True,
                        prog="titpu_rowmask"):
             packs = jax.device_get(devs)
-        parts = [
-            np.unpackbits(packed, count=None).astype(bool)[:cnt]
-            for packed, (_, _, cnt) in zip(packs, tiles)
-        ]
-        mask = np.concatenate(parts) if parts else np.zeros(0, bool)
-        idx = np.nonzero(mask)[0]
-        if dag.limit is not None and len(idx) > dag.limit.n:
-            idx = idx[: dag.limit.n]
+        with obs.stage("decode"):
+            parts = [
+                np.unpackbits(packed, count=None).astype(bool)[:cnt]
+                for packed, (_, _, cnt) in zip(packs, tiles)
+            ]
+            mask = np.concatenate(parts) if parts else np.zeros(0, bool)
+            idx = np.nonzero(mask)[0]
+            if dag.limit is not None and len(idx) > dag.limit.n:
+                idx = idx[: dag.limit.n]
         return self._host_rows(dag, snap, host_cols, idx)
 
     def _build_rowmask_kernel(self, dag, prepared):
@@ -1233,7 +1235,12 @@ class CopClient:
         return kernel
 
     def _host_rows(self, dag, snap, host_cols, idx) -> list[Chunk]:
-        """Project the selected rows host-side (numpy)."""
+        """Project the selected rows host-side (numpy): the read's
+        `gather` stage."""
+        with obs.stage("gather"):
+            return self._gather_rows(dag, snap, host_cols, idx)
+
+    def _gather_rows(self, dag, snap, host_cols, idx) -> list[Chunk]:
         dicts = self._scan_dicts(dag, snap)
         columns = []
         k = len(idx)
@@ -1283,11 +1290,9 @@ class CopClient:
         with obs.stage("device_get", span_name="device.fetch", clocked=True,
                        prog="titpu_topn"):
             outs = jax.device_get(devs)
-        chunks = []
-        for out in outs:
-            c = self._topn_decode(dag, snap, out)
-            if c is not None:
-                chunks.append(c)
+        with obs.stage("decode"):
+            chunks = [c for c in (self._topn_decode(dag, snap, out)
+                                  for out in outs) if c is not None]
         return chunks
 
     def _select_taken(self, key, prepared) -> list:

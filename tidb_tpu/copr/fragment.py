@@ -57,6 +57,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import obs
 from ..chunk.chunk import Chunk
 from ..chunk.column import Column
 from ..plan.expr import Col
@@ -98,7 +99,6 @@ class _Fallback(Exception):
 def execute_fragment(cop: CopClient, frag: FragmentDAG, snaps: dict
                      ) -> CopResult:
     """snaps: table_id -> TableSnapshot for every fragment table."""
-    from .. import obs
     # placement is decided by the PROBE (fact) epoch: a sharded probe
     # makes this a mesh fragment (builds replicate or key-partition),
     # a small probe keeps the whole tree on the single-device path
@@ -124,10 +124,10 @@ def execute_fragment(cop: CopClient, frag: FragmentDAG, snaps: dict
         obs.COPR_REQUESTS.inc(engine="host-fragment")
         obs.FRAG_FALLBACKS.inc(reason=reason)
         # the host interpreter's time is join work (the probe/
-        # gather/agg loop) — attribute it so the fallback path
-        # stays visible in the per-operator plane, not buried
-        # under "fragment"
-        with obs.operator("join"):
+        # gather/agg loop) — its stage under the join's label keeps
+        # the fallback path visible in the per-operator plane, not
+        # buried under "fragment"
+        with obs.operator("join"), obs.stage("host_fallback"):
             r = _host_fragment(frag, snaps)
         r.engine = f"host(fragment:{reason})"
         return r
@@ -426,7 +426,6 @@ def _device_fragment(cop, frag, snaps) -> CopResult:
                 ("hccompact", HC_COMPACT_DIV, nullable))
 
     # ---- staging ----
-    from .. import obs
     builds = []
     # build-side staging (dimension columns + perm tables) is join
     # work: the operator frame routes its stage time + transfer bytes
@@ -653,7 +652,6 @@ def _run_frag_batch(cop, frag, snaps, prepared, spans, builds, overlay,
             psnap.epoch.num_rows > cop.TILE_ROWS:
         return _run_frag_tiled(cop, frag, snaps, prepared, spans, builds,
                                mode)
-    from .. import obs
     # probe-side staging is scan work; aligned build staging is join
     # work — separate operator frames keep the attribution honest
     with obs.operator("scan"), \
@@ -719,23 +717,24 @@ def _run_frag_batch(cop, frag, snaps, prepared, spans, builds, overlay,
             cop._hc_dense.add(prepared["__hc_dense_key__"])
         return _run_frag_batch(cop, frag, snaps, prepared, spans, builds,
                                overlay, mode)
-    if mode == "hc":
-        # candidate blocks = exchange partitions (1 on a single device)
-        prepared["__hc_blocks__"] = pl.n_devices
-        chunk = _decode_hc(frag, snaps, prepared, out)
-        return [] if chunk is None else [chunk]
-    if mode == "agg":
-        return _decode_frag_agg(frag, snaps, prepared, out)
-    if mode == "topn":
-        chunk = _decode_frag_topn(frag, snaps, out)
-        return [] if chunk is None else [chunk]
+    with obs.stage("decode"):
+        if mode == "hc":
+            # candidate blocks = exchange partitions (1 on a single device)
+            prepared["__hc_blocks__"] = pl.n_devices
+            chunk = _decode_hc(frag, snaps, prepared, out)
+            return [] if chunk is None else [chunk]
+        if mode == "agg":
+            return _decode_frag_agg(frag, snaps, prepared, out)
+        if mode == "topn":
+            chunk = _decode_frag_topn(frag, snaps, out)
+            return [] if chunk is None else [chunk]
 
-    # row mode: device returned a packed probe-row bitmask; host replays
-    # the (cheap, vectorized) gathers for the passing rows only
-    n_rows = phost[0][0].shape[0] if phost else 0
-    mask = np.unpackbits(out, count=None).astype(bool)[:n_rows] \
-        if n_rows else np.zeros(0, bool)
-    idx = np.nonzero(mask)[0]
+        # row mode: device returned a packed probe-row bitmask; host
+        # replays the (cheap, vectorized) gathers for the passing rows only
+        n_rows = phost[0][0].shape[0] if phost else 0
+        mask = np.unpackbits(out, count=None).astype(bool)[:n_rows] \
+            if n_rows else np.zeros(0, bool)
+        idx = np.nonzero(mask)[0]
     return _host_rows_for(frag, snaps, idx, overlay)
 
 
@@ -746,7 +745,6 @@ def _run_frag_tiled(cop, frag, snaps, prepared, spans, builds, mode):
     like the single-table tiled path (client._merge_tile_outs)."""
     from .client import _merge_tile_outs
 
-    from .. import obs
     probe = frag.tables[0]
     psnap = snaps[probe.table.id]
     with obs.operator("scan"), \
@@ -792,28 +790,28 @@ def _run_frag_tiled(cop, frag, snaps, prepared, spans, builds, mode):
     if mode == "agg":
         with obs.stage("merge"):
             out = _merge_tile_outs(outs, prepared["__agg_sched__"])
-        return _decode_frag_agg(frag, snaps, prepared, out)
+        with obs.stage("decode"):
+            return _decode_frag_agg(frag, snaps, prepared, out)
 
     if mode == "topn":
         # per-tile candidate rows; the host Sort/Limit above merge them
         if taken:
             obs.TOPN_SELECT.inc(path=taken[0])
-        chunks = []
-        for out in outs:
-            c = _decode_frag_topn(frag, snaps, out)
-            if c is not None:
-                chunks.append(c)
-        return chunks
+        with obs.stage("decode"):
+            return [c for c in (_decode_frag_topn(frag, snaps, out)
+                                for out in outs) if c is not None]
 
     # rows: per-tile packed bitmasks -> global epoch row indices
     T = cop.TILE_ROWS
     idx_parts = []
-    for ti, (packed, (_, _, cnt)) in enumerate(zip(outs, tiles)):
-        mask = np.unpackbits(packed, count=None).astype(bool)[:cnt]
-        local = np.nonzero(mask)[0]
-        if len(local):
-            idx_parts.append(local + ti * T)
-    idx = np.concatenate(idx_parts) if idx_parts else np.zeros(0, np.int64)
+    with obs.stage("decode"):
+        for ti, (packed, (_, _, cnt)) in enumerate(zip(outs, tiles)):
+            mask = np.unpackbits(packed, count=None).astype(bool)[:cnt]
+            local = np.nonzero(mask)[0]
+            if len(local):
+                idx_parts.append(local + ti * T)
+        idx = np.concatenate(idx_parts) if idx_parts \
+            else np.zeros(0, np.int64)
     return _host_rows_for(frag, snaps, idx, overlay=False)
 
 
@@ -2136,12 +2134,15 @@ def _frag_key(frag: FragmentDAG) -> str:
 
 
 def _host_rows_for(frag, snaps, probe_idx, overlay) -> list[Chunk]:
-    """Materialize joined output rows (tree order) for given probe rows."""
-    cols, valid, dicts = _host_join(frag, snaps, probe_idx,
-                                    overlay=overlay, epoch_only_probe=True)
-    if cols is None:
-        return []
-    return _rows_chunk(frag, cols, valid, dicts)
+    """Materialize joined output rows (tree order) for given probe rows:
+    the read's `gather` stage."""
+    with obs.stage("gather"):
+        cols, valid, dicts = _host_join(frag, snaps, probe_idx,
+                                        overlay=overlay,
+                                        epoch_only_probe=True)
+        if cols is None:
+            return []
+        return _rows_chunk(frag, cols, valid, dicts)
 
 
 def _rows_chunk(frag, cols, valids, dicts) -> list[Chunk]:

@@ -169,41 +169,34 @@ def _op_label(plan: PhysicalPlan) -> str:
 
 
 def run_physical(plan: PhysicalPlan, ctx: ExecContext) -> Chunk:
-    from .. import obs
-
-    # always-on per-operator attribution: when a statement recorder is
-    # installed (every session statement), each node runs under an
-    # operator frame recording its EXCLUSIVE wall time + tagging the
-    # dispatch stages/transfer bytes opened inside — the continuous
-    # feed for Top SQL and the slow log's operator column. Cost is two
-    # perf_counter reads and a dict update per plan node.
-    rec = obs.active_stage_recorder()
-    if ctx.stats is not None:
-        import time as _time
-
-        # attribute dispatch-stage time (staging/compile/transfer/
-        # kernel/device_get/host_fallback) to this node, INCLUSIVE of
-        # children — same convention as the node wall time
-        before = rec.snapshot() if rec is not None else None
-        t0 = _time.perf_counter()
-        engine_tag = [None]
-        with obs.operator(_op_label(plan)):
-            chunk = _run_node(plan, ctx, engine_tag)
-        stages = rec.delta_since(before) if rec is not None else None
-        # mesh flight recorder: collect this node's per-shard dispatch
-        # accounting (None on a client without a recorder) —
-        # feeds the EXPLAIN ANALYZE `mesh` column and the skew detector
-        ctx.stats.record(plan, _time.perf_counter() - t0, chunk.num_rows,
-                         engine_tag[0], stages=stages,
-                         mesh=ctx.cop.take_mesh_note())
-        return chunk
-    if rec is not None:
-        with obs.operator(_op_label(plan)):
+    # always-on: each node runs in its `host_op` stage, which is also its
+    # operator frame: the node's own host work (what no nested stage or
+    # child node claims) on the stage timeline, and the dispatch stages /
+    # transfer bytes opened inside tagged with its label — the continuous
+    # feed for Top SQL and the slow log's operator column
+    if ctx.stats is None:
+        with obs.plan_node(_op_label(plan)):
             chunk = _run_node(plan, ctx, None)
         ctx.cop.take_mesh_note()
         return chunk
-    chunk = _run_node(plan, ctx, None)
-    ctx.cop.take_mesh_note()
+    import time as _time
+
+    # attribute dispatch-stage time (staging/compile/transfer/kernel/
+    # device_get/host_op/...) to this node, INCLUSIVE of children — same
+    # convention as the node wall time
+    rec = obs.active_stage_recorder()
+    before = rec.snapshot() if rec is not None else None
+    t0 = _time.perf_counter()
+    engine_tag = [None]
+    with obs.plan_node(_op_label(plan)):
+        chunk = _run_node(plan, ctx, engine_tag)
+    stages = rec.delta_since(before) if rec is not None else None
+    # mesh flight recorder: collect this node's per-shard dispatch
+    # accounting (None on a client without a recorder) — feeds the
+    # EXPLAIN ANALYZE `mesh` column and the skew detector
+    ctx.stats.record(plan, _time.perf_counter() - t0, chunk.num_rows,
+                     engine_tag[0], stages=stages,
+                     mesh=ctx.cop.take_mesh_note())
     return chunk
 
 
@@ -213,7 +206,8 @@ def _run_node(plan: PhysicalPlan, ctx: ExecContext,
     if isinstance(plan, PhysTableRead):
         if plan.dag.scan.table_id < 0:
             return Chunk([])  # dual pseudo-table: one conceptual row, no cols
-        snap = ctx.txn.snapshot(plan.dag.scan.table_id)
+        with obs.stage("snapshot"):
+            snap = ctx.txn.snapshot(plan.dag.scan.table_id)
         # the engine pins the placement (the epoch sharded over the
         # device mesh, or on one device) for this node from the
         # snapshot it just took, so every staging/kernel decision
@@ -244,12 +238,13 @@ def _run_node(plan: PhysicalPlan, ctx: ExecContext,
     from ..plan.fragment import PhysFragmentRead
     if isinstance(plan, PhysFragmentRead):
         from ..copr.fragment import execute_fragment
-        snaps = {t.table.id: ctx.txn.snapshot(t.table.id)
-                 for t in plan.frag.tables}
-        for sm in plan.frag.semis:  # membership builds need snapshots too
-            tid = sm.table.table.id
-            if tid not in snaps:
-                snaps[tid] = ctx.txn.snapshot(tid)
+        with obs.stage("snapshot"):
+            snaps = {t.table.id: ctx.txn.snapshot(t.table.id)
+                     for t in plan.frag.tables}
+            for sm in plan.frag.semis:  # membership builds need them too
+                tid = sm.table.table.id
+                if tid not in snaps:
+                    snaps[tid] = ctx.txn.snapshot(tid)
         result = execute_fragment(ctx.cop, plan.frag, snaps)
         obs.note_engine(result.engine)
         if engine_tag is not None:

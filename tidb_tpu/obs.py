@@ -1063,8 +1063,8 @@ FRAG_FETCHED_ROWS = PROCESS_METRICS.counter(
 DISPATCH_STAGE_SECONDS = PROCESS_METRICS.histogram(
     "tidb_dispatch_stage_duration_seconds",
     "exclusive wall time of one obs.stage, labeled by stage: every layer "
-    "of the served path from the socket to the fetch (the README's "
-    "stage vocabulary lists them)")
+    "of the served path from the socket read to the socket write (the "
+    "README's stage vocabulary lists them)")
 DISPATCH_STAGE_CLOCKED = PROCESS_METRICS.counter(
     "tidb_dispatch_stage_clocked_seconds_total",
     "wall time of the brackets whose thread CPU clock is read at both "
@@ -1549,52 +1549,33 @@ _op_tls = threading.local()
 
 
 class _OpCtx:
-    """One plan-operator frame: tags the thread with the operator label
-    (stages closed inside attribute their time to it; transfer-byte
-    accounting does the same) and records the frame's EXCLUSIVE wall
-    seconds on the active StageRecorder — a per-thread nesting stack
-    subtracts inner operator frames, so summing op_wall never double
-    counts a join's probe scan into the join. Without an active
-    recorder it is label bookkeeping only (two TLS writes)."""
+    """One operator frame inside a plan node: tags the thread with the
+    operator label, so the stages closed inside (and the transfer bytes
+    staged inside) are that operator's on the statement's recorder. Two
+    TLS writes and no clock: the operator's time IS the stages booked
+    under its label (StageRecorder.op_wall), so a frame holds the stages
+    that do its work (the fragment's scan and join staging, its fused
+    kernel and fetch)."""
 
-    __slots__ = ("label", "prev", "t0", "rec")
+    __slots__ = ("label", "prev")
 
     def __init__(self, label: str) -> None:
         self.label = label
         self.prev = None
-        self.t0 = 0.0
-        self.rec = None
 
     def __enter__(self) -> "_OpCtx":
         self.prev = getattr(_op_tls, "label", None)
         _op_tls.label = self.label
-        rec = getattr(_stage_tls, "rec", None)
-        self.rec = rec
-        if rec is not None:
-            stack = getattr(_op_tls, "stack", None)
-            if stack is None:
-                stack = _op_tls.stack = []
-            stack.append(0.0)  # accumulates nested-frame wall time
-            self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc) -> None:
         _op_tls.label = self.prev
-        rec = self.rec
-        if rec is not None:
-            dt = time.perf_counter() - self.t0
-            stack = _op_tls.stack
-            child = stack.pop()
-            if stack:
-                stack[-1] += dt
-            rec.add_op_wall(self.label,
-                            dt - child if dt > child else 0.0)
 
 
 def operator(label: str) -> _OpCtx:
-    """`with obs.operator("join"):` — attribute the enclosed work (wall
-    time, dispatch stages, transfer bytes) to one named plan operator
-    on the statement's StageRecorder."""
+    """`with obs.operator("join"):` — attribute the stages and transfer
+    bytes of the enclosed work to one named operator on the statement's
+    StageRecorder."""
     return _OpCtx(label)
 
 
@@ -1625,14 +1606,15 @@ class StageRecorder:
     to stay always-on.
 
     Besides the flat per-stage totals it carries the per-OPERATOR
-    attribution the Top SQL plane aggregates: `op_wall` (exclusive
-    wall seconds per plan operator, from obs.operator frames the
-    executor/fragment paths open), `ops` (each operator's per-stage
-    split; stages recorded outside any operator frame land under
-    '(session)'), and `op_bytes` (host->device transfer bytes per
-    operator, fed by the copr client's staging accounting)."""
+    attribution the Top SQL plane aggregates: `ops` (each operator's
+    per-stage split; stages recorded outside any operator frame land
+    under '(session)'), `op_wall` (exclusive wall seconds per operator:
+    the sum of its split, since every plan node's frame is its
+    `host_op` stage, which holds what no nested stage claims), and
+    `op_bytes` (host->device transfer bytes per operator, fed by the
+    copr client's staging accounting)."""
 
-    __slots__ = ("totals", "counts", "offcpu", "op_wall", "ops",
+    __slots__ = ("totals", "counts", "offcpu", "ops",
                  "op_bytes", "op_mesh", "engines", "conn", "seq")
 
     def __init__(self, conn: int = 0, seq: int = 0) -> None:
@@ -1646,7 +1628,6 @@ class StageRecorder:
         # statement number): metadata of its stages' profiler events
         self.conn = conn
         self.seq = seq
-        self.op_wall: dict[str, float] = {}
         self.ops: dict[str, dict[str, float]] = {}
         self.op_bytes: dict[str, int] = {}
         # per-operator mesh balance from the flight recorder:
@@ -1663,8 +1644,13 @@ class StageRecorder:
         if offcpu:
             self.offcpu[name] = self.offcpu.get(name, 0.0) + offcpu
 
-    def add_op_wall(self, op: str, seconds: float) -> None:
-        self.op_wall[op] = self.op_wall.get(op, 0.0) + seconds
+    @property
+    def op_wall(self) -> dict[str, float]:
+        """Exclusive wall seconds per operator, inner frames subtracted
+        (a join's probe scan is not the join's): the stages closed
+        under its label, outside-any-frame '(session)' left out."""
+        return {op: sum(d.values()) for op, d in self.ops.items()
+                if op != TopSQL.SESSION_OP}
 
     def note_mesh(self, op: str, share: float, skew: float) -> None:
         """Record one sharded dispatch's balance under the operator
@@ -1942,11 +1928,11 @@ class _StageCtx:
             rec.add(stage, excl, off)
             # per-operator split of the same exclusive time: stages
             # closed outside any operator frame (plan_build at the
-            # session layer) land under '(session)'. A stage that
-            # ENCLOSES the operator frames is opened with
-            # op_split=False: its self time is what those frames
-            # already hold as op_wall, and op_wall plus the
-            # '(session)' stages must stay additive (Top SQL coverage)
+            # session layer) land under '(session)'. `exec`, which
+            # ENCLOSES the plan's node frames, is opened with
+            # op_split=False and stays out of Top SQL's attribution,
+            # as it always has: its self time is the session's glue
+            # around the plan, not an operator's
             if self.op_split:
                 rec.add_op_stage(
                     getattr(_op_tls, "label", None) or "(session)",
@@ -1960,6 +1946,35 @@ class _StageCtx:
 # profiler events only under a jax profiler session (further keywords
 # become their metadata, e.g. prog="titpu_agg").
 stage = _StageCtx
+
+
+class _NodeCtx(_StageCtx):
+    """One plan node of the executor: its `host_op` stage (the node's own
+    host work, every nested stage and child node subtracting itself) and
+    its operator frame in one context manager and one pair of clock
+    reads. The stage books its exclusive time under the node's label,
+    which is what makes StageRecorder.op_wall the operator's exclusive
+    wall; `op=<label>` is its profiler event's metadata."""
+
+    __slots__ = ("label", "prev_label")
+
+    def __init__(self, label: str) -> None:
+        super().__init__("host_op", "executor." + label, op=label)
+        self.label = label
+
+    def __enter__(self) -> Optional[Span]:
+        self.prev_label = getattr(_op_tls, "label", None)
+        _op_tls.label = self.label
+        return super().__enter__()
+
+    def __exit__(self, *exc) -> None:
+        super().__exit__(*exc)
+        _op_tls.label = self.prev_label
+
+
+# `with obs.plan_node("scan+agg"):` — one plan node's frame (executor/
+# engine.py:run_physical).
+plan_node = _NodeCtx
 
 
 def note_stage(name: str, seconds: float) -> None:
@@ -2184,8 +2199,9 @@ def fmt_stages(stages: Optional[dict[str, float]]) -> str:
     if not stages:
         return ""
     order = ("parse", "fast_plan", "plan_build", "admission", "exec",
-             "prepare", "staging", "transfer", "compile", "kernel",
-             "device_get", "merge", "host_fallback", "ranged")
+             "snapshot", "prepare", "staging", "transfer", "compile",
+             "kernel", "device_get", "merge", "decode", "gather",
+             "host_op", "result_rows", "host_fallback", "ranged")
     keys = [k for k in order if k in stages] + \
         sorted(k for k in stages if k not in order)
     return " ".join(f"{k}:{stages[k] * 1e3:.3g}ms" for k in keys)

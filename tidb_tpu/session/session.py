@@ -66,11 +66,13 @@ from ..errno import (
 
 
 def _exec_stage(span_name: Optional[str] = None):
-    """The executor's stage: the plan's run with everything nested in it
-    (plan, admission, the coprocessor's stages) subtracting itself.
-    Clocked (one of the few brackets whose thread CPU time is read), and
-    out of the recorder's per-operator split, since it encloses the
-    operator frames, whose op_wall already holds its self time."""
+    """The executor's stage: the statement's run with everything nested
+    in it (plan, admission, the plan nodes' `host_op` and the stages
+    inside them, `result_rows`) subtracting itself, so its self time is
+    the session's glue around the plan. Clocked (one of the few brackets
+    whose thread CPU time is read; the stages nested in it share its
+    reading), and out of the recorder's per-operator split, since it
+    encloses the plan nodes' operator frames."""
     return obs.stage("exec", span_name, clocked=True, op_split=False)
 
 
@@ -1769,7 +1771,9 @@ class Session:
         ftypes = [f.ftype for f in plan.schema.fields]
         if not chunk.columns:
             return ResultSet(names, [], column_types=ftypes)
-        return ResultSet(names, chunk.to_pylist(), column_types=ftypes)
+        with obs.stage("result_rows"):
+            rows = chunk.to_pylist()
+        return ResultSet(names, rows, column_types=ftypes)
 
     def _lock_for_update(self, stmt: ast.SelectStmt) -> None:
         """SELECT ... FOR UPDATE row locks (reference: point-get/scan
