@@ -57,6 +57,7 @@ from ..plan.expr import Call, Col, Const, PlanExpr
 from ..store.table_store import TableSnapshot
 from ..types.field_type import FieldType, TypeKind
 from . import host_exec
+from . import rowbits
 from . import sumexact as SE
 from . import topnsel
 from .bounds import (
@@ -1209,14 +1210,9 @@ class CopClient:
                        prog="titpu_rowmask"):
             packs = jax.device_get(devs)
         with obs.stage("decode"):
-            parts = [
-                np.unpackbits(packed, count=None).astype(bool)[:cnt]
-                for packed, (_, _, cnt) in zip(packs, tiles)
-            ]
-            mask = np.concatenate(parts) if parts else np.zeros(0, bool)
-            idx = np.nonzero(mask)[0]
-            if dag.limit is not None and len(idx) > dag.limit.n:
-                idx = idx[: dag.limit.n]
+            idx = rowbits.decode(
+                packs, [cnt for _, _, cnt in tiles], self.TILE_ROWS,
+                limit=None if dag.limit is None else dag.limit.n)
         return self._host_rows(dag, snap, host_cols, idx)
 
     def _build_rowmask_kernel(self, dag, prepared):

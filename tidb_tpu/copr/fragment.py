@@ -62,6 +62,7 @@ from ..chunk.chunk import Chunk
 from ..chunk.column import Column
 from ..plan.expr import Col
 from ..plan.fragment import FragmentDAG
+from . import rowbits
 from . import topnsel
 from .bounds import expr_bounds, expr_device_safe, fits_int32
 from .client import (
@@ -732,9 +733,7 @@ def _run_frag_batch(cop, frag, snaps, prepared, spans, builds, overlay,
         # row mode: device returned a packed probe-row bitmask; host
         # replays the (cheap, vectorized) gathers for the passing rows only
         n_rows = phost[0][0].shape[0] if phost else 0
-        mask = np.unpackbits(out, count=None).astype(bool)[:n_rows] \
-            if n_rows else np.zeros(0, bool)
-        idx = np.nonzero(mask)[0]
+        idx = rowbits.decode([out], [n_rows], n_rows)
     return _host_rows_for(frag, snaps, idx, overlay)
 
 
@@ -802,16 +801,9 @@ def _run_frag_tiled(cop, frag, snaps, prepared, spans, builds, mode):
                                 for out in outs) if c is not None]
 
     # rows: per-tile packed bitmasks -> global epoch row indices
-    T = cop.TILE_ROWS
-    idx_parts = []
     with obs.stage("decode"):
-        for ti, (packed, (_, _, cnt)) in enumerate(zip(outs, tiles)):
-            mask = np.unpackbits(packed, count=None).astype(bool)[:cnt]
-            local = np.nonzero(mask)[0]
-            if len(local):
-                idx_parts.append(local + ti * T)
-        idx = np.concatenate(idx_parts) if idx_parts \
-            else np.zeros(0, np.int64)
+        idx = rowbits.decode(outs, [cnt for _, _, cnt in tiles],
+                             cop.TILE_ROWS)
     return _host_rows_for(frag, snaps, idx, overlay=False)
 
 
