@@ -117,11 +117,15 @@ ENGINE_TAG_FAMILIES: tuple[str, ...] = (
 #   fat    fused hc final cut (exact device ordering, k+1 rows out)
 #   group  all-groups sorted-run aggregation (dense gate rejected)
 #   +semi  suffix: semi/anti membership bitmap gates fused in
-DEVICE_FRAGMENT_MODES: tuple[str, ...] = (
+#   +runstat  suffix: run-statistics gates (EXISTS / NOT EXISTS / IN ...
+#          HAVING over the probe row's own storage run) fused in
+_FRAGMENT_BODIES: tuple[str, ...] = (
     "agg", "rows", "topn", "hc", "fat", "group",
     "agg+semi", "rows+semi", "topn+semi", "hc+semi", "fat+semi",
     "group+semi",
 )
+DEVICE_FRAGMENT_MODES: tuple[str, ...] = _FRAGMENT_BODIES + tuple(
+    f"{m}+runstat" for m in _FRAGMENT_BODIES)
 
 __all__ = ["HOT_LOCKS", "BLOCKING_CALLS", "BLOCKING_RECEIVER_ALLOW",
            "TLS_FRAME_FNS", "TLS_FRAME_CTX_ONLY", "THREAD_NAME_PREFIX",

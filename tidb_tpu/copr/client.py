@@ -58,6 +58,7 @@ from ..store.table_store import TableSnapshot
 from ..types.field_type import FieldType, TypeKind
 from . import host_exec
 from . import rowbits
+from . import runstat
 from . import sumexact as SE
 from . import topnsel
 from .bounds import (
@@ -244,6 +245,10 @@ class CopClient:
             obs.HC_SELECT.inc(0, path=sel_path)
         for pack_path in ("packed", "spilled", "whole"):
             obs.HC_PACK.inc(0, path=pack_path)
+        for body in runstat.BODIES:
+            obs.FRAG_READS.inc(0, mode=f"{body}+runstat")
+        for gate_kind in runstat.KINDS:
+            obs.RUNSTAT_GATES.inc(0, kind=gate_kind)
         _LIVE_CLIENTS.add(self)
 
     def _evict_stale(self, table_id: int, epoch_id: int) -> None:
@@ -486,6 +491,24 @@ class CopClient:
             with self._lock:
                 self._stats[key] = hit
         return bool(hit)
+
+    def _longest_run(self, snap: TableSnapshot, off: int) -> int:
+        """Rows in the longest run of equal values of the epoch column at
+        `off` (storage order, hidden rows included): the run-statistics
+        gates' scan depth and tile halo. Cached per epoch."""
+        key = (snap.epoch.epoch_id, "longest", off)
+        with self._lock:
+            hit = self._stats.get(key)
+        if hit is None:
+            k = snap.epoch.columns[off]
+            hit = 0
+            if len(k):
+                starts = np.flatnonzero(k[1:] != k[:-1]) + 1
+                hit = int(np.diff(starts, prepend=0,
+                                  append=len(k)).max())
+            with self._lock:
+                self._stats[key] = hit
+        return hit
 
     def _rank_meta(self, snap: TableSnapshot, offsets):
         """Host rank metadata for the streamseg kernel over the epoch

@@ -47,6 +47,15 @@ error from the device compiler or runtime fails the statement
 (DeviceError). A read the device answers is counted by the mode that
 served it in `tidb_copr_fragment_reads_total{mode}` and by the rows it
 brought back in `tidb_copr_fragment_fetched_rows_total`.
+
+A fragment with run-statistics gates (plan/fragment.py FragRunGate, mode
+suffix `+runstat`) reads them on the device from the probe's storage runs
+only; a probe snapshot that cannot serve them (`runstat-overlay`: an MVCC
+overlay breaks the runs; `runstat-mesh`; `runstat-unordered`: storage is
+not ordered by the run key, or it holds a NULL; `runstat-long-run`: a run
+longer than a tile; `runstat-sum-width`: a run's sum could leave int32)
+takes the host interpreter like any other gate, which totals each gate
+per key value.
 """
 
 from __future__ import annotations
@@ -63,6 +72,7 @@ from ..chunk.column import Column
 from ..plan.expr import Col
 from ..plan.fragment import FragmentDAG
 from . import rowbits
+from . import runstat as RS
 from . import topnsel
 from .bounds import expr_bounds, expr_device_safe, fits_int32
 from .client import (
@@ -112,10 +122,14 @@ def execute_fragment(cop: CopClient, frag: FragmentDAG, snaps: dict
                     build_rows = sum(
                         snaps[t.table.id].epoch.num_rows
                         for t in frag.tables[1:])
-                    sp.note = (f"{len(frag.tables)} tables, mode {mode}, "
-                               f"{build_rows} build rows")
+                    gates = "".join(
+                        f", gate {g.kind}" for g in frag.runstats)
+                    sp.note = (f"{len(frag.tables)} tables, mode {mode}"
+                               f"{gates}, {build_rows} build rows")
             obs.COPR_REQUESTS.inc(engine="device-fragment")
             obs.FRAG_READS.inc(mode=mode)
+            for g in frag.runstats:
+                obs.RUNSTAT_GATES.inc(kind=g.kind)
             obs.FRAG_FETCHED_ROWS.inc(sum(c.num_rows for c in r.chunks))
             return r
         except jax.errors.JaxRuntimeError as e:
@@ -195,6 +209,10 @@ def _device_fragment(cop, frag, snaps) -> CopResult:
         for d in frag.agg.aggs:
             if d.arg is not None:
                 cop._prepare_expr(d.arg, comb_dicts, prepared)
+
+    if frag.runstats:
+        _prepare_runstats(cop, frag, psnap, prepared, comb_dicts,
+                          comb_bounds)
 
     # join key spans
     spans = []
@@ -479,6 +497,8 @@ def _device_fragment(cop, frag, snaps) -> CopResult:
         "group" if prepared.get("__hc_all__") else mode)
     if getattr(frag, "semis", None):
         emode = f"{emode}+semi"
+    if frag.runstats:
+        emode = f"{emode}+runstat"
     return CopResult(chunks, is_partial_agg=frag.agg is not None,
                      engine=pl.engine(emode))
 
@@ -619,6 +639,137 @@ def _stage_semi_bitmap(cop, sm, snap, lo: int, span: int) -> dict:
     return entry
 
 
+def _prepare_runstats(cop, frag, psnap, prepared, comb_dicts,
+                      comb_bounds) -> None:
+    """Gates of the run-statistics mode over this probe snapshot, and its
+    static parameters: the doubling steps that cover the epoch's longest
+    run and the halo a tile borrows from each neighbour."""
+    if len(psnap.overlay_handles) > 0:
+        raise _Fallback("runstat-overlay")  # overlay rows sit apart
+    if cop.placement.axis is not None:
+        raise _Fallback("runstat-mesh")
+    g0 = frag.runstats[0]
+    key_off = g0.table.col_offsets[g0.key_local]
+    if not cop._runs_ordered(psnap, [key_off]):
+        raise _Fallback("runstat-unordered")
+    longest = cop._longest_run(psnap, key_off)
+    halo = max(longest - 1, 0)
+    if psnap.epoch.num_rows > cop.TILE_ROWS and halo > cop.TILE_ROWS:
+        raise _Fallback("runstat-long-run")
+    for g in frag.runstats:
+        dicts = [psnap.dictionaries[off] for off in g.table.col_offsets]
+        bounds = cop._scan_bounds(_facade_dag(g.table), psnap)
+        for ci, off in enumerate(g.table.col_offsets):
+            if psnap.epoch.columns[off].dtype == np.int64 and \
+                    not fits_int32(bounds[ci]):
+                raise _Fallback("int64-column")
+        for c in g.table.filters:
+            cop._prepare_expr(c, dicts, prepared)
+            if not expr_device_safe(c, bounds):
+                raise _Fallback("filter-unsafe")
+        for _func, arg, _op, _thr in g.having:
+            if arg is None:
+                continue
+            cop._prepare_expr(arg, dicts, prepared)
+            b = expr_bounds(arg, bounds)
+            # every run total stays inside int32 with room for the
+            # threshold's clamp (runstat.compare)
+            if not expr_device_safe(arg, bounds) or b is None or \
+                    max(abs(b[0]), abs(b[1])) * longest > RS.I32_MAX - 1:
+                raise _Fallback("runstat-sum-width")
+        if g.probe_val is not None:
+            cop._prepare_expr(g.probe_val, comb_dicts, prepared)
+            if not expr_device_safe(g.probe_val, comb_bounds):
+                raise _Fallback("key-width")
+    steps = RS.steps_for(longest)
+    prepared["__runstat__"] = {"steps": steps, "halo": halo,
+                               "tile": cop.TILE_ROWS}
+    prepared["__sig__"].append(("runstat", steps, halo, cop.TILE_ROWS))
+
+
+def _stage_runstat(cop, frag, psnap, tiles=None, ti=None) -> dict:
+    """The gates' columns as the program reads them: {"g": one column
+    tuple a gate, "vis"} over the whole epoch, or over tile `ti` of the
+    per-gate `tiles` with its neighbours ("prev", "next": the same shape;
+    "edge": int32[2], whether each is a real neighbour)."""
+    if tiles is None:
+        staged = [cop._stage_inputs(_facade_dag(g.table), psnap,
+                                    overlay=False) for g in frag.runstats]
+        return {"g": [tuple(st[0]) for st in staged], "vis": staged[0][1]}
+
+    def at(i):
+        return {"g": [tuple(t[i][0]) for t in tiles], "vis": tiles[0][i][1]}
+
+    last = len(tiles[0]) - 1
+    return {**at(ti), "prev": at(max(ti - 1, 0)), "next": at(min(ti + 1, last)),
+            "edge": jnp.asarray([ti > 0, ti < last], dtype=jnp.int32)}
+
+
+def _runstat_mask(frag, prepared, rs, cols):
+    """bool[rows]: the probe rows that pass every run-statistics gate.
+    rs: the gates' staged columns (_stage_runstat); cols: the combined
+    columns of the same rows (the probe value a residual compares)."""
+    cfg = prepared["__runstat__"]
+    tile = cfg["tile"]
+    vis, gcols = rs["vis"], [widen32(list(g)) for g in rs["g"]]
+    halo = cfg["halo"] if "prev" in rs else 0
+    if halo:
+        # a tile's rows with its neighbours' edge rows around them; a
+        # neighbour that does not exist (edge 0) contributes no row
+        prev, nxt = rs["prev"], rs["next"]
+        vis = RS.extend(vis, prev["vis"] & (rs["edge"][0] > 0),
+                        nxt["vis"] & (rs["edge"][1] > 0), tile, halo)
+        gcols = [widen32([
+            (RS.extend(d, pd, nd, tile, halo), RS.extend(v, pv, nv, tile,
+                                                           halo))
+            for (d, v), (pd, pv), (nd, nv) in zip(cur, pg, ng)])
+            for cur, pg, ng in zip(rs["g"], prev["g"], nxt["g"])]
+    g0 = frag.runstats[0]
+    key = gcols[0][g0.key_local][0]
+    passed = None
+    for g, gc in zip(frag.runstats, gcols):
+        m = selection_mask(g.table.filters, gc, prepared, vis)
+        if g.kind == "in_having":
+            arrays = [(m.astype(jnp.int32), "sum")]
+            for func, arg, _op, _thr in g.having:
+                if arg is not None:
+                    x, xv = eval_expr(arg, gc, prepared)
+                    ok = m & xv
+                    arrays.append((ok.astype(jnp.int32), "sum"))
+                    if func == "sum":
+                        arrays.append((jnp.where(ok, x.astype(jnp.int32),
+                                                 0), "sum"))
+            tot = RS.run_totals(key, arrays, cfg["steps"])
+            ok = tot[0] > 0   # the run is one of the subquery's groups
+            k = 1
+            for func, arg, op, thr in g.having:
+                if arg is None:
+                    ok = ok & RS.compare(op, tot[0], thr)
+                elif func == "count":
+                    ok = ok & RS.compare(op, tot[k], thr)
+                    k += 1
+                else:   # a SUM of no value is NULL: no comparison holds
+                    ok = ok & (tot[k] > 0) & RS.compare(op, tot[k + 1], thr)
+                    k += 2
+            ok = RS.unextend(ok, tile, halo)
+        else:
+            s_, sv = gc[g.cmp_local]
+            live = m & sv
+            cnt, lo, hi = (RS.unextend(t, tile, halo) for t in RS.run_totals(
+                key, [(live.astype(jnp.int32), "sum"),
+                      (jnp.where(live, s_, RS.I32_MAX), "min"),
+                      (jnp.where(live, s_, RS.I32_MIN), "max")],
+                cfg["steps"]))
+            # some row of the run holds another value than the probe row's
+            pv, pvl = eval_expr(g.probe_val, cols, prepared)
+            pv = pv.astype(jnp.int32)
+            ok = pvl & (cnt > 0) & ~((lo == pv) & (hi == pv))
+            if g.kind == "not_exists":
+                ok = ~ok
+        passed = ok if passed is None else passed & ok
+    return passed
+
+
 def _mode_op(frag, mode: str) -> str:
     """The fused kernel's operator label for the attribution plane:
     one device program covers the whole tree, so the label names the
@@ -672,6 +823,10 @@ def _run_frag_batch(cop, frag, snaps, prepared, spans, builds, overlay,
                 obs.stage("staging", span_name="copr.staging"):
             kern_builds = _stage_aligned(cop, frag, snaps, prepared,
                                          spans, jb, pcols) + sb
+    if frag.runstats:
+        with obs.operator("scan"), \
+                obs.stage("staging", span_name="copr.staging"):
+            kern_builds = kern_builds + [_stage_runstat(cop, frag, psnap)]
 
     aux = None
     if mode == "hc" and not overlay and \
@@ -679,18 +834,14 @@ def _run_frag_batch(cop, frag, snaps, prepared, spans, builds, overlay,
         aux = _stage_rank_aux(cop, psnap, prepared)
     key = ("frag", _frag_key(frag), _sig(prepared), mode,
            pcols[0][0].shape[0] if pcols else 0,
-           tuple(
-               ("part", b["present"].shape[0]) if "bykey" in b
-               else ("al", b["found"].shape[0]) if "acols" in b
-               else ("bm", b["bm"].shape[0]) if "bm" in b
-               else b["cols"][0][0].shape[0]
-               for b in kern_builds))
+           tuple(_build_shape(b) for b in kern_builds))
     taken = cop._select_taken(key, prepared) \
         if mode in ("topn", "hc") else None
+    name = _prog_mode(frag, mode)
     kern = cop._kernel(key, lambda: pl.frag_program(
-        _build_frag_kernel(frag, prepared, spans, mode, pl), mode,
+        _build_frag_kernel(frag, prepared, spans, mode, pl), name,
         prepared, cop.recorder))
-    prog = f"titpu_frag_{mode}"
+    prog = f"titpu_frag_{name}"
     with obs.operator(_mode_op(frag, mode)):
         with obs.stage("kernel", span_name="device.dispatch", prog=prog):
             dev = kern(pcols, pvis, kern_builds) if aux is None \
@@ -749,10 +900,13 @@ def _run_frag_tiled(cop, frag, snaps, prepared, spans, builds, mode):
     with obs.operator("scan"), \
             obs.stage("staging", span_name="copr.staging"):
         tiles = cop._stage_tiles(_facade_dag(probe), psnap)
+        gate_tiles = [cop._stage_tiles(_facade_dag(g.table), psnap)
+                      for g in frag.runstats]
     bucket = tiles[0][0][0][0].shape[0] if tiles and tiles[0][0] else 0
     kern = None
     devs = []
     kop = _mode_op(frag, mode)
+    prog = f"titpu_frag_{_prog_mode(frag, mode)}"
     taken = None
     jb_t, sb_t = builds[:len(frag.joins)], builds[len(frag.joins):]
     for ti, (cols, vis, cnt) in enumerate(tiles):
@@ -762,28 +916,25 @@ def _run_frag_tiled(cop, frag, snaps, prepared, spans, builds, mode):
                     obs.stage("staging", span_name="copr.staging"):
                 kb = _stage_aligned(cop, frag, snaps, prepared, spans,
                                     jb_t, cols, tag=("tile", ti)) + sb_t
+        if frag.runstats:
+            kb = kb + [_stage_runstat(cop, frag, psnap, gate_tiles, ti)]
         if kern is None:
             key = ("frag", _frag_key(frag), _sig(prepared), mode, bucket,
-                   tuple(
-                       ("al", b["found"].shape[0]) if "acols" in b
-                       else ("bm", b["bm"].shape[0]) if "bm" in b
-                       else b["cols"][0][0].shape[0]
-                       for b in kb))
+                   tuple(_build_shape(b) for b in kb))
             if mode == "topn":
                 taken = cop._select_taken(key, prepared)
             pl = cop.placement
             kern = cop._kernel(key, lambda: pl.frag_program(
-                _build_frag_kernel(frag, prepared, spans, mode, pl), mode,
-                prepared, cop.recorder))
+                _build_frag_kernel(frag, prepared, spans, mode, pl),
+                _prog_mode(frag, mode), prepared, cop.recorder))
         from ..util import interrupt
         interrupt.check()
         with obs.operator(kop), \
-                obs.stage("kernel", span_name="device.dispatch",
-                          prog=f"titpu_frag_{mode}"):
+                obs.stage("kernel", span_name="device.dispatch", prog=prog):
             devs.append(kern(cols, vis, kb))
     with obs.operator(kop), \
             obs.stage("device_get", span_name="device.fetch", clocked=True,
-                      prog=f"titpu_frag_{mode}"):
+                      prog=prog):
         outs = jax.device_get(devs)
 
     if mode == "agg":
@@ -805,6 +956,25 @@ def _run_frag_tiled(cop, frag, snaps, prepared, spans, builds, mode):
         idx = rowbits.decode(outs, [cnt for _, _, cnt in tiles],
                              cop.TILE_ROWS)
     return _host_rows_for(frag, snaps, idx, overlay=False)
+
+
+def _build_shape(b: dict):
+    """What of one kernel-argument entry a program's cache key holds."""
+    if "bykey" in b:
+        return ("part", b["present"].shape[0])
+    if "acols" in b:
+        return ("al", b["found"].shape[0])
+    if "bm" in b:
+        return ("bm", b["bm"].shape[0])
+    if "g" in b:
+        return ("rs", b["vis"].shape[0], "prev" in b)
+    return b["cols"][0][0].shape[0]
+
+
+def _prog_mode(frag, mode: str) -> str:
+    """The program's name after titpu_frag_: its mode, with `_runstat`
+    where run-statistics gates ride in it."""
+    return f"{mode}_runstat" if frag.runstats else mode
 
 
 def _decode_frag_agg(frag, snaps, prepared, out) -> list[Chunk]:
@@ -910,6 +1080,7 @@ def _agg_read_cols(frag) -> Optional[set]:
     used = _expr_cols(
         [j.probe_key for j in frag.joins]
         + [sm.probe_key for sm in frag.semis]
+        + [g.probe_val for g in frag.runstats if g.probe_val is not None]
         + frag.selection + list(frag.agg.group_by)
         + [d.arg for d in frag.agg.aggs if d.arg is not None])
     base = 0
@@ -1383,6 +1554,10 @@ def _build_frag_kernel(frag, prepared, spans, mode, pl):
                 mask = mask & ~hit  # NULL probe key never matches: kept
             else:  # ANTI_NULL, null-free set: NULL probe key filtered
                 mask = mask & kvl_s & ~hit
+        if frag.runstats:
+            mask = mask & _runstat_mask(
+                frag, prepared, builds[len(frag.joins) + len(frag.semis)],
+                cols)
         if sel:
             mask = selection_mask(sel, cols, prepared, mask)
         if mode == "agg":
@@ -2112,6 +2287,9 @@ def _frag_key(frag: FragmentDAG) -> str:
         parts.append(repr(t.filters))
     for sm in frag.semis:
         parts.append(f"{sm.kind}|{repr(sm.table.filters)}")
+    for g in frag.runstats:
+        parts.append(f"{g.kind}|{g.table.col_offsets}|{g.table.filters!r}|"
+                     f"{g.cmp_local}|{g.probe_val!r}|{g.having!r}")
     parts.append(repr(frag.selection))
     if frag.agg is not None:
         parts.append(repr(frag.agg.group_by))
@@ -2304,6 +2482,10 @@ def _host_join(frag, snaps, probe_idx, overlay, epoch_only_probe):
                 else:
                     keep &= pkv & ~found
 
+    if filtered and nrows:
+        for g in frag.runstats:
+            keep &= _host_runstat_gate(g, snaps, probe, cols, valids, dicts)
+
     if filtered and frag.selection and nrows:
         ev = NumpyEval([(c, v) for c, v in zip(cols, valids)], dicts,
                        nrows)
@@ -2320,6 +2502,69 @@ def _host_join(frag, snaps, probe_idx, overlay, epoch_only_probe):
     elif nrows == 0:
         return None, None, None
     return cols, valids, dicts
+
+
+def _host_runstat_gate(g, snaps, probe, cols, valids, dicts) -> np.ndarray:
+    """bool[rows]: the joined rows a run-statistics gate passes, over every
+    visible row of the gate's table (overlay included), each row's group
+    found by its key value; the device twin is _runstat_mask, which reads
+    the same totals off the storage runs."""
+    snap = snaps[g.table.table.id]
+    gv = [(d, np.ones(len(d), bool) if v is None else v)
+          for d, v in _full_host_cols(snap, g.table.col_offsets)]
+    gn = len(gv[0][0])
+    gev = NumpyEval(gv, [snap.dictionaries[off]
+                         for off in g.table.col_offsets], gn)
+    live = gv[g.key_local][1].copy()
+    for c in g.table.filters:
+        fv, fvl = gev.eval(c)
+        live &= _truthy(np.asarray(fv)) & fvl
+    keys, inv = np.unique(gv[g.key_local][0][live].astype(np.int64),
+                          return_inverse=True)
+    inv = inv.reshape(-1)
+    # the joined row's own key: the probe column the gate is keyed by
+    pk = probe.col_offsets.index(g.table.col_offsets[g.key_local])
+    rk = cols[pk].astype(np.int64)
+    at = np.minimum(np.searchsorted(keys, rk), max(len(keys) - 1, 0))
+    found = valids[pk] & (keys[at] == rk) if len(keys) else \
+        np.zeros(len(rk), bool)
+
+    def total(vals, red=np.add, ident=0):
+        """Each joined row's total of `vals` (one a live row) over its
+        key's group."""
+        out = np.full(len(keys), ident, np.int64)
+        red.at(out, inv, vals)
+        return out[at] if len(keys) else out[:0]
+
+    if g.kind == "in_having":
+        ok = found
+        for func, arg, op, thr in g.having:
+            if arg is None:
+                v = total(np.ones(int(live.sum()), np.int64))
+            else:
+                av, avl = gev.eval(arg)
+                avl = np.asarray(avl)[live]
+                v = n = total(avl.astype(np.int64))
+                if func == "sum":   # a SUM of no value is NULL: no match
+                    ok = ok & (n > 0)
+                    v = total(np.where(avl, np.asarray(av)[live].astype(
+                        np.int64), 0))
+            ok = ok & {"gt": v > thr, "ge": v >= thr, "lt": v < thr,
+                       "le": v <= thr}[op]
+        return ok
+    cd, cv = gv[g.cmp_local]
+    has = cv[live]
+    c = cd[live].astype(np.int64)
+    top = np.iinfo(np.int64)
+    cnt = total(has.astype(np.int64))
+    lo = total(np.where(has, c, top.max), np.minimum, top.max)
+    hi = total(np.where(has, c, top.min), np.maximum, top.min)
+    pv, pvl = NumpyEval([(d, v) for d, v in zip(cols, valids)], dicts,
+                        len(rk)).eval(g.probe_val)
+    pv = np.asarray(pv).astype(np.int64)
+    # some row of the key's group holds another value than the row's own
+    ok = found & np.asarray(pvl) & (cnt > 0) & ~((lo == pv) & (hi == pv))
+    return ~ok if g.kind == "not_exists" else ok
 
 
 def _host_agg(frag, cols, valids, dicts) -> Optional[Chunk]:

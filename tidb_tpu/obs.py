@@ -1055,7 +1055,14 @@ FRAG_READS = PROCESS_METRICS.counter(
     "agg (dense segments), group (every group, sorted runs), hc (top-k or "
     "HAVING candidates), fat (the final k groups), topn (top-n joined "
     "rows), rows (every joined row goes back to the host); +semi with a "
-    "membership gate")
+    "membership gate; +runstat with run-statistics gates")
+RUNSTAT_GATES = PROCESS_METRICS.counter(
+    "tidb_copr_runstat_gates_total",
+    "run-statistics gates of the fragment reads the device answered, one "
+    "a gate a read, by kind: exists, not_exists (an EXISTS / NOT EXISTS "
+    "correlated on the probe's run key, with a residual) or in_having (an "
+    "IN over GROUP BY that key HAVING ...): each is a statistic of the "
+    "probe row's own storage run (copr/runstat.py)")
 FRAG_FETCHED_ROWS = PROCESS_METRICS.counter(
     "tidb_copr_fragment_fetched_rows_total",
     "rows the device's fragment reads brought back to the host: groups, "

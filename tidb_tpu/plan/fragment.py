@@ -23,6 +23,12 @@ Eligibility (recognized bottom-up over the physical plan):
   are applied to the build bitmaps);
 * integer join keys (dictionary codes are per-table and don't unify).
 
+A SEMI/ANTI join whose subquery reads the PROBE table itself, correlated
+on the column its storage is clustered by (TPC-H Q18's IN over GROUP BY
+l_orderkey HAVING, Q21's EXISTS / NOT EXISTS on l_orderkey with a residual
+on l_suppkey), folds in as a run-statistics gate (FragRunGate): the
+subquery's answer for a row is a statistic of the row's own storage run.
+
 Key density, int32 staging width, and MVCC overlay state are runtime
 properties — the executor (copr/fragment.py) checks them per snapshot and
 falls back to an equivalent host (numpy) fragment interpreter, never to a
@@ -96,6 +102,31 @@ class FragSemi:
 
 
 @dataclass
+class FragRunGate:
+    """Run-statistics gate (EXISTS / NOT EXISTS / IN over GROUP BY ...
+    HAVING): a subquery over the PROBE table itself, correlated on the
+    column storage order is clustered by. Each value of that column is one
+    contiguous run of rows, so the subquery's answer for a probe row is a
+    statistic of the row's own run; copr/fragment.py computes it in the
+    fragment's program and gates the row by it (no bitmap, no candidate
+    buffer). `table` is the probe table again with the subquery's columns
+    and its own WHERE in LOCAL space; `key_local` its run key.
+
+    kind "exists" / "not_exists": the residual is
+    `table.col[cmp_local] <> probe_val` (probe_val in COMBINED space).
+    kind "in_having": the row's run, as the subquery's one group, passes
+    every HAVING conjunct: (func, local arg or None, op, threshold in the
+    argument's integer representation)."""
+
+    kind: str
+    table: FragTable
+    key_local: int
+    cmp_local: Optional[int] = None
+    probe_val: Optional[PlanExpr] = None
+    having: list = field(default_factory=list)
+
+
+@dataclass
 class HCTopN:
     """High-cardinality group-by hint: the aggregation's consumer is
     ORDER BY <score> LIMIT k, so the device may return only a candidate
@@ -157,6 +188,8 @@ class FragmentDAG:
     having: Optional[list] = None
     # semi/anti membership gates applied after the joins (no columns)
     semis: list[FragSemi] = field(default_factory=list)
+    # run-statistics gates applied after the membership gates
+    runstats: list[FragRunGate] = field(default_factory=list)
     HAVING_CAP = 65536  # candidate buffer for having/all-groups modes
 
     def combined_types(self) -> list[FieldType]:
@@ -174,6 +207,9 @@ class FragmentDAG:
         for sm in self.semis:
             parts.append(f"{sm.kind.lower()}(t{sm.table.table.id} "
                          f"key={sm.probe_key!r})")
+        for g in self.runstats:
+            parts.append(f"runstat({g.kind} t{g.table.table.id} "
+                         f"key=col#{g.key_local})")
         if self.selection:
             parts.append(f"sel({len(self.selection)})")
         if self.agg is not None:
@@ -485,6 +521,156 @@ def _try_assemble(col: _Collected) -> Optional[tuple[FragmentDAG, list[int]]]:
     return None
 
 
+_GATE_KINDS = {"SEMI": "exists", "ANTI": "not_exists"}
+
+
+def _collect_runstat(node: PhysicalPlan):
+    """SEMI/ANTI joins stacked over plain-column projections over a join
+    tree, as decorrelation leaves EXISTS, NOT EXISTS and IN subqueries:
+    (the join tree collected, the gate joins, left-schema position ->
+    tree position) or None. Every gate's left schema is the same one."""
+    gates = []
+    while isinstance(node, PhysHashJoin) and node.kind in _GATE_KINDS:
+        gates.append(node)
+        node = node.children[0]
+    projs = []
+    while isinstance(node, PhysProjection) and \
+            all(isinstance(e, Col) for e in node.exprs):
+        projs.append(node)
+        node = node.children[0]
+    col = _collect_join_tree(node) if gates else None
+    if col is None:
+        return None
+
+    def tree_pos(i: int) -> int:
+        for p in projs:
+            i = p.exprs[i].idx
+        return i
+
+    return col, gates, tree_pos
+
+
+def _run_key_offset(frag: FragmentDAG, c: int) -> Optional[int]:
+    """Store offset of the PROBE column that combined column `c` equals
+    on every joined row: a probe column itself, or the unique build key of
+    a join whose probe key is a plain probe column (Q18's o_orderkey)."""
+    probe = frag.tables[0]
+    n = len(probe.col_offsets)
+    if c < n:
+        return probe.col_offsets[c]
+    base = [0]
+    for t in frag.tables:
+        base.append(base[-1] + len(t.col_offsets))
+    for j in frag.joins:
+        if c == base[j.build] + j.build_key_local and \
+                isinstance(j.probe_key, Col) and j.probe_key.idx < n:
+            return probe.col_offsets[j.probe_key.idx]
+    return None
+
+
+def _cmp_compatible(a: FieldType, b: FieldType) -> bool:
+    """Integer-represented types whose raw values compare as the SQL
+    values do (no floats, no per-table dictionary codes)."""
+    if a.is_float or b.is_float or a.is_string or b.is_string:
+        return False
+    if a.is_integer and b.is_integer:
+        return True
+    return a.kind == b.kind and a.scale == b.scale
+
+
+def _runstat_gate(node: PhysHashJoin, frag: FragmentDAG,
+                  comb_of) -> Optional[FragRunGate]:
+    """The run-statistics gate a SEMI/ANTI join is, over a fragment
+    whose probe is the subquery's own table, correlated on the probe's
+    run key; None where it is not one. comb_of: left-schema position ->
+    combined position."""
+    if len(node.eq_conditions) != 1:
+        return None
+    li, ri = node.eq_conditions[0]
+    key_off = _run_key_offset(frag, comb_of(li))
+    if key_off is None:
+        return None
+    probe = frag.tables[0].table
+    if not node.other_conditions:
+        if node.kind != "SEMI":
+            return None
+        return _in_having_gate(node.children[1], ri, probe, key_off)
+    leaf = _semi_build_leaf(node.children[1])
+    if leaf is None or len(node.other_conditions) != 1:
+        return None
+    tr, local_of = leaf
+    offs = tr.dag.scan.col_offsets
+    klocal = local_of(ri)
+    if tr.table.id != probe.id or klocal is None or offs[klocal] != key_off:
+        return None
+    c = node.other_conditions[0]
+    if not (isinstance(c, Call) and c.op == "ne" and len(c.args) == 2 and
+            all(isinstance(a, Col) for a in c.args)):
+        return None
+    lw = len(node.children[0].schema.fields)
+    a, b = sorted(c.args, key=lambda x: x.idx)   # a left, b right
+    if not a.idx < lw <= b.idx:
+        return None
+    clocal = local_of(b.idx - lw)
+    types = _scan_types(tr)
+    if clocal is None or not _cmp_compatible(types[clocal], a.ftype):
+        return None
+    filters = list(tr.dag.selection.conditions) if tr.dag.selection else []
+    return FragRunGate(_GATE_KINDS[node.kind],
+                       FragTable(tr.table, list(offs), filters, types),
+                       klocal, cmp_local=clocal,
+                       probe_val=Col(comb_of(a.idx), a.ftype))
+
+
+def _in_having_gate(right: PhysicalPlan, ri: int, probe, key_off: int
+                    ) -> Optional[FragRunGate]:
+    """IN (SELECT k FROM probe_table [WHERE ...] GROUP BY k HAVING ...):
+    the build side as planned, Projection[k] over Selection[HAVING] over
+    the aggregation (pushed into the scan or not), k the run key."""
+    if not (isinstance(right, PhysProjection) and ri < len(right.exprs)
+            and isinstance(right.exprs[ri], Col)
+            and isinstance(right.children[0], PhysSelection)):
+        return None
+    sel = right.children[0]
+    agg = sel.children[0]
+    if not isinstance(agg, PhysHashAgg) or len(agg.group_by) != 1 or \
+            right.exprs[ri].idx != 0:
+        return None
+    entries = _having_entries(sel.conditions, agg, exact=True)
+    leaf = agg.children[0]
+    if not entries or not isinstance(leaf, PhysTableRead) or \
+            getattr(leaf, "table", None) is None or \
+            leaf.table.id != probe.id:
+        return None
+    dag = leaf.dag
+    if dag.scan.ranges is not None or dag.topn is not None or \
+            dag.limit is not None or dag.projections is not None:
+        return None
+    if agg.mode == "final" and dag.agg is not None:
+        group_by, aggs = dag.agg.group_by, dag.agg.aggs
+    elif agg.mode == "complete" and dag.agg is None:
+        group_by, aggs = agg.group_by, agg.aggs
+    else:
+        return None
+    g = group_by[0]
+    if len(aggs) != len(agg.aggs) or not isinstance(g, Col) or \
+            dag.scan.col_offsets[g.idx] != key_off:
+        return None
+    having = []
+    for ai, op, thr in entries:
+        d = aggs[ai]
+        if d.distinct or (d.arg is not None and (
+                _has_subq(d.arg) or not expr_pushable(d.arg))):
+            return None
+        having.append((d.func, d.arg, op, thr))
+    filters = list(dag.selection.conditions) if dag.selection else []
+    if any(_has_subq(c) for c in filters):
+        return None
+    return FragRunGate("in_having", FragTable(
+        leaf.table, list(dag.scan.col_offsets), filters, _scan_types(leaf)),
+        g.idx, having=having)
+
+
 def _match_agg_fragment(plan: PhysHashAgg, allow_single: bool = False
                         ) -> Optional[PhysHashAgg]:
     """HashAgg(complete) over [Projection?] over join tree -> final agg
@@ -510,6 +696,21 @@ def _match_agg_fragment(plan: PhysHashAgg, allow_single: bool = False
                         d.ftype, d.distinct, d.name, d.params)
                 for d in plan.aggs]
     col = _collect_join_tree(child)
+    gates: list = []
+    tree_pos = None
+    if col is None:
+        got = _collect_runstat(child)
+        if got is not None:
+            # the aggregation reads the gates' left schema: compose it
+            # down to tree positions like a projection
+            col, gates, tree_pos = got
+            fmap = [Col(tree_pos(i), f.ftype) for i, f in
+                    enumerate(gates[0].schema.fields)]
+            group_by = [_subst_cols(g, fmap) for g in group_by]
+            aggs = [AggDesc(d.func, None if d.arg is None
+                            else _subst_cols(d.arg, fmap),
+                            d.ftype, d.distinct, d.name, d.params)
+                    for d in aggs]
     if col is None or not agg_pushable(group_by, aggs) \
             or any(d.distinct for d in plan.aggs) \
             or any(d.func == "approx_count_distinct" for d in aggs):
@@ -517,7 +718,7 @@ def _match_agg_fragment(plan: PhysHashAgg, allow_single: bool = False
         # (streamseg/hcagg are sum-shaped); the scan path carries them
         return None
     if len(col.leaves) == 1 and not col.semis:
-        if not allow_single:
+        if not allow_single and not gates:
             return None
         tr = col.leaves[0]
         frag = FragmentDAG([FragTable(
@@ -531,6 +732,14 @@ def _match_agg_fragment(plan: PhysHashAgg, allow_single: bool = False
         if asm is None:
             return None
         frag, remap = asm
+    for node in gates:
+        gate = _runstat_gate(node, frag, lambda i: remap[tree_pos(i)])
+        if gate is None:
+            return None
+        frag.runstats.append(gate)
+    if len({g.table.col_offsets[g.key_local] for g in frag.runstats}) > 1:
+        # the program totals every gate over ONE key's runs
+        return None
     frag.agg = DAGAggregation(
         [_remap_expr(g, remap) for g in group_by],
         [AggDesc(d.func,
@@ -555,58 +764,77 @@ _HC_SCORE_FUNCS = ("sum", "count", "avg")
 _FLIP = {"gt": "lt", "lt": "gt", "ge": "le", "le": "ge"}
 
 
-def _having_entries(conds: list[PlanExpr], agg_node: PhysHashAgg):
+def _having_entries(conds: list[PlanExpr], agg_node: PhysHashAgg,
+                    exact: bool = False):
     """Extract device-checkable HAVING predicates: comparisons of one
     SUM/COUNT aggregate against a constant, with the threshold converted
     to the aggregate's integer representation. Unconvertible conjuncts
     are simply not pushed — the host Selection re-applies every conjunct
-    exactly, so the device filter only needs to be a superset."""
+    exactly, so the device filter only needs to be a superset.
+
+    `exact`: every conjunct has to convert, with no rounding of its
+    constant and no float, or the result is None — the device's
+    comparison then IS the HAVING clause (a run-statistics gate)."""
+    out = []
+    for c in conds:
+        entry = _having_entry(c, agg_node, exact)
+        if entry is not None:
+            out.append(entry)
+        elif exact:
+            return None
+    return out
+
+
+def _having_entry(c: PlanExpr, agg_node: PhysHashAgg, exact: bool):
+    """One conjunct of _having_entries as (agg index, op, threshold), or
+    None where it does not convert."""
     from ..types.field_type import TypeKind
     from ..types.value import Decimal as Dec
 
-    ngroups = len(agg_node.group_by)
-    out = []
-    for c in conds:
-        if not (isinstance(c, Call) and c.op in _FLIP and
-                len(c.args) == 2):
-            continue
-        a, b = c.args
-        op = c.op
-        if isinstance(a, Const) and isinstance(b, Col):
-            a, b, op = b, a, _FLIP[op]
-        if not (isinstance(a, Col) and isinstance(b, Const)):
-            continue
-        ai = a.idx - ngroups
-        if ai < 0 or ai >= len(agg_node.aggs):
-            continue
-        d = agg_node.aggs[ai]
-        if d.func not in ("sum", "count"):
-            continue
-        # normalize the constant to an exact Decimal (a Const's value is
-        # already in ITS OWN ftype's integer representation)
-        v = b.value
-        try:
-            if isinstance(v, Dec):
-                dv = v
-            elif b.ftype.kind == TypeKind.DECIMAL:
-                dv = Dec(int(v), b.ftype.scale)
-            elif isinstance(v, bool) or not isinstance(v, (int, float)):
-                continue
-            elif isinstance(v, int):
-                dv = Dec(v, 0)
-            else:
-                dv = Dec.parse(repr(float(v)))
-        except (TypeError, ValueError, OverflowError):
-            continue
-        # the device computes sums in the ARGUMENT's integer
-        # representation (the partial layout); the final output type may
-        # carry a different (wider) scale
-        ft = d.arg.ftype if d.func == "sum" and d.arg is not None \
-            else d.ftype
-        sc = ft.scale if ft.kind == TypeKind.DECIMAL else 0
-        thr = dv.rescale(sc).unscaled
-        out.append((ai, op, thr))
-    return out
+    if not (isinstance(c, Call) and c.op in _FLIP and len(c.args) == 2):
+        return None
+    a, b = c.args
+    op = c.op
+    if isinstance(a, Const) and isinstance(b, Col):
+        a, b, op = b, a, _FLIP[op]
+    if not (isinstance(a, Col) and isinstance(b, Const)):
+        return None
+    ai = a.idx - len(agg_node.group_by)
+    if ai < 0 or ai >= len(agg_node.aggs):
+        return None
+    d = agg_node.aggs[ai]
+    if d.func not in ("sum", "count"):
+        return None
+    if exact and d.arg is not None and d.arg.ftype.is_float:
+        return None
+    # normalize the constant to an exact Decimal (a Const's value is
+    # already in ITS OWN ftype's integer representation)
+    v = b.value
+    try:
+        if isinstance(v, Dec):
+            dv = v
+        elif b.ftype.kind == TypeKind.DECIMAL:
+            dv = Dec(int(v), b.ftype.scale)
+        elif isinstance(v, bool) or not isinstance(v, (int, float)):
+            return None
+        elif isinstance(v, int):
+            dv = Dec(v, 0)
+        elif exact:
+            return None
+        else:
+            dv = Dec.parse(repr(float(v)))
+    except (TypeError, ValueError, OverflowError):
+        return None
+    # the device computes sums in the ARGUMENT's integer
+    # representation (the partial layout); the final output type may
+    # carry a different (wider) scale
+    ft = d.arg.ftype if d.func == "sum" and d.arg is not None \
+        else d.ftype
+    sc = ft.scale if ft.kind == TypeKind.DECIMAL else 0
+    thr = dv.rescale(sc)
+    if exact and thr != dv:
+        return None
+    return (ai, op, thr.unscaled)
 
 
 def _resolve_hc_items(sort_node, proj, agg_node) -> Optional[list]:
@@ -709,8 +937,8 @@ def apply_fragments(plan: PhysicalPlan) -> PhysicalPlan:
             if rewritten is not None:
                 attached = _attach_hc(plan, sort_node, proj, below,
                                       rewritten)
-                single = len(rewritten.children[0].frag.tables) == 1
-                if attached or not single:
+                frag = rewritten.children[0].frag
+                if attached or len(frag.tables) > 1 or frag.runstats:
                     # a join fragment is worthwhile on its own; the
                     # degenerate single-table fragment only serves the hc
                     # hint — keep the original plan if it didn't attach
