@@ -540,3 +540,184 @@ def test_a_run_key_with_nulls_reads_on_the_host():
     assert session.query(sql.format("")) == [(2, 3)]
     assert session.query(sql.format("not")) == [(5, 13)]
     assert list(session.last_engines) == ["host(fragment:runstat-unordered)"]
+
+
+# ---- the rank body's group-key score, packed from the run-start rows -------
+
+@pytest.fixture
+def packed_score(monkeypatch):
+    """The packing is for the TPU and epochs of 4 M rows and more; here,
+    any epoch, with the TPU's kernels interpreted."""
+    import jax
+    from jax.experimental.pallas import tpu as pltpu
+
+    monkeypatch.setattr(F, "RANK_PACK_MIN_ROWS", 1)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pltpu.force_tpu_interpret_mode():
+        yield
+
+
+def _scored() -> dict:
+    return {p: obs.RANK_SCORE.get(path=p) for p in ("packed", "gather")}
+
+
+def _moved(before: dict) -> dict:
+    return {p: n - before[p] for p, n in _scored().items()}
+
+
+@pytest.mark.parametrize("limit", [100, 100000])
+def test_q18_through_the_packed_score_is_exact(subq, no_split, packed_score,
+                                               limit):
+    """Q18 orders by o_totalprice, a group key: the rank body reads it at
+    each order's first LINEITEM row by packing, and answers exactly, the
+    planted ties and the 300 boundary among the answers."""
+    session, data, planted = subq
+    before = _scored()
+    got = _q18_rows(_one_read(
+        session, Q18.replace("limit 100", f"limit {limit}")))
+    assert _moved(before) == {"packed": 1, "gather": 0}
+    ref = REF.q18(data, limit=limit)
+    assert _same_q18(got, ref, limit) is None, _same_q18(got, ref, limit)
+    keys = {r[2] for r in got}
+    assert planted["above_300"] in keys and planted["at_300"] not in keys
+    assert {r[2] for r in got[:2]} == {planted["tied"][0],
+                                       planted["tied"][2]}
+    assert got[2][2] == planted["tied"][1]
+
+
+@pytest.mark.parametrize("n0,bucket", [(1000, 1024), (5003, 8192),
+                                       (70001, 131072)])
+def test_packed_score_key_is_the_key_at_every_run_start(n0, bucket):
+    """The key packed from the staged run-start flags equals the gather at
+    r0 at every rank below nd, on buckets with pad rows (holding values
+    of their own) and nd off a multiple of 128."""
+    from types import SimpleNamespace
+
+    import jax.numpy as jnp
+    from tidb_tpu.copr import streamseg as SS
+    from tidb_tpu.copr.client import CopClient
+
+    rng = np.random.default_rng(n0)
+    key = np.repeat(np.arange(n0), rng.integers(1, 8, n0))[:n0]
+    meta = SS.rank_meta([key])
+    nd = meta["nd"]
+    assert nd % 128 and bucket > n0
+    snap = SimpleNamespace(epoch=SimpleNamespace(epoch_id=-1),
+                           store=SimpleNamespace(table=SimpleNamespace(id=-1)))
+    prepared = {"__rank_meta__": meta}
+    gathered = F._stage_rank_aux(CopClient(), snap, prepared, bucket)
+    packed = F._stage_rank_aux(CopClient(), snap,
+                               {**prepared, "__rank_pack__": True}, bucket)
+    flags = np.asarray(packed["run_start"])
+    assert flags.shape == (bucket,) and not flags[n0:].any()
+    assert np.array_equal(np.flatnonzero(flags), _starts(key))
+    col = jnp.asarray(rng.integers(-2**31, 2**31 - 1, bucket,
+                                   dtype=np.int32))
+    want, how = F._key_at_ranks(col, gathered, meta)
+    got, path = F._key_at_ranks(col, packed, meta)
+    assert (how, path) == ("gather", "packed")
+    assert got.shape == want.shape == (meta["nd_pad"],)
+    assert np.array_equal(np.asarray(got)[:nd], np.asarray(want)[:nd])
+
+
+@pytest.mark.parametrize("case", ["null_score", "masked_run"])
+def test_packed_score_keeps_nulls_and_masked_runs_in_place(packed_score,
+                                                           case):
+    """A score key with NULLs (they lead an ascending order) and runs whose
+    rows the WHERE passes none of (the best-scored among them): the packed
+    body agrees with the host's join and aggregation."""
+    import tidb_tpu.plan.fragment as PF
+    s = Session()
+    s.execute("create table d (k int not null primary key, p int)")
+    s.execute("create table f (id int not null primary key, k int not null,"
+              " v int)")
+    rng = np.random.default_rng(43)
+    keys = 3001
+    p = rng.permutation(keys) * 7
+    lens = rng.integers(1, 8, keys)
+    k = np.repeat(np.arange(keys), lens)
+    v = rng.integers(1, 100, len(k))
+    p_valid = np.ones(keys, bool)
+    if case == "null_score":
+        p_valid[rng.choice(keys, 5, replace=False)] = False
+        sql = ("select f.k, d.p, sum(f.v) from f, d where f.k = d.k "
+               "group by f.k, d.p order by d.p, f.k limit 20")
+    else:
+        # every row of the ten best-priced orders fails the WHERE
+        v[np.isin(k, np.argsort(-p)[:10])] = -1
+        sql = ("select f.k, d.p, sum(f.v) from f, d where f.k = d.k and "
+               "f.v > 0 group by f.k, d.p order by d.p desc, f.k limit 20")
+    s.storage.table_store(s.catalog.table("test", "d").id).bulk_load(
+        [np.arange(keys, dtype=np.int64), p], [None, p_valid])
+    s.storage.table_store(s.catalog.table("test", "f").id).bulk_load(
+        [np.arange(len(k), dtype=np.int64), k, v], [None, None, None])
+    for t in ("d", "f"):
+        s.execute(f"analyze table {t}")
+    before = _scored()
+    got = s.query(sql)
+    assert s.last_engines == ["device[fat]"], s.last_engines
+    assert _moved(before) == {"packed": 1, "gather": 0}
+    with pytest.MonkeyPatch.context() as m:
+        m.setattr(PF, "apply_fragments", lambda p: p)
+        want = s.query(sql + " ")
+    assert got == want
+    if case == "null_score":
+        assert [r[1] for r in got[:5]] == [None] * 5
+
+
+@pytest.mark.parametrize("path", ["packed", "gather", "off_tpu"])
+def test_rank_score_counter_moves_once_a_q18_read(subq, monkeypatch, path):
+    """One count a Q18 read, under the path its program was traced with:
+    packed on the TPU at or above RANK_PACK_MIN_ROWS, gather below it and
+    anywhere off the TPU."""
+    import jax
+    from jax.experimental.pallas import tpu as pltpu
+
+    session, _, _ = subq
+    monkeypatch.setattr(F, "RANK_PACK_MIN_ROWS",
+                        1 << 40 if path == "gather" else 1)
+    if path != "off_tpu":
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    before = _scored()
+    with pltpu.force_tpu_interpret_mode():
+        session.query(Q18)
+        session.query(Q18)
+    taken = "packed" if path == "packed" else "gather"
+    assert _moved(before) == {"packed": 0, "gather": 0, taken: 2}
+
+
+def test_rank_score_counter_ignores_an_aggregate_score(subq, packed_score):
+    """GROUP BY l_orderkey ordered by its SUM (heavy's group_top10 shape)
+    takes the rank path with an aggregate score: neither label moves."""
+    session, _, _ = subq
+    scores = []
+    inner = F._hc_rank_body
+
+    def spy(frag, prepared, cols, mask, aux):
+        scores.append(frag.hc.score)
+        return inner(frag, prepared, cols, mask, aux)
+    from tidb_tpu.copr.client import CopClient
+    fresh = Session(session.storage, cop=CopClient())
+    fresh.execute("use joins")
+    before = _scored()
+    with pytest.MonkeyPatch.context() as m:
+        m.setattr(F, "_hc_rank_body", spy)
+        fresh.query("select l_orderkey, sum(l_quantity) from lineitem "
+                    "group by l_orderkey order by 2 desc, 1 limit 10")
+    assert [kind for kind, _ in scores] == ["agg"]
+    assert _moved(before) == {"packed": 0, "gather": 0}
+
+
+def test_rank_score_counter_renders_at_zero_from_the_first_client():
+    code = ("from tidb_tpu import obs\n"
+            "from tidb_tpu.copr.client import CopClient\n"
+            "assert 'rank_score_total{' not in obs.PROCESS_METRICS.render()\n"
+            "CopClient()\n"
+            "print(obs.PROCESS_METRICS.render())\n")
+    out = subprocess.run([sys.executable, "-c", code], text=True,
+                         env=dict(os.environ, JAX_PLATFORMS="cpu"),
+                         capture_output=True, timeout=120, check=True,
+                         cwd=os.path.dirname(os.path.dirname(__file__)))
+    for path in ("packed", "gather"):
+        assert f'tidb_copr_rank_score_total{{path="{path}"}} 0' in out.stdout
+    assert obs.lint_metrics([obs.PROCESS_METRICS]) == []

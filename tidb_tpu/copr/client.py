@@ -245,6 +245,8 @@ class CopClient:
             obs.HC_SELECT.inc(0, path=sel_path)
         for pack_path in ("packed", "spilled", "whole"):
             obs.HC_PACK.inc(0, path=pack_path)
+        for score_path in ("packed", "gather"):
+            obs.RANK_SCORE.inc(0, path=score_path)
         for body in runstat.BODIES:
             obs.FRAG_READS.inc(0, mode=f"{body}+runstat")
         for gate_kind in runstat.KINDS:
@@ -1314,7 +1316,8 @@ class CopClient:
                                   for out in outs) if c is not None]
         return chunks
 
-    def _select_taken(self, key, prepared) -> list:
+    def _select_taken(self, key, prepared,
+                      slot: str = "__select_taken__") -> list:
         """The one-slot record, kept beside the program cached under
         `key`, of the path its body selects the winners by: a body built
         from `prepared` hands it to topnsel.select (a TopN) or
@@ -1322,10 +1325,12 @@ class CopClient:
         program is traced (at its first dispatch) with what it does for
         the shape it ranks. tidb_copr_topn_select_total, or
         tidb_copr_hc_select_total, counts a read under it after the
-        dispatch."""
+        dispatch. Another `slot` of `prepared` keeps another such record
+        of the same program (the rank body's score read,
+        tidb_copr_rank_score_total)."""
         with self._lock:
-            taken = self._select_paths.setdefault(key, [])
-        prepared["__select_taken__"] = taken
+            taken = self._select_paths.setdefault((slot, key), [])
+        prepared[slot] = taken
         return taken
 
     def _topn_decode(self, dag, snap, out) -> Optional[Chunk]:

@@ -96,6 +96,13 @@ FRAG_SPAN_CAP = 1 << 26
 # pass, packed in 16 ms where a sort and gathers took 395 ms)
 HC_COMPACT_MIN_ROWS = 1 << 22
 HC_COMPACT_DIV = 32
+# the rank-path hc body ordered by a group key reads that key at each
+# rank's first row: over an epoch of this many rows or more it packs the
+# key at the run-start rows to the front (copr/rowpack.py) instead of
+# gathering it at every rank out of the epoch-long column (Q18 at SF10:
+# 15 M ranks out of a 67 M-row bucket); below it the gather is cheap, and
+# so it is anywhere off the TPU, where rowpack runs in Pallas's interpreter
+RANK_PACK_MIN_ROWS = 1 << 22
 
 
 class _Fallback(Exception):
@@ -371,6 +378,12 @@ def _device_fragment(cop, frag, snaps) -> CopResult:
                 if meta is not None:
                     prepared["__rank_meta__"] = meta
                     prepared["__sig__"].append(SS.program_key(meta))
+                    if frag.hc is not None and \
+                            frag.hc.score[0] == "group" and \
+                            meta["n0"] >= RANK_PACK_MIN_ROWS and \
+                            jax.default_backend() == "tpu":
+                        prepared["__rank_pack__"] = True
+                        prepared["__sig__"].append(("rankpack",))
         # hc None (HAVING-filtered or all-groups "group" mode) runs in
         # rank space when the epoch is run-ordered, else through the
         # sorted-run body's gate-scored candidate buffer
@@ -828,15 +841,19 @@ def _run_frag_batch(cop, frag, snaps, prepared, spans, builds, overlay,
                 obs.stage("staging", span_name="copr.staging"):
             kern_builds = kern_builds + [_stage_runstat(cop, frag, psnap)]
 
+    bucket = pcols[0][0].shape[0] if pcols else 0
     aux = None
     if mode == "hc" and not overlay and \
             prepared.get("__rank_meta__") is not None:
-        aux = _stage_rank_aux(cop, psnap, prepared)
-    key = ("frag", _frag_key(frag), _sig(prepared), mode,
-           pcols[0][0].shape[0] if pcols else 0,
+        aux = _stage_rank_aux(cop, psnap, prepared, bucket)
+    key = ("frag", _frag_key(frag), _sig(prepared), mode, bucket,
            tuple(_build_shape(b) for b in kern_builds))
     taken = cop._select_taken(key, prepared) \
         if mode in ("topn", "hc") else None
+    # how the rank body reads a group-key score at each rank's first row
+    # (left empty by a body scored otherwise)
+    score_taken = cop._select_taken(key, prepared, "__rank_score_taken__") \
+        if aux is not None else None
     name = _prog_mode(frag, mode)
     kern = cop._kernel(key, lambda: pl.frag_program(
         _build_frag_kernel(frag, prepared, spans, mode, pl), name,
@@ -849,6 +866,8 @@ def _run_frag_batch(cop, frag, snaps, prepared, spans, builds, overlay,
         if taken is not None:
             (obs.HC_SELECT if mode == "hc" else obs.TOPN_SELECT).inc(
                 path=taken[0])
+        if score_taken:
+            obs.RANK_SCORE.inc(path=score_taken[0])
         with obs.stage("device_get", span_name="device.fetch",
                        clocked=True, prog=prog):
             out = jax.device_get(dev)
@@ -1035,23 +1054,39 @@ def _decode_frag_topn(frag, snaps, out) -> Optional[Chunk]:
     return Chunk(columns)
 
 
-def _stage_rank_aux(cop, snap, prepared):
+def _stage_rank_aux(cop, snap, prepared, n: int):
     """Device-resident epoch arrays for the streamseg rank kernel: the
     in-block local ranks lr with the per-block rank counts cb, and
-    first-row-per-rank r0 (cached per epoch)."""
+    first-row-per-rank r0; where the body packs its score key
+    (`__rank_pack__`), also run_start, bool[n] over the `n`-row bucket:
+    true at each run's first row, false at every pad row (each cached
+    per epoch)."""
+    from . import streamseg as SS
+
     meta = prepared["__rank_meta__"]
-    key = (snap.epoch.epoch_id, "rankaux", meta["n0"], meta["nd"])
-    with cop._lock:
-        hit = cop._col_cache.get(key)
-        cacheable = cop._live_epochs.get(snap.store.table.id) \
-            == snap.epoch.epoch_id
-    if hit is None:
-        from . import streamseg as SS
-        hit = {**SS.rank_aux(meta), "r0": jnp.asarray(meta["r0"])}
-        if cacheable:
-            with cop._lock:
-                cop._col_cache[key] = hit
-    return hit
+
+    def run_start():
+        flags = np.zeros(n, dtype=bool)
+        flags[meta["r0"][:meta["nd"]]] = True
+        return {"run_start": jnp.asarray(flags)}
+
+    staged = {(snap.epoch.epoch_id, "rankaux", meta["n0"], meta["nd"]):
+              lambda: {**SS.rank_aux(meta), "r0": jnp.asarray(meta["r0"])}}
+    if prepared.get("__rank_pack__"):
+        staged[(snap.epoch.epoch_id, "rankstart", meta["nd"], n)] = run_start
+    aux = {}
+    for key, make in staged.items():
+        with cop._lock:
+            hit = cop._col_cache.get(key)
+            cacheable = cop._live_epochs.get(snap.store.table.id) \
+                == snap.epoch.epoch_id
+        if hit is None:
+            hit = make()
+            if cacheable:
+                with cop._lock:
+                    cop._col_cache[key] = hit
+        aux.update(hit)
+    return aux
 
 
 def _expr_cols(exprs, base: int = 0) -> set:
@@ -1803,7 +1838,12 @@ def _hc_rank_body(frag, prepared, cols, mask, aux):
     # ---- candidate selection by (approximate) primary sort score ----
     kind, idx = hc.score
     if kind == "group":
-        enc_r = encs[idx][r0]
+        # slots from nd on hold any value: gate (zero there) scores them
+        # -inf
+        enc_r, path = _key_at_ranks(encs[idx], aux, meta)
+        score_taken = prepared.get("__rank_score_taken__")
+        if score_taken is not None:
+            score_taken[:] = [path]
         sv = enc_r.astype(jnp.float32)
         score_null = enc_r == nulls[idx]
     else:
@@ -1828,6 +1868,21 @@ def _hc_rank_body(frag, prepared, cols, mask, aux):
         res[f"gk{gi}"] = encs[gi][rows_of]
     _emit_pairs(res, sched, term_ix, cnt_ix, tot, cand)
     return _maybe_fused_cut(frag, prepared, res)
+
+
+def _key_at_ranks(enc, aux, meta):
+    """(the bucket-long int32 column `enc` at each rank's first row,
+    nd_pad long, and how it was read: "packed" or "gather"). Ranks are
+    numbered by run start in storage order, so where run_start is staged
+    the column's values at the run-start rows, packed to the front in
+    order (copr/rowpack.py), ARE enc[r0], with nothing gathered at the
+    epoch's length. Slots from nd on hold any value."""
+    from . import rowpack
+
+    if "run_start" in aux:
+        _, (at,), _ = rowpack.pack(aux["run_start"], [enc], meta["nd_pad"])
+        return at, "packed"
+    return enc[aux["r0"]], "gather"
 
 
 def _emit_pairs(res, sched, term_ix, cnt_ix, tot, cand):

@@ -1034,6 +1034,13 @@ HC_PACK = PROCESS_METRICS.counter(
     "buffer held every passing row), spilled (more passed than it holds: "
     "the read ran again whole) or whole (a statement that once spilled "
     "runs whole from then on)")
+RANK_SCORE = PROCESS_METRICS.counter(
+    "tidb_copr_rank_score_total",
+    "coprocessor reads of a run-ordered high-cardinality GROUP BY fragment "
+    "(the rank path) ordered by a group key, by how its program reads that "
+    "key at each group's first row: packed (the key at the run-start rows "
+    "packed to the front, copr/rowpack.py, over an epoch of 4 M rows or "
+    "more on the TPU) or gather (a gather at every group's first row)")
 SNAPSHOT_MASK = PROCESS_METRICS.counter(
     "tidb_store_snapshot_mask_total",
     "TableStore.snapshot calls, by the base-row visibility mask they hand "
